@@ -72,6 +72,8 @@ def main() -> None:
                     help="directory for results.csv/tables.md ('' = off)")
     args = ap.parse_args()
 
+    from repro import compile_cache
+    print(f"compile cache: {compile_cache.enable()}")
     from benchmarks.report import Report
     report = Report(verbose=args.verbose)
     failed_modules = []

@@ -67,8 +67,8 @@ def _decode_case(jax, jnp, B, Hq, Hkv, hd, bs, mb, seed):
     nb = B * mb + 2
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, Hq, hd), jnp.float32)
-    pk = jax.random.normal(ks[1], (nb, bs, Hkv, hd), jnp.float32)
-    pv = jax.random.normal(ks[2], (nb, bs, Hkv, hd), jnp.float32)
+    pk = jax.random.normal(ks[1], (nb, Hkv, bs, hd), jnp.float32)
+    pv = jax.random.normal(ks[2], (nb, Hkv, bs, hd), jnp.float32)
     rng = np.random.default_rng(seed + B * 1000 + hd)
     free = list(rng.permutation(np.arange(1, nb)))
     lens = np.zeros(B, np.int32)
@@ -84,8 +84,8 @@ def _window_case(jax, jnp, B, S, Hq, Hkv, hd, bs, mb, seed):
     nb = B * mb + 2
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, S, Hq, hd), jnp.float32)
-    pk = jax.random.normal(ks[1], (nb, bs, Hkv, hd), jnp.float32)
-    pv = jax.random.normal(ks[2], (nb, bs, Hkv, hd), jnp.float32)
+    pk = jax.random.normal(ks[1], (nb, Hkv, bs, hd), jnp.float32)
+    pv = jax.random.normal(ks[2], (nb, Hkv, bs, hd), jnp.float32)
     rng = np.random.default_rng(seed + B * 1000 + S * 100 + hd)
     free = list(rng.permutation(np.arange(1, nb)))
     base = np.zeros(B, np.int32)

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 
 NEG_INF = float("-inf")
 
@@ -109,7 +108,7 @@ def decode_attention(q, k, v, n_valid, *, sliding_window: int = 0,
             jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
             jax.ShapeDtypeStruct((B, Hkv, G), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(n_valid, jnp.int32).reshape(1), qg, k, v)

@@ -1,7 +1,7 @@
 """Fused paged GQA attention for TPU: one kernel, q_len >= 1.
 
 The serving engine's KV lives in a shared block pool
-``(num_blocks, block_size, Hkv, hd)`` per layer, and each slot maps its
+``(num_blocks, Hkv, block_size, hd)`` per layer, and each slot maps its
 logical positions through a per-slot block table (``repro.serve.blocks``).
 The portable jnp path (`attention.paged_decode_attention` /
 `attention.paged_verify_attention`) *gathers* each row's blocks into a
@@ -11,7 +11,8 @@ O(B x max_seq) of extra HBM traffic per layer per step.
 This kernel reads the pool **in place**: the block table and per-row
 base lengths ride in as scalar-prefetch operands (SMEM), and the K/V
 BlockSpec index maps dereference the table, so each grid step DMAs
-exactly one physical block from the pool into VMEM. Nothing is
+one KV head of one physical block, a contiguous ``(block_size, hd)``
+tile, from the pool into VMEM. Nothing is
 materialized per-row; the only per-step HBM traffic is the blocks a row
 actually owns (plus masked-off scratch for table tails).
 
@@ -38,6 +39,14 @@ continuous batching needs no padding and no HBM mask tensor.
 
 Emits (out, lse) so sequence-sharded pools can merge partials with the
 same closed-form LSE combine as the stripe decode kernel.
+
+Layout and Mosaic tiling: the last two dimensions of every block must be
+divisible by (8, 128) or equal the array's own. The pool is head-major
+so a K/V block is ``(1, 1, bs, hd)`` (last two dims the array's own),
+and lse leaves as ``(B, Hkv, R, 1)`` so its block ``(1, 1, R, 1)`` ends
+in full dimensions too. A token-major pool with a ``(1, bs, 1, hd)``
+block puts a 1 in the second-minor place of the ``Hkv`` axis, which the
+TPU compiler refuses for ``Hkv > 1``; interpret mode does not check it.
 """
 from __future__ import annotations
 
@@ -47,8 +56,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
-
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 
 NEG_INF = float("-inf")
 
@@ -159,8 +166,8 @@ def _paged_window_kernel(table_ref, base_ref, q_ref, k_ref, v_ref, o_ref,
 
     base = base_ref[b]
     q = q_ref[0, 0].astype(jnp.float32)                  # (S*G, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (bs, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                  # (bs, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
 
     s = _qk_scores(q, k, scale, deterministic=deterministic)
     mask = _window_mask(s.shape, j, base, bs=bs, G=G, window=window)
@@ -178,7 +185,7 @@ def _paged_window_kernel(table_ref, base_ref, q_ref, k_ref, v_ref, o_ref,
     def _write():
         l = jnp.maximum(acc_ref[:, -1:], 1e-30)
         o_ref[0, 0] = (acc_ref[:, :-1] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_safe + jnp.log(l))[:, 0]
+        lse_ref[0, 0] = m_safe + jnp.log(l)              # (S*G, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("sliding_window", "interpret"))
@@ -186,8 +193,8 @@ def paged_window_attention(q, pool_k, pool_v, block_table, base_lens, *,
                            sliding_window: int = 0, interpret: bool = True):
     """The fused multi-token tile. q (B, S, Hq, hd) — S window tokens
     per row at absolute positions ``base_lens[b] + [0, S)``, their K/V
-    already scattered into the pool; pool_k/pool_v (num_blocks, bs,
-    Hkv, hd); block_table (B, max_blocks) int32; base_lens (B,) int32
+    already scattered into the pool; pool_k/pool_v (num_blocks, Hkv,
+    bs, hd); block_table (B, max_blocks) int32; base_lens (B,) int32
     tokens resident per row *before* the window. Window query w of row
     b attends to cache positions ``[0, base_lens[b] + w]`` (causal in
     the window). Returns (out (B,S,Hq,hd) in q.dtype, lse (B,S,Hq) f32).
@@ -195,7 +202,7 @@ def paged_window_attention(q, pool_k, pool_v, block_table, base_lens, *,
     ``S = 1`` with ``base_lens = lengths - 1`` is exactly the classic
     single-token paged decode — one code path, every consumer."""
     B, S, Hq, hd = q.shape
-    bs, Hkv = pool_k.shape[1], pool_k.shape[2]
+    Hkv, bs = pool_k.shape[1], pool_k.shape[2]
     G = Hq // Hkv
     R = S * G
     max_blocks = block_table.shape[1]
@@ -217,19 +224,19 @@ def paged_window_attention(q, pool_k, pool_v, block_table, base_lens, *,
     flat_table = block_table.reshape(-1).astype(jnp.int32)
 
     def kv_map(b, h, j, table, lens):
-        return (table[b * max_blocks + j], 0, h, 0)
+        return (table[b * max_blocks + j], h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, Hkv, max_blocks),
         in_specs=[
             pl.BlockSpec((1, 1, R, hd), lambda b, h, j, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd), kv_map),
-            pl.BlockSpec((1, bs, 1, hd), kv_map),
+            pl.BlockSpec((1, 1, bs, hd), kv_map),
+            pl.BlockSpec((1, 1, bs, hd), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, R, hd), lambda b, h, j, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, R), lambda b, h, j, *_: (b, h, 0)),
+            pl.BlockSpec((1, 1, R, 1), lambda b, h, j, *_: (b, h, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((R, hd + 1), jnp.float32),    # acc | denominator
@@ -241,9 +248,9 @@ def paged_window_attention(q, pool_k, pool_v, block_table, base_lens, *,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, R, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, R), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, R, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(flat_table, jnp.asarray(base_lens, jnp.int32).reshape(-1), qg,
@@ -259,7 +266,7 @@ def paged_window_attention(q, pool_k, pool_v, block_table, base_lens, *,
 def paged_decode_attention(q, pool_k, pool_v, block_table, lengths, *,
                            sliding_window: int = 0, interpret: bool = True):
     """Single-token decode — the fused window kernel at its S = 1
-    degenerate case. q (B,Hq,hd); pool_k/pool_v (num_blocks, bs, Hkv,
+    degenerate case. q (B,Hq,hd); pool_k/pool_v (num_blocks, Hkv, bs,
     hd); block_table (B, max_blocks) int32; lengths (B,) int32 valid
     tokens per row (the new token's K/V already scattered into its
     block). Returns (out (B,Hq,hd) in q.dtype, lse (B,Hq) f32)."""

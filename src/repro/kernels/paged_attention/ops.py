@@ -29,7 +29,7 @@ __all__ = ["paged_decode_attention", "paged_decode_attention_ref",
 
 def paged_decode_attention(q, pool_k, pool_v, block_table, lengths, *,
                            sliding_window: int = 0, force_ref: bool = False):
-    """q (B,Hq,hd); pool_k/pool_v (num_blocks, bs, Hkv, hd); block_table
+    """q (B,Hq,hd); pool_k/pool_v (num_blocks, Hkv, bs, hd); block_table
     (B, max_blocks) int32; lengths (B,) valid tokens per row (new token
     already scattered). Returns (out (B,Hq,hd), lse (B,Hq) f32)."""
     if force_ref:

@@ -46,11 +46,11 @@ def paged_window_attention_ref(q, pool_k, pool_v, block_table, base_lens, *,
 
     q (B,S,Hq,hd) — S window tokens per row at positions
     ``base_lens[b] + [0, S)`` (K/V already scattered); pool_k/pool_v
-    (num_blocks, bs, Hkv, hd); block_table (B, max_blocks) int32;
+    (num_blocks, Hkv, bs, hd); block_table (B, max_blocks) int32;
     base_lens (B,) tokens resident per row before the window. Returns
     (out (B,S,Hq,hd) in q.dtype, lse (B,S,Hq) f32)."""
     B, S, Hq, hd = q.shape
-    bs, Hkv = pool_k.shape[1], pool_k.shape[2]
+    Hkv, bs = pool_k.shape[1], pool_k.shape[2]
     G = Hq // Hkv
     R = S * G
     max_blocks = block_table.shape[1]
@@ -65,8 +65,8 @@ def paged_window_attention_ref(q, pool_k, pool_v, block_table, base_lens, *,
         def body(carry, j):
             acc, m_prev = carry
             phys = table_b[j]
-            k = pool_k[phys, :, h].astype(jnp.float32)      # (bs, hd)
-            v = pool_v[phys, :, h].astype(jnp.float32)
+            k = pool_k[phys, h].astype(jnp.float32)         # (bs, hd)
+            v = pool_v[phys, h].astype(jnp.float32)
             s = _qk_scores(qf, k, scale, deterministic=True)
             mask = _window_mask(s.shape, j, base, bs=bs, G=G,
                                 window=sliding_window)
@@ -123,21 +123,28 @@ def paged_decode_attention_ref(q, pool_k, pool_v, block_table, lengths, *,
     return out[:, 0], lse[:, 0]
 
 
+def _gather_heads(pool, block_table):
+    """Gather each row's blocks through its table: (num_blocks, Hkv, bs,
+    hd) -> (B, Hkv, max_blocks*bs, hd) in logical order, float32."""
+    B, max_blocks = block_table.shape
+    _, Hkv, bs, hd = pool.shape
+    g = jnp.transpose(pool[block_table], (0, 2, 1, 3, 4))  # (B,Hkv,mb,bs,hd)
+    return g.reshape(B, Hkv, max_blocks * bs, hd).astype(jnp.float32)
+
+
 def gathered_window_ref(q, pool_k, pool_v, block_table, base_lens, *,
                         sliding_window: int = 0):
     """Independent window oracle: table gather + one-shot masked softmax
     with the causal-in-window mask (query w of row b sees cache
     positions <= base_lens[b] + w)."""
     B, S, Hq, hd = q.shape
-    bs, Hkv = pool_k.shape[1], pool_k.shape[2]
+    Hkv, bs = pool_k.shape[1], pool_k.shape[2]
     G = Hq // Hkv
     max_blocks = block_table.shape[1]
     T = max_blocks * bs
-    gk = pool_k[block_table].reshape(B, T, Hkv, hd)
-    gv = pool_v[block_table].reshape(B, T, Hkv, hd)
     qg = q.reshape(B, S, Hkv, G, hd).astype(jnp.float32)
-    kx = jnp.moveaxis(gk, 2, 1).astype(jnp.float32)          # (B,Hkv,T,hd)
-    vx = jnp.moveaxis(gv, 2, 1).astype(jnp.float32)
+    kx = _gather_heads(pool_k, block_table)                  # (B,Hkv,T,hd)
+    vx = _gather_heads(pool_v, block_table)
     s = jnp.einsum("bskgd,bktd->bkstg", qg, kx) / jnp.sqrt(float(hd))
     base = jnp.asarray(base_lens, jnp.int32).reshape(-1)
     i = base[:, None] + jnp.arange(S)[None, :]               # (B,S) abs pos
@@ -157,15 +164,13 @@ def gathered_decode_ref(q, pool_k, pool_v, block_table, lengths, *,
                         sliding_window: int = 0):
     """Independent oracle: table gather + one-shot masked softmax."""
     B, Hq, hd = q.shape
-    bs, Hkv = pool_k.shape[1], pool_k.shape[2]
+    Hkv, bs = pool_k.shape[1], pool_k.shape[2]
     G = Hq // Hkv
     max_blocks = block_table.shape[1]
     T = max_blocks * bs
-    gk = pool_k[block_table].reshape(B, T, Hkv, hd)
-    gv = pool_v[block_table].reshape(B, T, Hkv, hd)
     qg = q.reshape(B, Hkv, G, hd).astype(jnp.float32)
-    kx = jnp.moveaxis(gk, 2, 1).astype(jnp.float32)          # (B,Hkv,T,hd)
-    vx = jnp.moveaxis(gv, 2, 1).astype(jnp.float32)
+    kx = _gather_heads(pool_k, block_table)                  # (B,Hkv,T,hd)
+    vx = _gather_heads(pool_v, block_table)
     s = jnp.einsum("bkgd,bktd->bkgt", qg, kx) / jnp.sqrt(float(hd))
     lengths = jnp.asarray(lengths, jnp.int32).reshape(-1)
     j = jnp.arange(T)

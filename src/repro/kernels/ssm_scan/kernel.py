@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
-
 
 def _ssm_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, s0_ref,
                 y_ref, sT_ref, state_ref, *, bt: int, n_t_blocks: int):
@@ -99,7 +97,7 @@ def ssm_scan(u, dt, Bm, Cm, A, D, state, *, bt: int = 64, bd: int = 0,
             jax.ShapeDtypeStruct((B, di, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(u, dt, Bm, Cm, A, D.reshape(1, di), state)

@@ -1,13 +1,18 @@
 """Serving launcher: slot-native continuous-batching engine for one
 architecture, behind an SLO-aware scheduler.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-1.6b \
-        [--requests 6] [--batch 4] [--max-new 8] [--policy spf]
+    PYTHONPATH=src python -m repro.launch.serve [--arch qwen3-4b] \
+        [--requests 6] [--batch 8] [--max-new 8] [--policy spf]
+    PYTHONPATH=src JAX_PLATFORMS=cpu python -m repro.launch.serve --reduced
 
-Serves synthetic token requests through the mixed-length engine (reduced
-config on CPU). For the multi-model parallel-PaaS serving of the paper,
-see examples/serve_parallel_pipeline.py; for pod-scale serving shapes,
-see repro.launch.dryrun (decode_32k / long_500k).
+Serves synthetic token requests at the architecture's published widths
+and dtype, with seeded random weights, through the paged engine and the
+Pallas paged-attention kernel. ``--reduced`` serves the 2-layer float32
+``ArchConfig.reduced()`` variant instead: the size for CPU runs and CI.
+Before serving, the engine's decode, chunk-window and admission programs
+are compiled ahead of time and their ``memory_analysis()`` sizes the KV
+pool against the device's memory. For the multi-model parallel-PaaS
+serving of the paper, see examples/serve_parallel_pipeline.py.
 """
 from __future__ import annotations
 
@@ -17,20 +22,122 @@ import statistics
 
 import jax
 
+from repro import compile_cache
 from repro.configs.base import ARCH_IDS, get_config
 from repro.models.model import build_model
 from repro.serve.engine import Request, ServingEngine
 from repro.serve.scheduler import POLICIES, Scheduler
 
+# engine shape per mode when --batch / --max-seq are not given
+DEFAULT_SHAPE = {"published": {"batch": 8, "max_seq": 2048},
+                 "reduced": {"batch": 4, "max_seq": 128}}
+# share of the device's memory the resident state plus one program may
+# plan to use; the rest is left to the runtime and fragmentation
+HBM_BUDGET = 0.9
+
+
+def load_model(arch: str, *, reduced: bool, seed: int = 0):
+    """Config, model and seeded random parameters. The parameters are
+    built on the device by one jitted program, so each float32 draw
+    fuses into its cast to the model dtype instead of living as an eager
+    float32 transient (3.6 GB for Qwen3-4B's stacked FFN weight)."""
+    cfg = get_config(arch)
+    if reduced:
+        cfg = dataclasses.replace(cfg.reduced(), dtype=jax.numpy.float32)
+    model = build_model(cfg)
+    params = jax.jit(model.init)(jax.random.key(seed))
+    return cfg, model, params
+
+
+def _nbytes(tree) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+def _hbm_limit():
+    """bytes_limit of device 0; None where the backend reports no memory
+    statistics (the CPU)."""
+    return (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
+def plan_memory(eng: ServingEngine, compiled: dict) -> dict:
+    """Device bytes each serving program needs while it runs: its
+    arguments, outputs not aliased to donated arguments, and
+    temporaries, plus the KV pool where the pool is not an argument
+    (admission). ``peak`` is the largest of them."""
+    pool = _nbytes(eng.caches)
+    plan = {"params": _nbytes(eng.params), "pool": pool,
+            "num_blocks": eng.pool.total}
+    for name, c in compiled.items():
+        ma = c.memory_analysis()
+        live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+        plan[name] = live + (pool if name == "admit" else 0)
+    plan["peak"] = max(plan[name] for name in compiled)
+    return plan
+
+
+def build_engine(model, params, *, batch: int, max_seq: int,
+                 prefill_chunk=None, prefill_budget=None, tracer=None):
+    """The serving engine as this launcher runs it: paged KV, the Pallas
+    paged-attention kernel (compiled on TPU, interpret mode elsewhere),
+    and a pool sized from the compiled programs. The pool starts at one
+    ``max_seq`` stripe per slot; while the peak program exceeds
+    ``HBM_BUDGET`` of the device's memory it shrinks, counting each
+    block twice (resident, and its copy in a program's temporaries).
+    Returns (engine, compiled programs, memory plan); the compiled
+    programs are the ones the engine then runs."""
+    limit = _hbm_limit()
+    num_blocks = None
+    while True:
+        eng = ServingEngine(model, params, batch_size=batch,
+                            max_seq=max_seq, num_blocks=num_blocks,
+                            use_kernel=True, prefill_chunk=prefill_chunk,
+                            prefill_budget=prefill_budget, tracer=tracer)
+        if not eng.paged:
+            return eng, {}, None
+        compiled = eng.compile_programs()
+        plan = plan_memory(eng, compiled)
+        plan["hbm"] = limit
+        if limit is None or plan["peak"] <= HBM_BUDGET * limit:
+            return eng, compiled, plan
+        per_block = plan["pool"] / plan["num_blocks"]
+        over = plan["peak"] - HBM_BUDGET * limit
+        num_blocks = plan["num_blocks"] - int(-(-over // (2 * per_block)))
+        if num_blocks < batch + 1:
+            raise RuntimeError(
+                f"batch {batch} x max_seq {max_seq} does not fit: peak "
+                f"program {plan['peak'] / 1e9:.2f} GB of "
+                f"{limit / 1e9:.2f} GB with the smallest pool")
+        del eng, compiled
+
+
+def describe_plan(plan) -> str:
+    if plan is None:
+        return "memory: stripe cache, no ahead-of-time sizing"
+    gb = lambda n: f"{n / 1e9:.2f} GB"                     # noqa: E731
+    hbm = (f"{gb(plan['hbm'])} device memory, budget {HBM_BUDGET:.0%}"
+           if plan["hbm"] else "device memory not reported")
+    return (f"memory: params {gb(plan['params'])}, KV pool "
+            f"{plan['num_blocks']} blocks {gb(plan['pool'])}; per program "
+            f"(args + unaliased outputs + temps, + pool at admit): decode "
+            f"{gb(plan['decode'])}, chunk {gb(plan['chunk'])}, admit "
+            f"{gb(plan['admit'])}; peak {gb(plan['peak'])} of {hbm}")
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b", choices=ARCH_IDS)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the 2-layer float32 reduced config (CPU "
+                         "runs and CI) instead of the published widths")
     ap.add_argument("--requests", type=int, default=6)
-    ap.add_argument("--batch", type=int, default=4)
+    ap.add_argument("--batch", type=int, default=None,
+                    help="engine slots (default: 8 published, 4 reduced)")
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=16)
-    ap.add_argument("--max-seq", type=int, default=128)
+    ap.add_argument("--max-seq", type=int, default=None,
+                    help="tokens per slot (default: 2048 published, 128 "
+                         "reduced)")
     ap.add_argument("--policy", default="fifo", choices=POLICIES)
     ap.add_argument("--slo-ms", type=float, default=0.0,
                     help="per-request deadline; 0 = no SLO")
@@ -55,15 +162,17 @@ def main() -> None:
         from repro.serve.telemetry import Tracer
         tracer = Tracer()
 
-    cfg = dataclasses.replace(get_config(args.arch).reduced(),
-                              dtype=jax.numpy.float32)
-    model = build_model(cfg)
-    params = model.init(jax.random.key(0))
-    eng = ServingEngine(model, params, batch_size=args.batch,
-                        max_seq=args.max_seq,
-                        prefill_chunk=args.prefill_chunk,
-                        prefill_budget=args.prefill_budget,
-                        tracer=tracer)
+    print(f"compile cache: {compile_cache.enable()}")
+    mode = "reduced" if args.reduced else "published"
+    batch = args.batch or DEFAULT_SHAPE[mode]["batch"]
+    max_seq = args.max_seq or DEFAULT_SHAPE[mode]["max_seq"]
+    cfg, model, params = load_model(args.arch, reduced=args.reduced)
+    eng, _, plan = build_engine(model, params, batch=batch,
+                                max_seq=max_seq,
+                                prefill_chunk=args.prefill_chunk,
+                                prefill_budget=args.prefill_budget,
+                                tracer=tracer)
+    print(describe_plan(plan))
 
     sched = Scheduler(eng, policy=args.policy,
                       prefill_budget=args.prefill_budget)
@@ -83,7 +192,9 @@ def main() -> None:
                             max_new_tokens=args.max_new))
 
     print(f"serving {args.requests} requests on {args.arch} "
-          f"({cfg.family}, reduced) — engine batch {args.batch}, "
+          f"({cfg.family}, {mode}: {cfg.n_layers} x {cfg.d_model}, "
+          f"{jax.numpy.dtype(cfg.dtype).name}) on "
+          f"{jax.devices()[0].device_kind} — engine batch {batch}, "
           f"policy {args.policy}"
           + (" — async streaming loop" if args.stream else ""))
     if args.stream:

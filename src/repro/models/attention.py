@@ -178,6 +178,15 @@ def verify_decode_attention(q, k_cache, v_cache, base, *, sliding_window=0):
     return _combine(scores, v_cache, q.shape[2])
 
 
+def _gather_pool(pool, block_table):
+    """Gather each row's blocks through its table into a stripe:
+    (num_blocks, Hkv, bs, hd) -> (B, max_blocks*bs, Hkv, hd)."""
+    B, max_blocks = block_table.shape
+    _, Hkv, bs, hd = pool.shape
+    g = jnp.swapaxes(pool[block_table], 2, 3)         # (B,mb,bs,Hkv,hd)
+    return g.reshape(B, max_blocks * bs, Hkv, hd)
+
+
 def paged_verify_attention(q, pool_k, pool_v, k_new, v_new, block_table,
                            cache_len, n_write, *, sliding_window: int = 0,
                            use_kernel: bool = False):
@@ -210,7 +219,7 @@ def paged_verify_attention(q, pool_k, pool_v, k_new, v_new, block_table,
     once and applies the same causal-in-window mask.
     """
     from repro.serve.blocks import SCRATCH_BLOCK
-    bs = pool_k.shape[1]
+    bs = pool_k.shape[2]
     B, S = q.shape[:2]
     base = jnp.asarray(cache_len, jnp.int32).reshape(-1)    # (B,)
     pos = base[:, None] + jnp.arange(S)[None, :]            # (B,S)
@@ -219,18 +228,18 @@ def paged_verify_attention(q, pool_k, pool_v, k_new, v_new, block_table,
                                                 jnp.int32).reshape(-1, 1)
     phys = jnp.where(safe, block_table[rows[:, None], pos // bs],
                      SCRATCH_BLOCK)                         # (B,S)
-    pool_k = pool_k.at[phys, pos % bs].set(k_new.astype(pool_k.dtype))
-    pool_v = pool_v.at[phys, pos % bs].set(v_new.astype(pool_v.dtype))
-    max_blocks = block_table.shape[1]
+    # head-major pool: the (B,S) advanced indices around the head slice
+    # address (B, S, Hkv, hd) sites, the layout of k_new/v_new
+    pool_k = pool_k.at[phys, :, pos % bs].set(k_new.astype(pool_k.dtype))
+    pool_v = pool_v.at[phys, :, pos % bs].set(v_new.astype(pool_v.dtype))
     if use_kernel:
         from repro.kernels.paged_attention.ops import (
             paged_window_attention as _window_kernel)
         out, _ = _window_kernel(q, pool_k, pool_v, block_table, base,
                                 sliding_window=sliding_window)
         return out.reshape(B, S, -1), pool_k, pool_v
-    gk = pool_k[block_table].reshape(B, max_blocks * bs, *pool_k.shape[2:])
-    gv = pool_v[block_table].reshape(B, max_blocks * bs, *pool_v.shape[2:])
-    out = verify_decode_attention(q, gk, gv, base,
+    out = verify_decode_attention(q, _gather_pool(pool_k, block_table),
+                                  _gather_pool(pool_v, block_table), base,
                                   sliding_window=sliding_window)
     return out, pool_k, pool_v
 
@@ -240,8 +249,9 @@ def paged_decode_attention(q, pool_k, pool_v, k_new, v_new, block_table,
                            use_kernel: bool = False):
     """Decode one token per sequence against a shared KV **block pool**.
 
-    q/k_new/v_new: (B, 1, H*, hd); pool_k/pool_v: (num_blocks, bs, Hkv,
-    hd); block_table: (B, max_blocks) int32; cache_len: (B,) tokens
+    q/k_new/v_new: (B, 1, H*, hd); pool_k/pool_v: (num_blocks, Hkv, bs,
+    hd), head-major so the kernel's per-head block tile is contiguous;
+    block_table: (B, max_blocks) int32; cache_len: (B,) tokens
     already cached per row. Row b's logical position j lives at
     ``(block_table[b, j // bs], j % bs)`` — the new token's K/V is
     scattered there first (owned blocks are disjoint across rows — with
@@ -266,22 +276,24 @@ def paged_decode_attention(q, pool_k, pool_v, k_new, v_new, block_table,
       bit-exact (f32) against its streaming jnp oracle by the
       differential grids in ``tests/test_kernels.py``.
     """
-    bs = pool_k.shape[1]
+    bs = pool_k.shape[2]
     idx = jnp.asarray(cache_len, jnp.int32).reshape(-1)     # (B,)
     rows = jnp.arange(idx.shape[0])
     phys = block_table[rows, idx // bs]                     # (B,)
-    pool_k = pool_k.at[phys, idx % bs].set(k_new[:, 0].astype(pool_k.dtype))
-    pool_v = pool_v.at[phys, idx % bs].set(v_new[:, 0].astype(pool_v.dtype))
-    B, max_blocks = block_table.shape
+    pool_k = pool_k.at[phys, :, idx % bs].set(
+        k_new[:, 0].astype(pool_k.dtype))
+    pool_v = pool_v.at[phys, :, idx % bs].set(
+        v_new[:, 0].astype(pool_v.dtype))
+    B = block_table.shape[0]
     if use_kernel:
         from repro.kernels.paged_attention.ops import (
             paged_decode_attention as _paged_kernel)
         out, _ = _paged_kernel(q[:, 0], pool_k, pool_v, block_table, idx + 1,
                                sliding_window=sliding_window)
         return out.reshape(B, 1, -1), pool_k, pool_v
-    gk = pool_k[block_table].reshape(B, max_blocks * bs, *pool_k.shape[2:])
-    gv = pool_v[block_table].reshape(B, max_blocks * bs, *pool_v.shape[2:])
-    out = decode_attention(q, gk, gv, idx + 1, sliding_window=sliding_window)
+    out = decode_attention(q, _gather_pool(pool_k, block_table),
+                           _gather_pool(pool_v, block_table), idx + 1,
+                           sliding_window=sliding_window)
     return out, pool_k, pool_v
 
 
@@ -292,7 +304,7 @@ def attention_block(x, p, cfg, *, mode: str, cache=None, cache_len=None,
     """Full attention sub-block incl. output proj. Returns (out, new_cache).
 
     cache: dict(k=(B,T,Hkv,hd), v=(B,T,Hkv,hd)) or None — or, with
-    ``block_table`` set, the paged pool dict(k=(num_blocks,bs,Hkv,hd), ...).
+    ``block_table`` set, the paged pool dict(k=(num_blocks,Hkv,bs,hd), ...).
     In decode mode, ``x`` with more than one token per row is a
     **multi-token window** — a speculative verify window or a chunked
     prefill window: the S tokens write K/V at positions

@@ -250,8 +250,8 @@ class Model:
 
         block_table: optional (B, max_blocks) int32 — paged-KV mode. The
         cache leaves are then a shared block pool (L, num_blocks,
-        block_size, Hkv, hd) and row b's logical position j resolves to
-        (block_table[b, j // block_size], j % block_size). Requires a
+        Hkv, block_size, hd) and row b's logical position j resolves to
+        (block_table[b, j // block_size], :, j % block_size). Requires a
         (B,) cache_len vector. ``paged_kernel`` switches the paged read
         from the transient jnp gather to the in-place Pallas kernel
         (``kernels.paged_attention``; interpret mode off-TPU)."""
@@ -371,18 +371,21 @@ class Model:
         return cache
 
     def init_paged_cache(self, num_blocks: int, block_size: int):
-        """Zeroed block-pool KV: ``(L, num_blocks, block_size, Hkv, hd)``
+        """Zeroed block-pool KV: ``(L, num_blocks, Hkv, block_size, hd)``
         per leaf, shared by every slot through a per-slot block table
-        (see ``repro.serve.blocks``). Only pure-attention families page;
-        recurrent state is O(1) in sequence length and keeps the
-        per-slot fixed cache."""
+        (see ``repro.serve.blocks``). Head-major, so one KV head of one
+        block is a contiguous ``(block_size, hd)`` tile: the paged
+        kernel's K/V block then meets the TPU's (8, 128) tiling rule
+        (``kernels/paged_attention/kernel.py``). Only pure-attention
+        families page; recurrent state is O(1) in sequence length and
+        keeps the per-slot fixed cache."""
         cfg = self.cfg
         kind = transformer.block_kind(cfg)
         if kind not in ("dense", "moe"):
             raise ValueError(f"paged KV unsupported for family {kind!r} "
                              "(recurrent/cross-attn leaves are not paged)")
         L, Hkv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
-        shape = (L, num_blocks, block_size, Hkv, hd)
+        shape = (L, num_blocks, Hkv, block_size, hd)
         return {"k": jnp.zeros(shape, cfg.dtype),
                 "v": jnp.zeros(shape, cfg.dtype)}
 

@@ -154,7 +154,6 @@ def moe_decode_ffn(x, p, cfg, plan):
     weight bytes to a few token-sized buffers.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     B, S, d = x.shape
     mesh, maxis = plan.mesh, plan.model_axis
     fsdp = tuple(a for a in (plan.weight_fsdp if isinstance(
@@ -228,10 +227,10 @@ def moe_decode_ffn(x, p, cfg, plan):
         aux = jax.lax.pmean(aux, b_axes + (maxis,))
         return out, aux
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(b_ax, None, None), wspec),
-                   out_specs=(P(b_ax, None, None), P()),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(b_ax, None, None), wspec),
+                       out_specs=(P(b_ax, None, None), P()),
+                       check_vma=False)
     return fn(x, p)
 
 
@@ -249,7 +248,6 @@ def moe_ffn(x, p, cfg, plan=None):
         return moe_decode_ffn(x, p, cfg, plan)
 
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     mesh = plan.mesh
     maxis = plan.model_axis               # "model"
     n_model = plan.axis_size(maxis)
@@ -285,8 +283,8 @@ def moe_ffn(x, p, cfg, plan=None):
             return out.reshape(Bb, Sb, d), aux
 
     wspec_tree = {k_: wspec[k_] for k_ in p}
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(xspec, wspec_tree),
-                   out_specs=(xspec, P()),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(xspec, wspec_tree),
+                       out_specs=(xspec, P()),
+                       check_vma=False)
     return fn(x, p)

@@ -3,7 +3,7 @@
 One pool owns ``num_blocks`` interchangeable KV blocks of ``block_size``
 tokens each (the device tensors live in the engine as
 ``model.init_paged_cache(num_blocks, block_size)`` — shape
-``(L, num_blocks, block_size, Hkv, hd)`` per leaf). A sequence's KV is
+``(L, num_blocks, Hkv, block_size, hd)`` per leaf). A sequence's KV is
 scattered over whichever physical blocks were free at admission/growth
 time; logical token ``j`` of a slot lives at
 ``(table[j // block_size], j % block_size)``. Contiguity is never

@@ -98,6 +98,7 @@ co-batching).
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -180,6 +181,90 @@ class _Tick:
         if fn is None:
             raise RuntimeError("tick already committed")
         return fn()
+
+
+# ------------------------------------------------------ paged programs
+def paged_admit_step(model, plan, block_size, p, tokens, last_idx, temps,
+                     top_ks, seeds, ctrs):
+    """Batched prefill for the pool path: returns the first token per
+    row (+ logprob) and the prefill KV padded (with zeros, never
+    attended) to a block_size multiple so every logical block slices
+    full."""
+    logits, pref = model.prefill(p, {"tokens": tokens}, plan,
+                                 last_idx=last_idx)
+    pad = (-tokens.shape[1]) % block_size
+    if pad:
+        pref = {key: jnp.pad(pref[key],
+                             ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+                for key in pref}
+    nxt, logp = sampling.sample(logits[:, -1, :], temps, top_ks, seeds,
+                                ctrs)
+    return nxt, logp, pref
+
+
+def paged_decode_step(model, plan, kernel, p, tok, caches, lengths, table,
+                      temps, top_ks, seeds, ctrs):
+    logits, caches = model.decode_step(p, tok, caches, lengths, plan,
+                                       block_table=table,
+                                       paged_kernel=kernel)
+    nxt, logp = sampling.sample(logits[:, -1, :], temps, top_ks, seeds,
+                                ctrs)
+    return nxt, logp, caches
+
+
+def paged_chunk_step(model, plan, kernel, p, toks, caches, lengths, table,
+                     n_write, last_idx, temps, top_ks, seeds, ctrs):
+    """Paged chunk window: scatter through the block table, diverted to
+    scratch past each row's fed count (pads, parked riders)."""
+    logits, caches = model.prefill(p, {"tokens": toks}, plan,
+                                   cache=caches, cache_len=lengths,
+                                   block_table=table, paged_kernel=kernel,
+                                   n_write=n_write, last_idx=last_idx)
+    nxt, logp = sampling.sample(logits[:, 0, :], temps, top_ks, seeds,
+                                ctrs)
+    return nxt, logp, caches
+
+
+def paged_programs(model, *, block_size: int, use_kernel: bool,
+                   plan=None) -> dict:
+    """The paged engine's three serving programs, jitted: ``admit``
+    (batched prefill of first chunks), ``decode`` (one token per slot)
+    and ``chunk`` (a multi-token chunk window). Decode and chunk donate
+    the pool. One definition serves the engine, its ahead-of-time
+    compile (:meth:`ServingEngine.compile_programs`) and the compile-only
+    tests."""
+    def bind(fn, *static):
+        # keep the step's own name on the jitted program (traces, HLO)
+        return functools.update_wrapper(functools.partial(fn, model, plan,
+                                                          *static), fn)
+    kernel = bool(use_kernel)
+    return {
+        "admit": jax.jit(bind(paged_admit_step, block_size)),
+        "decode": jax.jit(bind(paged_decode_step, kernel),
+                          donate_argnums=(2,)),
+        "chunk": jax.jit(bind(paged_chunk_step, kernel),
+                         donate_argnums=(2,)),
+    }
+
+
+def paged_program_args(params, caches, *, batch: int, blocks_per_slot: int,
+                       width: int, sharding=None) -> dict:
+    """Arguments for :func:`paged_programs` at one serving shape:
+    ``batch`` slots, ``width``-token chunk windows, and an admission of
+    ``batch`` first chunks ``width`` wide. ``params`` and ``caches`` pass
+    through (arrays or shape stand-ins); the rest are
+    ``ShapeDtypeStruct`` stand-ins on ``sharding``."""
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    rows = sds((batch,))
+    samp = (sds((batch,), jnp.float32), rows, rows, rows)
+    table = sds((batch, blocks_per_slot))
+    return {
+        "admit": (params, sds((batch, width)), rows, *samp),
+        "decode": (params, sds((batch, 1)), caches, rows, table, *samp),
+        "chunk": (params, sds((batch, width)), caches, rows, table, rows,
+                  rows, *samp),
+    }
 
 
 class ServingEngine:
@@ -333,33 +418,20 @@ class ServingEngine:
                                         seeds, ctrs)
             return nxt, logp, caches
 
-        def prefill_paged(p, tokens, last_idx, temps, top_ks, seeds, ctrs):
-            """Batched prefill for the pool path: returns the first token
-            per row (+ logprob) and the prefill KV padded (with zeros,
-            never attended) to a block_size multiple so every logical
-            block slices full."""
-            logits, pref = model.prefill(p, {"tokens": tokens}, plan,
-                                         last_idx=last_idx)
-            pad = (-tokens.shape[1]) % block_size
-            if pad:
-                pref = {key: jnp.pad(pref[key],
-                                     ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-                        for key in pref}
-            nxt, logp = sampling.sample(logits[:, -1, :], temps, top_ks,
-                                        seeds, ctrs)
-            return nxt, logp, pref
-
         def write_block(caches, pref, row, start, phys):
             """Copy one logical block of row ``row`` of the prefill KV
             (token window [start, start+block_size)) into physical pool
             block ``phys`` — a device-side dynamic_update_slice on the
-            donated pool, same no-host-copy property as the stripe path."""
+            donated pool, same no-host-copy property as the stripe path.
+            The prefill KV is token-major (L, rows, S, Hkv, hd); the
+            pool is head-major (L, num_blocks, Hkv, bs, hd)."""
             for key in caches:
                 L = pref[key].shape[0]
                 chunk = jax.lax.dynamic_slice(
                     pref[key], (jnp.int32(0), row, start, jnp.int32(0),
                                 jnp.int32(0)),
                     (L, 1, block_size) + pref[key].shape[3:])
+                chunk = jnp.swapaxes(chunk, 2, 3)
                 caches[key] = jax.lax.dynamic_update_slice(
                     caches[key], chunk.astype(caches[key].dtype),
                     (jnp.int32(0), phys) + (jnp.int32(0),) * 3)
@@ -372,15 +444,6 @@ class ServingEngine:
             return nxt, logp, caches
 
         kernel_flag = self.use_kernel
-
-        def decode_paged(p, tok, caches, lengths, table, temps, top_ks,
-                         seeds, ctrs):
-            logits, caches = model.decode_step(p, tok, caches, lengths, plan,
-                                               block_table=table,
-                                               paged_kernel=kernel_flag)
-            nxt, logp = sampling.sample(logits[:, -1, :], temps, top_ks,
-                                        seeds, ctrs)
-            return nxt, logp, caches
 
         def verify(p, toks, caches, lengths, dprobs, proposed, n_spec,
                    temps, top_ks, seeds, ctrs):
@@ -421,21 +484,6 @@ class ServingEngine:
                                         seeds, ctrs)
             return nxt, logp, caches
 
-        def chunk_paged(p, toks, caches, lengths, table, n_write,
-                        last_idx, temps, top_ks, seeds, ctrs):
-            """Paged chunk window: scatter through the block table,
-            diverted to scratch past each row's fed count (pads, parked
-            riders)."""
-            logits, caches = model.prefill(p, {"tokens": toks}, plan,
-                                           cache=caches, cache_len=lengths,
-                                           block_table=table,
-                                           paged_kernel=kernel_flag,
-                                           n_write=n_write,
-                                           last_idx=last_idx)
-            nxt, logp = sampling.sample(logits[:, 0, :], temps, top_ks,
-                                        seeds, ctrs)
-            return nxt, logp, caches
-
         def copy_block(caches, src, dst):
             """Copy-on-write: duplicate physical block ``src`` into the
             freshly-allocated ``dst`` on device (all layers, one jitted
@@ -452,15 +500,19 @@ class ServingEngine:
             return caches
 
         self._admit = jax.jit(admit, donate_argnums=(1,))
-        self._prefill_paged = jax.jit(prefill_paged)
         self._write_block = jax.jit(write_block, donate_argnums=(0,))
         self._copy_block = jax.jit(copy_block, donate_argnums=(0,))
-        self._decode = jax.jit(decode_paged if self.paged else decode,
-                               donate_argnums=(2,))
         self._verify = jax.jit(verify_paged if self.paged else verify,
                                donate_argnums=(2,))
-        self._chunk_fn = jax.jit(chunk_paged if self.paged else chunk,
-                                 donate_argnums=(2,))
+        if self.paged:
+            progs = paged_programs(model, plan=plan, block_size=block_size,
+                                   use_kernel=self.use_kernel)
+            self._prefill_paged = progs["admit"]
+            self._decode = progs["decode"]
+            self._chunk_fn = progs["chunk"]
+        else:
+            self._decode = jax.jit(decode, donate_argnums=(2,))
+            self._chunk_fn = jax.jit(chunk, donate_argnums=(2,))
         self.metrics = {"prefills": 0, "prefill_batches": 0,
                         "decode_steps": 0, "completed": 0,
                         "stop_token_exits": 0, "slot_reuses": 0,
@@ -482,6 +534,24 @@ class ServingEngine:
                         # Prometheus tells fused-window from
                         # single-token launches by these two series
                         "kernel_windows": 0, "kernel_positions": 0}
+
+    def compile_programs(self) -> dict:
+        """Compile the paged serving programs ahead of time at this
+        engine's shapes: decode, a full-width chunk window, and an
+        admission of ``batch_size`` first chunks. Later calls at these
+        shapes reuse the executables. Returns ``{name: compiled}``, whose
+        ``memory_analysis()`` and ``as_text()`` the launcher and the chip
+        smoke read."""
+        if not self.paged:
+            raise ValueError("compile_programs covers the paged programs")
+        width = _bucket(self.prefill_chunk or self.max_seq, self.max_seq)
+        args = paged_program_args(self.params, self.caches, batch=self.B,
+                                  blocks_per_slot=self.blocks_per_slot,
+                                  width=width)
+        progs = {"admit": self._prefill_paged, "decode": self._decode,
+                 "chunk": self._chunk_fn}
+        return {name: fn.lower(*args[name]).compile()
+                for name, fn in progs.items()}
 
     # ---------------------------------------------------------- telemetry
     def _trace_admit(self, req: Request, slot: int, *,

@@ -143,8 +143,8 @@ def _paged_case(B, Hq, Hkv, hd, bs, max_blocks, dt, *, seed=0, full=False):
     nb = B * max_blocks + 2
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(ks[0], (B, Hq, hd), dt)
-    pool_k = jax.random.normal(ks[1], (nb, bs, Hkv, hd), dt)
-    pool_v = jax.random.normal(ks[2], (nb, bs, Hkv, hd), dt)
+    pool_k = jax.random.normal(ks[1], (nb, Hkv, bs, hd), dt)
+    pool_v = jax.random.normal(ks[2], (nb, Hkv, bs, hd), dt)
     rng = np.random.default_rng(seed + B * 1000 + hd)
     free = list(rng.permutation(np.arange(1, nb)))
     lens = np.zeros(B, np.int32)
@@ -278,8 +278,8 @@ def _window_case(B, S, Hq, Hkv, hd, bs, max_blocks, dt, *, seed=0):
     nb = B * max_blocks + 2
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(ks[0], (B, S, Hq, hd), dt)
-    pool_k = jax.random.normal(ks[1], (nb, bs, Hkv, hd), dt)
-    pool_v = jax.random.normal(ks[2], (nb, bs, Hkv, hd), dt)
+    pool_k = jax.random.normal(ks[1], (nb, Hkv, bs, hd), dt)
+    pool_v = jax.random.normal(ks[2], (nb, Hkv, bs, hd), dt)
     rng = np.random.default_rng(seed + B * 1000 + S * 100 + hd)
     free = list(rng.permutation(np.arange(1, nb)))
     base = np.zeros(B, np.int32)

@@ -23,8 +23,9 @@ def run_in_subprocess(body: str) -> dict:
         import jax.numpy as jnp
         import numpy as np
     """) + textwrap.dedent(body)
-    env = dict(os.environ, PYTHONPATH=SRC)
-    env.pop("JAX_PLATFORMS", None)
+    # forced host devices exist only on the CPU backend; never let the
+    # child reach for an accelerator the parent may hold
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, env=env, timeout=420)
     assert out.returncode == 0, out.stderr[-3000:]

@@ -96,8 +96,10 @@ GRID = {
 @pytest.mark.parametrize("config", list(GRID))
 def test_async_streams_bit_identical_to_sync_drain(stack, config):
     """Staggered open-loop arrivals through the async loop emit, per
-    request, exactly the token/logprob stream a synchronous closed-loop
-    drain emits — on every engine config, greedy and sampled."""
+    request, exactly the token stream a synchronous closed-loop drain
+    emits, with logprobs equal to float tolerance — on every engine
+    config, greedy and sampled. What the loop streams equals its own
+    reply bit for bit."""
     cfg, model, params = stack
     kw, lens = GRID[config]
     if kw == "SPEC":
@@ -149,15 +151,15 @@ def test_async_streams_bit_identical_to_sync_drain(stack, config):
         assert toks == reply["tokens"] == ref_done[r.rid].out_tokens, \
             (config, r.rid)
         assert lps == reply["logprobs"], (config, r.rid)
-        if config in ("shared_prefix", "chunked"):
-            # different arrival patterns change which XLA program computes
-            # the prompt-final logits (chunk window vs prefill gather, and
-            # what co-batches with it) — tokens stay bit-exact, logprobs
-            # to float tolerance (the test_chunked.py contract)
-            np.testing.assert_allclose(lps, ref_done[r.rid].out_logprobs,
-                                       rtol=2e-5, atol=2e-5)
-        else:
-            assert lps == ref_done[r.rid].out_logprobs, (config, r.rid)
+        # Staggered arrivals change which compiled program computes a
+        # request's logits (admission bucket, co-batched rows, chunk
+        # window vs prefill): XLA compiles each shape on its own and may
+        # fuse and order float reductions differently, so the last
+        # digits of a logprob can move (observed: 1.4e-6 on jax 0.9.0).
+        # Tokens stay bit-exact on every config; logprobs hold to the
+        # float tolerance of the test_chunked.py contract.
+        np.testing.assert_allclose(lps, ref_done[r.rid].out_logprobs,
+                                   rtol=2e-5, atol=2e-5)
         assert len(toks) == 4
     if eng.paged:
         eng.pool.check()                   # raises on invariant breach
