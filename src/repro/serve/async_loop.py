@@ -51,7 +51,7 @@ from collections import deque
 from repro.core.services import RequestError, ServiceError
 from repro.serve.engine import Request
 from repro.serve.scheduler import Scheduler
-from repro.serve.telemetry import PID_LOOP
+from repro.serve.telemetry import phase
 
 
 class StreamHandle:
@@ -114,9 +114,17 @@ class AsyncServeLoop:
             "ticks": 0,                 # committed device steps
             "planned_ahead_ticks": 0,   # ticks that planned >=1 candidate
             "planned": 0,               # total candidates planned in-flight
+            # host seconds per phase of run_once (telemetry.phase)
+            "cancel_s": 0.0,            # applying cancels
+            "fill_s": 0.0,              # intake, admission, sheds
+            "dispatch_s": 0.0,          # planning + launching the step
             "plan_time_s": 0.0,         # host time inside the overlap window
-            "commit_wait_s": 0.0,       # host time blocked on the device
+            "commit_wait_s": 0.0,       # device wait + commit bookkeeping
+            "account_s": 0.0,           # scheduler stats of the finished
+            "emit_s": 0.0,              # streaming tokens, resolving replies
+            "outside_s": 0.0,           # the caller's time between calls
         }
+        self._left = None               # clock when run_once last returned
 
     # ------------------------------------------------------------ intake
     def submit(self, req: Request, on_token=None) -> StreamHandle:
@@ -229,64 +237,56 @@ class AsyncServeLoop:
         (plan-ahead window) → commit → account → emit → resolve.
         Returns False when there was nothing to do.
 
-        With a tracer on the engine, every phase lands on the trace's
-        serve-loop track as a span — the plan-window and commit-wait
-        spans measure the dispatch/commit overlap directly (host work
-        hidden vs. time blocked on the device). Timestamps come from
-        the loop's clock, so a VirtualClock-driven pump emits a
+        Every phase runs under the telemetry phase timer: it adds to its
+        counter in ``self.metrics``, lands on the engine tracer's
+        serve-loop track as a span, and on a profiler trace as a
+        ``serve.<phase>`` host event. The plan-window and commit-wait
+        phases measure the dispatch/commit overlap directly (host work
+        hidden vs. time blocked on the device), and ``outside_s`` the
+        caller's time between two calls. Timestamps come from the
+        loop's clock, so a VirtualClock-driven pump emits a
         deterministic timeline."""
-        tr = self.engine.tracer
-        trace = tr.enabled
+        eng, m, clock, tr = self.engine, self.metrics, self.clock, \
+            self.engine.tracer
         with self._lock:
-            tp = self.clock() if trace else 0.0
-            self._apply_cancels()
-            if trace:
-                now = self.clock()
-                tr.complete("apply-cancels", tp, now - tp, pid=PID_LOOP)
-                tp = now
-            self._admit()
-            self.scheduler.fill()
-            self._collect_shed()
-            if trace:
-                now = self.clock()
-                tr.complete("fill", tp, now - tp, pid=PID_LOOP)
-                tp = now
-            eng = self.engine
+            with phase("apply-cancels", clock, m, "cancel_s", tr) as p:
+                self._apply_cancels()
+            if self._left is not None:
+                m["outside_s"] += p.start - self._left
+            with phase("fill", clock, m, "fill_s", tr) as p:
+                self._admit()
+                self.scheduler.fill()
+                self._collect_shed()
+            self._left = p.end
             if not (eng.active or eng.waiting or eng._finished_at_admit):
+                eng.end_launch_chain()
                 return False
-            tick = eng.dispatch_step()
+            with phase("dispatch", clock, m, "dispatch_s", tr) as p:
+                tick = eng.dispatch_step()
+                p.args = {"active": eng.active}
             # ---- overlap window: the device step is in flight --------
-            t0 = self.clock()
-            if trace:
-                tr.complete("dispatch", tp, t0 - tp, pid=PID_LOOP,
-                            args={"active": eng.active})
-            self._admit()               # late arrivals reach this plan
-            planned = self.scheduler.plan_ahead(self.plan_limit)
-            t1 = self.clock()
+            with phase("plan-window", clock, m, "plan_time_s", tr) as p:
+                self._admit()           # late arrivals reach this plan
+                planned = self.scheduler.plan_ahead(self.plan_limit)
+                p.args = {"planned": planned}
             # ----------------------------------------------------------
-            done = tick.commit()
-            t2 = self.clock()
-            if trace:
-                tr.complete("plan-window", t0, t1 - t0, pid=PID_LOOP,
-                            args={"planned": planned})
-                tr.complete("commit-wait", t1, t2 - t1, pid=PID_LOOP)
-            self.scheduler.account(done)
-            self.metrics["ticks"] += 1
-            self.metrics["planned"] += planned
+            with phase("commit-wait", clock, m, "commit_wait_s", tr):
+                done = tick.commit()
+            with phase("account", clock, m, "account_s", tr):
+                self.scheduler.account(done)
+            m["ticks"] += 1
+            m["planned"] += planned
             if planned:
-                self.metrics["planned_ahead_ticks"] += 1
-            self.metrics["plan_time_s"] += t1 - t0
-            self.metrics["commit_wait_s"] += t2 - t1
-            tp = self.clock() if trace else 0.0
-            self._emit()
-            if trace:
-                tr.complete("emit", tp, self.clock() - tp, pid=PID_LOOP,
-                            args={"finished": len(done)})
-            for r in done:
-                handle = self._live.pop(r.rid, None)
-                if handle is not None and not handle.done:
-                    handle.reply = self._reply(r)
-                    handle._finish()
+                m["planned_ahead_ticks"] += 1
+            with phase("emit", clock, m, "emit_s", tr,
+                       args={"finished": len(done)}) as p:
+                self._emit()
+                for r in done:
+                    handle = self._live.pop(r.rid, None)
+                    if handle is not None and not handle.done:
+                        handle.reply = self._reply(r)
+                        handle._finish()
+            self._left = p.end
             return True
 
     def wait(self, handle: StreamHandle) -> dict:

@@ -101,6 +101,7 @@ from __future__ import annotations
 import functools
 import time
 from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 import jax
@@ -111,7 +112,8 @@ from repro.serve import sampling
 from repro.serve.blocks import BlockPool
 from repro.serve.sampling import GREEDY, SamplingParams
 from repro.serve.spec import DraftRunner
-from repro.serve.telemetry import NOOP, PID_LOOP, PID_POOL, PID_REQUESTS
+from repro.serve.telemetry import (NOOP, PID_ENGINE, PID_LOOP, PID_POOL,
+                                   PID_REQUESTS, phase)
 
 _MIN_BUCKET = 8
 # default chunk for chunked prefill (tokens per slot per chunk step):
@@ -533,7 +535,23 @@ class ServingEngine:
                         # (1 per active row on a plain decode tick) —
                         # Prometheus tells fused-window from
                         # single-token launches by these two series
-                        "kernel_windows": 0, "kernel_positions": 0}
+                        "kernel_windows": 0, "kernel_positions": 0,
+                        # host seconds per engine phase (telemetry.phase):
+                        # launching a step (argument transfer, enqueue),
+                        # blocking on its result, and admission, with the
+                        # part of it blocked on the admit program
+                        "launch_s": 0.0, "device_wait_s": 0.0,
+                        "admit_s": 0.0, "admit_wait_s": 0.0,
+                        "write_blocks": 0,
+                        # one step's result on the host to the next
+                        # step's launch returning, over consecutive steps
+                        # only: the time no serving step is queued
+                        "launch_gap_s": 0.0, "launch_gaps": 0,
+                        # admission to first generated token
+                        "first_tokens": 0, "admit_to_first_s": 0.0}
+        # when the last step's result reached the host; None once the
+        # chain of consecutive steps is broken
+        self._result_at = None
 
     def compile_programs(self) -> dict:
         """Compile the paged serving programs ahead of time at this
@@ -554,6 +572,37 @@ class ServingEngine:
                 for name, fn in progs.items()}
 
     # ---------------------------------------------------------- telemetry
+    def _phase(self, name: str, key: str) -> phase:
+        """An engine phase: counted in ``metrics[key]``, spanned on the
+        tracer's serve-engine track, a ``serve.<name>`` profiler event."""
+        return phase(name, self.clock, self.metrics, key, self.tracer,
+                     pid=PID_ENGINE)
+
+    @contextmanager
+    def _launch(self):
+        """Launch one serving step (argument transfer and enqueue; JAX
+        returns before the device finishes) and count the launch gap
+        since the previous step's result reached the host."""
+        with self._phase("launch", "launch_s") as p:
+            yield
+        if self._result_at is not None:
+            self.metrics["launch_gap_s"] += p.end - self._result_at
+            self.metrics["launch_gaps"] += 1
+            self._result_at = None
+
+    def _result(self, *arrays) -> list:
+        """Block on a serving step's outputs and bring them to the host;
+        the next launch's gap runs from here."""
+        with self._phase("device-wait", "device_wait_s") as p:
+            out = [np.asarray(a) for a in arrays]
+        self._result_at = p.end
+        return out
+
+    def end_launch_chain(self) -> None:
+        """The caller found nothing to step: the next launch follows an
+        idle spell, not a step, and counts no launch gap."""
+        self._result_at = None
+
     def _trace_admit(self, req: Request, slot: int, *,
                      shared: bool = False, chunked: bool = False) -> None:
         """Stamp the admission (first one only: a preempted request's
@@ -574,6 +623,10 @@ class ServingEngine:
         if req.first_token_s is not None:
             return
         req.first_token_s = self.clock()
+        if req.admitted_s is not None:
+            self.metrics["first_tokens"] += 1
+            self.metrics["admit_to_first_s"] += \
+                req.first_token_s - req.admitted_s
         if self.tracer.enabled:
             self.tracer.instant("first_token", pid=PID_REQUESTS,
                                 tid=req.rid, ts=req.first_token_s)
@@ -894,6 +947,10 @@ class ServingEngine:
         its un-shared suffix, fed through the normal decode steps.
         Returns how many of the *caller's* requests were admitted (a
         prefix of ``reqs``)."""
+        with self._phase("admit", "admit_s"):
+            return self._add_requests(reqs)
+
+    def _add_requests(self, reqs: list) -> int:
         for r in reqs:
             if len(r.prompt) > self.max_seq:
                 raise ValueError(f"request {r.rid}: prompt length "
@@ -1026,7 +1083,8 @@ class ServingEngine:
                 nxt, logp, self.caches = self._admit(
                     self.params, self.caches, jnp.asarray(toks),
                     jnp.asarray(last), jnp.asarray(slots), *samp)
-            nxt, logp = np.asarray(nxt), np.asarray(logp)
+            with self._phase("admit-wait", "admit_wait_s"):
+                nxt, logp = np.asarray(nxt), np.asarray(logp)
             for j, (req, slot, n0) in enumerate(members):
                 eff = self._eff_prompt(req)
                 P = len(eff)
@@ -1149,8 +1207,10 @@ class ServingEngine:
             if n0 < P:
                 self.metrics["chunked_admissions"] += 1
             else:
-                req.out_tokens.append(int(np.asarray(nxt)[0]))
-                req.out_logprobs.append(float(np.asarray(logp)[0]))
+                with self._phase("admit-wait", "admit_wait_s"):
+                    nxt, logp = np.asarray(nxt), np.asarray(logp)
+                req.out_tokens.append(int(nxt[0]))
+                req.out_logprobs.append(float(logp[0]))
                 self._note_first_token(req)
         else:
             self.slot_blocks[slot] = list(blocks)
@@ -1204,6 +1264,7 @@ class ServingEngine:
         bs = self.block_size
         parent = self.pool.ROOT if self.prefix_sharing else False
         reg_pos = 0
+        self.metrics["write_blocks"] += n_blk
         for i, phys in enumerate(blocks):
             self.caches = self._write_block(
                 self.caches, pref, np.int32(row),
@@ -1502,17 +1563,19 @@ class ServingEngine:
             self.metrics["kernel_windows"] += 1
             self.metrics["kernel_positions"] += int(
                 sum(n_write[i] for i in active))
-        if self.paged:
-            a, out_toks, lps, self.caches = self._verify(
-                self.params, jnp.asarray(toks), self.caches,
-                jnp.asarray(self.slot_len), jnp.asarray(self.block_table),
-                jnp.asarray(n_write), dprobs, jnp.asarray(proposed), ns,
-                temps, top_ks, seeds, ctrs)
-        else:
-            a, out_toks, lps, self.caches = self._verify(
-                self.params, jnp.asarray(toks), self.caches,
-                jnp.asarray(self.slot_len), dprobs, jnp.asarray(proposed),
-                ns, temps, top_ks, seeds, ctrs)
+        with self._launch():
+            if self.paged:
+                a, out_toks, lps, self.caches = self._verify(
+                    self.params, jnp.asarray(toks), self.caches,
+                    jnp.asarray(self.slot_len),
+                    jnp.asarray(self.block_table), jnp.asarray(n_write),
+                    dprobs, jnp.asarray(proposed), ns, temps, top_ks,
+                    seeds, ctrs)
+            else:
+                a, out_toks, lps, self.caches = self._verify(
+                    self.params, jnp.asarray(toks), self.caches,
+                    jnp.asarray(self.slot_len), dprobs,
+                    jnp.asarray(proposed), ns, temps, top_ks, seeds, ctrs)
         self.metrics["decode_steps"] += 1
         self.metrics["verify_steps"] += 1
         return _Tick(lambda: self._commit_spec(active, n_spec, finished,
@@ -1520,8 +1583,7 @@ class ServingEngine:
 
     def _commit_spec(self, active, n_spec, finished, totals, a, out_toks,
                      lps) -> list:
-        a, out_toks, lps = np.asarray(a), np.asarray(out_toks), \
-            np.asarray(lps)
+        a, out_toks, lps = self._result(a, out_toks, lps)
         k = self.spec_k
         win_proposed = win_accepted = 0     # this verify window's totals
         for i in active:
@@ -1608,24 +1670,25 @@ class ServingEngine:
         if self.paged and self.use_kernel:
             self.metrics["kernel_windows"] += 1
             self.metrics["kernel_positions"] += sum(n_fed.values())
-        if self.paged:
-            nxt, logp, self.caches = self._chunk_fn(
-                self.params, jnp.asarray(toks), self.caches,
-                jnp.asarray(self.slot_len), jnp.asarray(self.block_table),
-                jnp.asarray(n_write), jnp.asarray(last), temps, top_ks,
-                seeds, ctrs)
-        else:
-            nxt, logp, self.caches = self._chunk_fn(
-                self.params, jnp.asarray(toks), self.caches,
-                jnp.asarray(self.slot_len), jnp.asarray(last), temps,
-                top_ks, seeds, ctrs)
+        with self._launch():
+            if self.paged:
+                nxt, logp, self.caches = self._chunk_fn(
+                    self.params, jnp.asarray(toks), self.caches,
+                    jnp.asarray(self.slot_len),
+                    jnp.asarray(self.block_table), jnp.asarray(n_write),
+                    jnp.asarray(last), temps, top_ks, seeds, ctrs)
+            else:
+                nxt, logp, self.caches = self._chunk_fn(
+                    self.params, jnp.asarray(toks), self.caches,
+                    jnp.asarray(self.slot_len), jnp.asarray(last), temps,
+                    top_ks, seeds, ctrs)
         self.metrics["decode_steps"] += 1
         self.metrics["chunk_steps"] += 1
         return _Tick(lambda: self._commit_chunk(active, n_fed, finished,
                                                 nxt, logp))
 
     def _commit_chunk(self, active, n_fed, finished, nxt, logp) -> list:
-        nxt, logp = np.asarray(nxt), np.asarray(logp)
+        nxt, logp = self._result(nxt, logp)
         for i in active:
             r = self.slot_req[i]
             c = n_fed[i]
@@ -1672,6 +1735,7 @@ class ServingEngine:
         finished, self._finished_at_admit = self._finished_at_admit, []
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
         if not active:
+            self.end_launch_chain()
             return _Tick(lambda: finished)
         # any slot past capacity would write out of bounds — finish it now
         for i in list(active):
@@ -1730,6 +1794,7 @@ class ServingEngine:
             finished.extend(self._finished_at_admit)
             self._finished_at_admit = []
         if not active:
+            self.end_launch_chain()
             return _Tick(lambda: finished)
         if self.spec_k and any(n_spec[i] > 0 for i in active):
             return self._spec_step(active, n_spec, finished)
@@ -1746,21 +1811,22 @@ class ServingEngine:
         samp = self._sampling_slots()
         if self.paged and self.use_kernel:
             self.metrics["kernel_positions"] += len(active)
-        if self.paged:
-            nxt, logp, self.caches = self._decode(
-                self.params, jnp.asarray(tok), self.caches,
-                jnp.asarray(self.slot_len), jnp.asarray(self.block_table),
-                *samp)
-        else:
-            nxt, logp, self.caches = self._decode(
-                self.params, jnp.asarray(tok), self.caches,
-                jnp.asarray(self.slot_len), *samp)
+        with self._launch():
+            if self.paged:
+                nxt, logp, self.caches = self._decode(
+                    self.params, jnp.asarray(tok), self.caches,
+                    jnp.asarray(self.slot_len),
+                    jnp.asarray(self.block_table), *samp)
+            else:
+                nxt, logp, self.caches = self._decode(
+                    self.params, jnp.asarray(tok), self.caches,
+                    jnp.asarray(self.slot_len), *samp)
         self.metrics["decode_steps"] += 1
         return _Tick(lambda: self._commit_decode(active, finished, nxt,
                                                  logp))
 
     def _commit_decode(self, active, finished, nxt, logp) -> list:
-        nxt, logp = np.asarray(nxt), np.asarray(logp)
+        nxt, logp = self._result(nxt, logp)
         for i in active:
             r = self.slot_req[i]
             self.slot_len[i] += 1

@@ -8,15 +8,23 @@ across ``engine.metrics``, ``pool.stats()``, ``SchedulerStats`` and
 whether the async loop's plan window actually overlapped device
 compute. This module is the measurement layer under every serving PR:
 
+* :class:`phase` — the timer every host phase of the serve loop and
+  the engine runs under (a tick's fill/dispatch/plan/commit/emit, the
+  engine's launch, device wait and admission). Always on: two reads of
+  the caller's clock add the phase's time to a counter in the caller's
+  metrics dict, the same reads stamp the phase's span in the
+  :class:`Tracer` when one records, and the phase runs inside a
+  ``jax.profiler.TraceAnnotation`` named ``serve.<phase>``, so under a
+  profiler trace it sits on the host plane on the device's clock.
 * :class:`Tracer` — a clock-injectable event recorder. Components emit
   **spans** (named intervals: a request's queued/prefill/decode phases,
-  a tick's fill/dispatch/plan/commit/emit phases) and **instants**
-  (admit, park, preempt, copy-on-write, shed, cancel) into a bounded
-  ring buffer; :meth:`Tracer.chrome_trace` renders the buffer as Chrome
-  trace-event JSON that Perfetto (https://ui.perfetto.dev) loads
-  directly — requests as one named track each, the serve loop's tick
-  phases as another, pool occupancy as a counter track. The clock is
-  injectable, so traces recorded under a
+  the phases above) and **instants** (admit, park, preempt,
+  copy-on-write, shed, cancel) into a bounded ring buffer;
+  :meth:`Tracer.chrome_trace` renders the buffer as Chrome trace-event
+  JSON that Perfetto (https://ui.perfetto.dev) loads directly —
+  requests as one named track each, the serve loop's tick phases and
+  the engine's as two more, pool occupancy as a counter track. The
+  clock is injectable, so traces recorded under a
   :class:`~repro.serve.clock.VirtualClock` are **deterministic**: the
   same scripted workload emits byte-identical JSON, which is what lets
   tests assert on traces at all.
@@ -25,11 +33,10 @@ compute. This module is the measurement layer under every serving PR:
   an untraced engine pays a handful of no-op attribute checks per tick
   (< 0.5 % of a step; ``bench_serving`` gates it) and the hot path
   allocates nothing.
-* :class:`MetricsRegistry` — one namespace of counters / gauges /
-  histograms with Prometheus text exposition
-  (:meth:`MetricsRegistry.prometheus_text`). Existing stats dicts
-  (``engine.metrics``, ``pool.stats()``, scheduler/loop/balancer
-  counters) plug in as **sources** — callables polled at collection
+* :class:`MetricsRegistry` — Prometheus text exposition
+  (:meth:`MetricsRegistry.prometheus_text`) of the existing stats
+  dicts (``engine.metrics``, ``pool.stats()``, scheduler/loop/balancer
+  counters), plugged in as **sources** — callables polled at collection
   time — so the registry unifies them without forking their storage;
   :func:`prometheus_text` merges many registries (one per replica,
   labelled) into one exposition, which is how ``service.py`` and
@@ -48,12 +55,15 @@ import time
 from collections import deque
 from contextlib import contextmanager
 
+import jax
+
 # Trace "process" ids: Perfetto groups tracks by pid, so the serve
-# loop's tick phases, the per-request lifecycles, and the pool's
-# occupancy counters land in three separately-collapsible groups.
+# loop's tick phases, the per-request lifecycles, the pool's occupancy
+# counters and the engine's phases land in separately-collapsible groups.
 PID_LOOP = 0        # serve-loop tick phases (one thread track)
 PID_REQUESTS = 1    # one thread track per request (tid = rid)
 PID_POOL = 2        # block-pool counters + events
+PID_ENGINE = 3      # engine phases inside a tick: launch, waits, admit
 
 
 class NoopTracer:
@@ -175,7 +185,8 @@ class Tracer(NoopTracer):
                    "tid": 0, "args": {"name": label}}
                   for pid, label in ((PID_LOOP, "serve-loop"),
                                      (PID_REQUESTS, "requests"),
-                                     (PID_POOL, "kv-block-pool"))]
+                                     (PID_POOL, "kv-block-pool"),
+                                     (PID_ENGINE, "serve-engine"))]
         rids = sorted({e["tid"] for e in self._events
                        if e["pid"] == PID_REQUESTS})
         events.extend({"name": "thread_name", "ph": "M",
@@ -196,6 +207,44 @@ class Tracer(NoopTracer):
         return len(trace["traceEvents"])
 
 
+class phase:
+    """Time one host phase: ``with phase("fill", clock, metrics,
+    "fill_s", tracer):``. Runs the block inside a
+    ``jax.profiler.TraceAnnotation`` named ``serve.<name>``, reads
+    ``clock`` once on entry (``start``) and once on exit (``end``), adds
+    ``end - start`` to ``metrics[key]`` and, when ``tracer`` records,
+    emits the span ``name`` on track ``pid`` from the same two reads —
+    so the counter, the ring-buffer span and the profiler's host event
+    cover the same work. ``args`` (settable inside the block) go on the
+    span. Costs two clock reads, a dict add and an inactive profiler
+    annotation when nothing records: time phases, never per-slot or
+    per-token work."""
+
+    __slots__ = ("name", "clock", "metrics", "key", "tracer", "pid",
+                 "args", "start", "end", "_annotation")
+
+    def __init__(self, name: str, clock, metrics: dict, key: str,
+                 tracer=NOOP, *, pid: int = PID_LOOP, args=None):
+        self.name, self.clock = name, clock
+        self.metrics, self.key = metrics, key
+        self.tracer, self.pid, self.args = tracer, pid, args
+        self._annotation = jax.profiler.TraceAnnotation("serve." + name)
+
+    def __enter__(self) -> "phase":
+        self._annotation.__enter__()
+        self.start = self.clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = self.clock()
+        self.metrics[self.key] += self.end - self.start
+        if self.tracer.enabled:
+            self.tracer.complete(self.name, self.start,
+                                 self.end - self.start, pid=self.pid,
+                                 args=self.args)
+        self._annotation.__exit__(*exc)
+
+
 # =========================================================== metrics
 def _sanitize(name: str) -> str:
     """Prometheus metric names allow [a-zA-Z0-9_:]; everything else
@@ -204,97 +253,8 @@ def _sanitize(name: str) -> str:
     return "".join(c if c.isalnum() or c in "_:" else "_" for c in name)
 
 
-class Counter:
-    """Monotonic count (``inc`` only; resets are a new process)."""
-
-    __slots__ = ("name", "help", "value")
-    kind = "counter"
-
-    def __init__(self, name: str, help: str = ""):
-        self.name = name
-        self.help = help
-        self.value = 0.0
-
-    def inc(self, n: float = 1.0) -> None:
-        if n < 0:
-            raise ValueError(f"{self.name}: counters only go up ({n})")
-        self.value += n
-
-    def samples(self):
-        return [("", self.value)]
-
-
-class Gauge:
-    """Point-in-time value (queue depth, pool occupancy)."""
-
-    __slots__ = ("name", "help", "value")
-    kind = "gauge"
-
-    def __init__(self, name: str, help: str = ""):
-        self.name = name
-        self.help = help
-        self.value = 0.0
-
-    def set(self, v: float) -> None:
-        self.value = float(v)
-
-    def inc(self, n: float = 1.0) -> None:
-        self.value += n
-
-    def dec(self, n: float = 1.0) -> None:
-        self.value -= n
-
-    def samples(self):
-        return [("", self.value)]
-
-
-# Latency-shaped default buckets (seconds): sub-ms host work through
-# multi-second drains, plus the paper's 700 ms budget as an edge.
-DEFAULT_BUCKETS = (0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
-                   0.7, 1.0, 2.5, 5.0, 10.0)
-
-
-class Histogram:
-    """Cumulative-bucket histogram, Prometheus exposition semantics:
-    ``_bucket{le=...}`` counts observations <= bound, plus ``_sum`` and
-    ``_count``."""
-
-    __slots__ = ("name", "help", "buckets", "counts", "sum", "count")
-    kind = "histogram"
-
-    def __init__(self, name: str, help: str = "",
-                 buckets=DEFAULT_BUCKETS):
-        self.name = name
-        self.help = help
-        self.buckets = tuple(sorted(buckets))
-        if not self.buckets:
-            raise ValueError(f"{name}: need >= 1 bucket")
-        self.counts = [0] * len(self.buckets)
-        self.sum = 0.0
-        self.count = 0
-
-    def observe(self, v: float) -> None:
-        self.sum += v
-        self.count += 1
-        for i, b in enumerate(self.buckets):
-            if v <= b:
-                self.counts[i] += 1
-
-    def samples(self):
-        out = []
-        cum = 0
-        for b, c in zip(self.buckets, self.counts):
-            cum = c  # counts are already cumulative per observe()
-            out.append((f'_bucket{{le="{b}"}}', cum))
-        out.append(('_bucket{le="+Inf"}', self.count))
-        out.append(("_sum", self.sum))
-        out.append(("_count", self.count))
-        return out
-
-
 class MetricsRegistry:
-    """One namespace of instruments + polled sources, with Prometheus
-    text exposition.
+    """Polled sources with Prometheus text exposition.
 
     ``labels`` stamp every sample (e.g. ``{"replica": "lm/0"}``) so
     per-replica registries merge into one exposition without name
@@ -308,53 +268,24 @@ class MetricsRegistry:
 
     def __init__(self, labels: dict | None = None):
         self.labels = dict(labels or {})
-        self._instruments: dict[str, object] = {}
         self._sources: list[tuple[str, object]] = []
-
-    # ------------------------------------------------------ instruments
-    def _get(self, cls, name: str, help: str, **kw):
-        name = _sanitize(name)
-        inst = self._instruments.get(name)
-        if inst is None:
-            inst = self._instruments[name] = cls(name, help, **kw)
-        elif not isinstance(inst, cls):
-            raise ValueError(f"{name}: already registered as "
-                             f"{type(inst).__name__}")
-        return inst
-
-    def counter(self, name: str, help: str = "") -> Counter:
-        return self._get(Counter, name, help)
-
-    def gauge(self, name: str, help: str = "") -> Gauge:
-        return self._get(Gauge, name, help)
-
-    def histogram(self, name: str, help: str = "",
-                  buckets=DEFAULT_BUCKETS) -> Histogram:
-        return self._get(Histogram, name, help, buckets=buckets)
 
     def source(self, prefix: str, fn) -> None:
         """Poll ``fn()`` (a flat ``{name: number}`` dict) at collect
         time, exposing each key as gauge ``{prefix}_{key}``."""
         self._sources.append((prefix, fn))
 
-    # ------------------------------------------------------- collection
     def collect(self) -> list:
-        """``(name, kind, help, labels, samples)`` tuples for every
-        instrument plus every source key — ``samples`` is a list of
-        ``(suffix, value)``."""
+        """``(name, labels, value)`` for every numeric source key."""
         out = []
-        for name in sorted(self._instruments):
-            inst = self._instruments[name]
-            out.append((inst.name, inst.kind, inst.help, self.labels,
-                        inst.samples()))
         for prefix, fn in self._sources:
             vals = fn()
             for key in sorted(vals):
                 v = vals[key]
                 if isinstance(v, bool) or not isinstance(v, (int, float)):
                     continue
-                out.append((_sanitize(f"{prefix}_{key}"), "gauge", "",
-                            self.labels, [("", float(v))]))
+                out.append((_sanitize(f"{prefix}_{key}"), self.labels,
+                            float(v)))
         return out
 
     def prometheus_text(self) -> str:
@@ -371,34 +302,17 @@ def _render_labels(labels: dict) -> str:
 
 def prometheus_text(registries) -> str:
     """Merge many registries (one per replica, each with distinguishing
-    labels) into one Prometheus text exposition: ``# HELP``/``# TYPE``
-    emitted once per metric name, samples from every registry under
-    it."""
+    labels) into one Prometheus text exposition: ``# TYPE`` emitted once
+    per metric name, samples from every registry under it."""
     by_name: dict[str, list] = {}
-    meta: dict[str, tuple] = {}
     for reg in registries:
-        for name, kind, help, labels, samples in reg.collect():
-            by_name.setdefault(name, []).append((labels, samples))
-            if name not in meta or (help and not meta[name][1]):
-                meta[name] = (kind, help)
+        for name, labels, value in reg.collect():
+            by_name.setdefault(name, []).append((labels, value))
     lines = []
     for name in sorted(by_name):
-        kind, help = meta[name]
-        if help:
-            lines.append(f"# HELP {name} {help}")
-        lines.append(f"# TYPE {name} {kind}")
-        for labels, samples in by_name[name]:
-            for suffix, value in samples:
-                if "{" in suffix and labels:
-                    # fold the registry labels in with the sample's own
-                    # (histogram buckets carry le="...")
-                    base, inner = suffix.split("{", 1)
-                    lab = _render_labels(labels)
-                    lines.append(f"{name}{base}{lab[:-1]},{inner}"
-                                 f" {_fmt(value)}")
-                else:
-                    lines.append(f"{name}{suffix}{_render_labels(labels)}"
-                                 f" {_fmt(value)}")
+        lines.append(f"# TYPE {name} gauge")
+        lines.extend(f"{name}{_render_labels(labels)} {_fmt(value)}"
+                     for labels, value in by_name[name])
     return "\n".join(lines) + "\n"
 
 

@@ -1,5 +1,6 @@
-"""Telemetry: tracer determinism, trace/stats reconstruction, metrics
-registry + Prometheus exposition, and bit-identity with tracing on.
+"""Telemetry: tracer determinism, trace/stats reconstruction, the phase
+timer's counters and spans, metrics registry + Prometheus exposition,
+and bit-identity with tracing on.
 
 The load-bearing claims: (1) a scripted workload under a VirtualClock
 emits **byte-identical** trace JSON run to run, (2) the trace's queued
@@ -8,10 +9,12 @@ clock reads, not a re-measurement), and (3) turning tracing on changes
 no token stream anywhere on the engine grid.
 """
 import dataclasses
+import glob
 import json
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs.base import get_config
@@ -20,10 +23,9 @@ from repro.serve.async_loop import AsyncServeLoop
 from repro.serve.clock import VirtualClock
 from repro.serve.engine import Request, ServingEngine
 from repro.serve.scheduler import Scheduler
-from repro.serve.telemetry import (NOOP, PID_LOOP, PID_POOL, PID_REQUESTS,
-                                   Counter, Gauge, Histogram,
-                                   MetricsRegistry, NoopTracer, Tracer,
-                                   prometheus_text)
+from repro.serve.telemetry import (NOOP, PID_ENGINE, PID_LOOP, PID_POOL,
+                                   PID_REQUESTS, MetricsRegistry, Tracer,
+                                   phase, prometheus_text)
 
 MAX_SEQ = 64
 
@@ -79,42 +81,35 @@ def test_negative_duration_clamped():
     assert ev["dur"] == 0.0
 
 
-# =================================================== registry unit tests
-def test_counter_monotonic():
-    c = Counter("hits")
-    c.inc()
-    c.inc(2)
-    assert c.value == 3
-    with pytest.raises(ValueError, match="only go up"):
-        c.inc(-1)
+# ================================================ phase timer unit tests
+def test_phase_adds_interval_and_emits_identical_span():
+    vc = VirtualClock(start=2.0)
+    tr = Tracer(clock=vc)
+    m = {"fill_s": 0.5}
+    with phase("fill", vc, m, "fill_s", tr, pid=PID_ENGINE) as p:
+        vc.advance(0.25)
+        p.args = {"k": 1}
+    assert (p.start, p.end) == (2.0, 2.25)
+    assert m["fill_s"] == 0.75                  # added, not overwritten
+    (ev,) = [e for e in tr.chrome_trace()["traceEvents"] if e["ph"] == "X"]
+    assert ev == {"name": "fill", "ph": "X", "ts": 2000000.0,
+                  "dur": 250000.0, "pid": PID_ENGINE, "tid": 0,
+                  "args": {"k": 1}}
 
 
-def test_gauge_moves_both_ways():
-    g = Gauge("depth")
-    g.set(5)
-    g.inc()
-    g.dec(2)
-    assert g.value == 4
-
-
-def test_histogram_cumulative_buckets():
-    h = Histogram("lat", buckets=(0.1, 1.0))
-    for v in (0.05, 0.5, 0.5, 5.0):
-        h.observe(v)
-    samples = dict(h.samples())
-    assert samples['_bucket{le="0.1"}'] == 1
-    assert samples['_bucket{le="1.0"}'] == 3
-    assert samples['_bucket{le="+Inf"}'] == 4
-    assert samples["_count"] == 4
-    assert samples["_sum"] == pytest.approx(6.05)
-
-
-def test_registry_kind_mismatch_raises():
-    reg = MetricsRegistry()
-    reg.counter("ticks")
-    assert reg.counter("ticks") is reg.counter("ticks")   # create-or-get
-    with pytest.raises(ValueError, match="already registered"):
-        reg.gauge("ticks")
+def test_phase_without_a_recording_tracer_still_counts():
+    vc = VirtualClock()
+    m = {"emit_s": 0.0}
+    for dt in (0.125, 0.5):
+        with phase("emit", vc, m, "emit_s"):    # NOOP by default
+            vc.advance(dt)
+    assert m["emit_s"] == 0.625
+    with pytest.raises(KeyError):               # the block's error passes
+        with phase("emit", vc, m, "emit_s", NOOP):
+            vc.advance(1.0)
+            raise KeyError("client")
+    assert m["emit_s"] == 1.625                 # and its time is counted
+    assert NOOP.chrome_trace()["traceEvents"] == []
 
 
 def test_registry_source_polls_and_skips_non_numeric():
@@ -134,19 +129,17 @@ def test_prometheus_merge_across_registries():
     regs = []
     for i in range(2):
         reg = MetricsRegistry(labels={"replica": f"lm/{i}"})
-        reg.counter("served", help="requests served").inc(i + 1)
-        h = reg.histogram("wait", buckets=(1.0,))
-        h.observe(0.5)
+        reg.source("engine", lambda i=i: {"completed": i + 1,
+                                          "launch_gap_s": 0.5 * i})
         regs.append(reg)
     text = prometheus_text(regs)
-    # HELP/TYPE once per name, samples from both registries under it
-    assert text.count("# TYPE served counter") == 1
-    assert text.count("# HELP served requests served") == 1
-    assert 'served{replica="lm/0"} 1' in text
-    assert 'served{replica="lm/1"} 2' in text
-    # registry labels fold into the histogram's own le label
-    assert 'wait_bucket{replica="lm/0",le="1.0"} 1' in text
-    assert 'wait_count{replica="lm/1"} 1' in text
+    # TYPE once per name, samples from both registries under it
+    assert text.count("# TYPE engine_completed gauge") == 1
+    assert 'engine_completed{replica="lm/0"} 1' in text
+    assert 'engine_completed{replica="lm/1"} 2' in text
+    assert 'engine_launch_gap_s{replica="lm/0"} 0' in text
+    assert 'engine_launch_gap_s{replica="lm/1"} 0.5' in text
+    assert "# HELP" not in text
 
 
 def test_metric_names_sanitized():
@@ -305,6 +298,226 @@ def test_pool_track_alloc_free_and_occupancy(stack):
     # everything retired: the last occupancy sample (emitted on the
     # final free) shows no held blocks
     assert counters[-1]["args"]["used"] == 0
+
+
+# ------------------------------------------------- phase counters
+# scripted host time per phase (s): distinct, so time booked to the
+# wrong phase breaks the identities below
+SLOW = {"cancel": 1e-3, "fill": 2e-3, "plan": 3e-3, "account": 5e-3,
+        "emit": 7e-3, "grow": 11e-3, "launch": 13e-3, "wait": 17e-3,
+        "admit_wait": 19e-3, "done_check": 23e-4, "outside": 29e-3}
+HOOK_CONFIGS = {"decode": ({}, [5, 9, 7, 12, 6]),
+                "chunked": ({"prefill_chunk": 8}, [21, 30, 17, 26, 19])}
+
+
+class _SlowFetch:
+    """A step output whose transfer to the host takes scripted time."""
+
+    def __init__(self, x, vc, dt):
+        self.x, self.vc, self.dt = x, vc, dt
+
+    def __array__(self, dtype=None, copy=None):
+        self.vc.advance(self.dt)
+        return np.asarray(self.x, dtype)
+
+
+def _slow(vc, fn, dt, fetch=0.0):
+    """``fn`` taking ``dt`` of the clock; with ``fetch``, its first two
+    outputs take that long each to reach the host."""
+    def run(*a, **k):
+        vc.advance(dt)
+        out = fn(*a, **k)
+        if fetch:
+            out = (_SlowFetch(out[0], vc, fetch),
+                   _SlowFetch(out[1], vc, fetch)) + tuple(out[2:])
+        return out
+    return run
+
+
+@pytest.fixture(scope="module", params=list(HOOK_CONFIGS))
+def hooked(stack, request):
+    """A scripted serve on a VirtualClock in which every host phase of
+    the loop and the engine takes a known time, pumped with no idle
+    tick. Returns (loop, tracer, per-tick counter snapshots)."""
+    cfg, model, params = stack
+    kw, lens = HOOK_CONFIGS[request.param]
+    vc = VirtualClock()
+    tracer = Tracer(clock=vc)
+    eng = ServingEngine(model, params, batch_size=2, max_seq=MAX_SEQ,
+                        clock=vc, tracer=tracer, **kw)
+    sched = Scheduler(eng, clock=vc)
+    loop = AsyncServeLoop(sched)
+    loop._apply_cancels = _slow(vc, loop._apply_cancels, SLOW["cancel"])
+    sched.fill = _slow(vc, sched.fill, SLOW["fill"])
+    sched.plan_ahead = _slow(vc, sched.plan_ahead, SLOW["plan"])
+    sched.account = _slow(vc, sched.account, SLOW["account"])
+    loop._emit = _slow(vc, loop._emit, SLOW["emit"])
+    eng._grow_or_park = _slow(vc, eng._grow_or_park, SLOW["grow"])
+    eng._is_done = _slow(vc, eng._is_done, SLOW["done_check"])
+    eng._decode = _slow(vc, eng._decode, SLOW["launch"], SLOW["wait"])
+    eng._chunk_fn = _slow(vc, eng._chunk_fn, SLOW["launch"], SLOW["wait"])
+    eng._prefill_paged = _slow(vc, eng._prefill_paged, 0.0,
+                               SLOW["admit_wait"])
+    handles = [loop.submit(Request(rid=i, prompt=list(p), max_new_tokens=4))
+               for i, p in enumerate(_prompts(cfg, lens, seed=31))]
+    snaps = []
+    while not all(h.done for h in handles):
+        assert loop.run_once(), "the scripted serve has no idle tick"
+        snaps.append((dict(loop.metrics), dict(eng.metrics)))
+        vc.advance(SLOW["outside"])
+        assert len(snaps) < 200, "serve did not converge"
+    return loop, tracer, snaps
+
+
+def test_launch_gap_is_the_host_phases_between_steps(hooked):
+    """From one step's result on the host to the next step's launch
+    returning: the commit bookkeeping after the device wait, account,
+    emit, the caller's time, cancels, fill (admissions included) and the
+    dispatch up to the launch. Nothing after the launch takes scripted
+    time, so ``dispatch_s`` is the dispatch up to the launch; the plan
+    window overlaps the device and is no part of the gap."""
+    _, _, snaps = hooked
+    zero = ({k: 0 for k in snaps[0][0]}, {k: 0 for k in snaps[0][1]})
+    prev = zero
+    ticks = []
+    for lm, em in snaps:
+        ticks.append(({k: lm[k] - prev[0][k] for k in lm},
+                      {k: em[k] - prev[1][k] for k in em}))
+        prev = (lm, em)
+    assert ticks[0][1]["launch_gaps"] == 0      # no step before the first
+    for (pl, pe), (lm, em) in zip(ticks, ticks[1:]):
+        assert em["launch_gaps"] == 1
+        expect = (pl["commit_wait_s"] - pe["device_wait_s"]
+                  + pl["account_s"] + pl["emit_s"] + lm["outside_s"]
+                  + lm["cancel_s"] + lm["fill_s"] + lm["dispatch_s"])
+        assert em["launch_gap_s"] == pytest.approx(expect, abs=1e-9)
+    last_l, last_e = snaps[-1]
+    n = len(snaps)
+    assert last_e["launch_gaps"] == n - 1
+    assert last_e["launch_s"] == pytest.approx(n * SLOW["launch"])
+    assert last_e["device_wait_s"] == pytest.approx(2 * n * SLOW["wait"])
+    assert last_l["outside_s"] == pytest.approx((n - 1) * SLOW["outside"])
+    assert last_l["plan_time_s"] == pytest.approx(n * SLOW["plan"])
+    # admissions ran inside fill, each blocked on its admit program
+    assert 0 < last_e["admit_wait_s"] <= last_e["admit_s"] \
+        <= last_l["fill_s"]
+    assert last_e["write_blocks"] >= last_e["prefills"]
+
+
+PHASE_COUNTERS = [
+    ("apply-cancels", PID_LOOP, "loop", "cancel_s"),
+    ("fill", PID_LOOP, "loop", "fill_s"),
+    ("dispatch", PID_LOOP, "loop", "dispatch_s"),
+    ("plan-window", PID_LOOP, "loop", "plan_time_s"),
+    ("commit-wait", PID_LOOP, "loop", "commit_wait_s"),
+    ("account", PID_LOOP, "loop", "account_s"),
+    ("emit", PID_LOOP, "loop", "emit_s"),
+    ("launch", PID_ENGINE, "engine", "launch_s"),
+    ("device-wait", PID_ENGINE, "engine", "device_wait_s"),
+    ("admit", PID_ENGINE, "engine", "admit_s"),
+    ("admit-wait", PID_ENGINE, "engine", "admit_wait_s"),
+]
+
+
+@pytest.mark.parametrize("name,pid,owner,key", PHASE_COUNTERS,
+                         ids=[c[0] for c in PHASE_COUNTERS])
+def test_phase_spans_sum_to_their_counter(hooked, name, pid, owner, key):
+    """The trace shows exactly what the counters sum: same clock reads."""
+    loop, tracer, _ = hooked
+    metrics = loop.metrics if owner == "loop" else loop.engine.metrics
+    spans = [e for e in tracer.chrome_trace()["traceEvents"]
+             if e["ph"] == "X" and e["name"] == name and e["pid"] == pid]
+    assert spans
+    assert sum(e["dur"] for e in spans) == pytest.approx(
+        metrics[key] * 1e6, abs=0.1 * len(spans))
+    assert metrics[key] > 0
+
+
+def test_idle_tick_breaks_the_launch_chain(stack):
+    cfg, model, params = stack
+    vc = VirtualClock()
+    eng = ServingEngine(model, params, batch_size=2, max_seq=MAX_SEQ,
+                        clock=vc)
+    loop = AsyncServeLoop(Scheduler(eng, clock=vc))
+    p1, p2 = _prompts(cfg, [6, 7], seed=33)
+    for rid, p in ((0, p1), (1, p2)):
+        h = loop.submit(Request(rid=rid, prompt=list(p), max_new_tokens=3))
+        while not h.done:
+            loop.run_once()
+            vc.advance(0.01)
+        gaps = dict(eng.metrics)
+        for _ in range(3):              # idle: nothing queued or active
+            assert not loop.run_once()
+            vc.advance(5.0)
+    # each request: admission + 2 decode steps, one gap between them;
+    # the 15 s idle spell is no launch gap
+    assert gaps["launch_gaps"] == 2 and gaps["decode_steps"] == 4
+    assert gaps["launch_gap_s"] == pytest.approx(0.02)
+    # the caller's time: every advance but the last falls between calls
+    assert loop.metrics["outside_s"] == pytest.approx(
+        0.01 * loop.metrics["ticks"] + 5.0 * 5)
+
+
+def test_admit_to_first_token_sums_the_request_stamps(stack):
+    cfg, model, params = stack
+    vc = VirtualClock()
+    eng = ServingEngine(model, params, batch_size=2, max_seq=MAX_SEQ,
+                        clock=vc, prefill_chunk=8)
+    loop = AsyncServeLoop(Scheduler(eng, clock=vc))
+    reqs = [Request(rid=i, prompt=list(p), max_new_tokens=3)
+            for i, p in enumerate(_prompts(cfg, [21, 30, 5, 17], seed=35))]
+    handles = [loop.submit(r) for r in reqs]
+    while not all(h.done for h in handles):
+        loop.run_once()
+        vc.advance(0.01)
+    m = eng.metrics
+    assert m["first_tokens"] == len(reqs)
+    waits = [r.first_token_s - r.admitted_s for r in reqs]
+    assert m["admit_to_first_s"] == pytest.approx(sum(waits))
+    # the 30-token prompt: 8 tokens at admission, then windows of 8, 8
+    # and 6, the first in the admission's own tick; the 5-token one's
+    # first token comes from its admission
+    assert max(waits) == pytest.approx(0.02)
+    assert min(waits) == 0.0
+
+
+def test_profiler_trace_holds_phases_nested_in_the_tick(stack, tmp_path):
+    """Under a profiler trace the phases are host events on the
+    profiler's clock, nested in the caller's span around ``run_once``
+    and, for the engine's, in the loop phase that runs them."""
+    cfg, model, params = stack
+    eng = ServingEngine(model, params, batch_size=2, max_seq=MAX_SEQ)
+    loop = AsyncServeLoop(Scheduler(eng))
+    handles = [loop.submit(Request(rid=i, prompt=list(p), max_new_tokens=3))
+               for i, p in enumerate(_prompts(cfg, [6, 9, 7], seed=37))]
+    loop.run_once()                     # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        while not all(h.done for h in handles):
+            with jax.profiler.TraceAnnotation("tick"):
+                loop.run_once()
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    events.setdefault(e.name, []).append(
+                        (e.start_ns, e.end_ns))
+
+    def inside(child, parent):
+        return events[child] and all(
+            any(ps <= s and e <= pe for ps, pe in events[parent])
+            for s, e in events[child])
+
+    ticks = len(events["tick"])
+    assert ticks >= 3
+    for name in ("serve.fill", "serve.dispatch", "serve.commit-wait",
+                 "serve.emit"):
+        assert len(events[name]) == ticks and inside(name, "tick"), name
+    assert inside("serve.launch", "serve.dispatch")
+    assert inside("serve.device-wait", "serve.commit-wait")
+    assert inside("serve.admit", "serve.fill")
 
 
 # ------------------------------------------ tracing-on bit-identity grid
