@@ -8,13 +8,10 @@ The portable jnp path (`attention.paged_decode_attention` /
 transient ``(B, max_blocks*bs)`` buffer before the attention math —
 O(B x max_seq) of extra HBM traffic per layer per step.
 
-This kernel reads the pool **in place**: the block table and per-row
-base lengths ride in as scalar-prefetch operands (SMEM), and the K/V
-BlockSpec index maps dereference the table, so each grid step DMAs
-one KV head of one physical block, a contiguous ``(block_size, hd)``
-tile, from the pool into VMEM. Nothing is
-materialized per-row; the only per-step HBM traffic is the blocks a row
-actually owns (plus masked-off scratch for table tails).
+This kernel reads the pool **in place**, and only the pages a row
+holds. The block table and per-row base lengths ride in as
+scalar-prefetch operands (SMEM); the pool stays in HBM
+(``memory_space=pl.ANY``) and the kernel starts its own DMAs.
 
 One fused tile serves every serving consumer:
 
@@ -24,29 +21,42 @@ One fused tile serves every serving consumer:
 * **chunked prefill** — a prompt chunk is a window of known tokens
   against the partially-resident prompt.
 
-Grid (B, Hkv, max_blocks): all ``q_len * G`` query rows of one KV head
-(G = Hq/Hkv) are processed together as an ``(S*G, hd)`` tile (the same
-MXU-occupancy trick as ``decode_attention``, extended across the
-window), with the block sweep innermost over flash-style VMEM
-accumulators. **Causal-in-window masking** happens per query row:
-window position ``w = row // G`` of batch row ``b`` attends to cache
-positions ``[0, base[b] + w]`` — ``base`` is the per-row count of
-tokens resident *before* the window, so every window token conditions
-on the committed context plus its own in-window prefix, exactly what
-``w+1`` sequential single-token calls would each see. Rows at
-different base lengths mask per-row via the prefetched vector — ragged
-continuous batching needs no padding and no HBM mask tensor.
+Grid ``(B, Hkv // hg)``: one step per batch row and *head group* of
+``hg`` KV heads, and inside it a loop over the row's **compute blocks**
+of ``P`` pages (``P * bs`` tokens). Row ``b``'s window reads cache
+positions below ``base[b] + S``, so it holds
+``ceil((base[b] + S) / bs)`` pages; the loop runs
+``ceil(held / P)`` times and copies exactly the held pages — one
+``make_async_copy`` per page, a contiguous ``(hg, bs, hd)`` slab of the
+head-major pool addressed through the table — into a two-slot VMEM
+buffer. The next compute block (or the next grid step's first one) is
+in flight while the current one is computed. Pages past the held
+bound are neither copied nor computed: table tails cost nothing.
+``P`` and ``hg`` come from :func:`tile_plan`, from the shapes alone.
+
+All ``S * G`` query rows of one KV head (G = Hq/Hkv) ride one
+``(S*G, hd)`` tile, batched over the step's ``hg`` heads, with flash
+accumulators in VMEM across the block loop. **Causal-in-window
+masking** happens per query row: window position ``w = row // G`` of
+batch row ``b`` attends to cache positions ``[0, base[b] + w]`` —
+``base`` is the per-row count of tokens resident *before* the window,
+so every window token conditions on the committed context plus its own
+in-window prefix, exactly what ``w+1`` sequential single-token calls
+would each see. Rows at different base lengths mask per-row via the
+prefetched vector — ragged continuous batching needs no padding and no
+HBM mask tensor. A buffer page that was not copied holds whatever the
+slot held before; the mask keeps it out of the scores and V is zeroed
+there, since a masked weight of 0 times a non-finite value is NaN.
 
 Emits (out, lse) so sequence-sharded pools can merge partials with the
 same closed-form LSE combine as the stripe decode kernel.
 
-Layout and Mosaic tiling: the last two dimensions of every block must be
-divisible by (8, 128) or equal the array's own. The pool is head-major
-so a K/V block is ``(1, 1, bs, hd)`` (last two dims the array's own),
-and lse leaves as ``(B, Hkv, R, 1)`` so its block ``(1, 1, R, 1)`` ends
-in full dimensions too. A token-major pool with a ``(1, bs, 1, hd)``
-block puts a 1 in the second-minor place of the ``Hkv`` axis, which the
-TPU compiler refuses for ``Hkv > 1``; interpret mode does not check it.
+Layout and Mosaic tiling: the q/out/lse blocks end in their arrays'
+full ``(S*G, hd)`` / ``(S*G, 1)`` dimensions. A page copy lands in
+``buf[slot, :, p]`` of a ``(2, hg, P, bs, hd)`` buffer, whose last two
+dimensions are the page's own, and the K/V tiles are upcast to float32
+before ``(hg, P, bs, hd)`` is read as ``(hg, P*bs, hd)``, which is
+tile-aligned for any ``bs`` that is a multiple of 8.
 """
 from __future__ import annotations
 
@@ -59,12 +69,73 @@ import jax.experimental.pallas.tpu as pltpu
 
 NEG_INF = float("-inf")
 
+# Tokens one compute block aims at: large enough that a row takes a few
+# loop iterations, not one per page, and small enough that the last,
+# partly held block of a row wastes little math.
+BLOCK_TOKENS = 256
+# VMEM one grid step may plan for: three quarters of the 16 MiB that
+# Mosaic scopes for a kernel by default on TPU v5e, the rest left for
+# the compiler's own temporaries.
+VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _step_vmem_bytes(hg: int, T: int, R: int, hd: int, itemsize: int) -> int:
+    """VMEM of one grid step with ``hg`` heads and ``T``-token compute
+    blocks, counted in (8, 128) float32 tiles: the two-slot K/V
+    buffers, the float32 tiles the block's math makes (upcast K and V,
+    ``[V | 1]`` stacked on the accumulator, scores, weights and
+    ``[p | diag(alpha)]``), the accumulators, and the pipelined
+    q/out/lse blocks."""
+    lanes = lambda n: _round_up(n, 128)                      # noqa: E731
+    Rp = _round_up(R, 8)
+    kv_buffers = 2 * 2 * hg * T * hd * itemsize
+    kv_f32 = 2 * hg * T * lanes(hd) * 4
+    v_aug = hg * _round_up(T + R, 8) * lanes(hd + 1) * 4
+    scores = 3 * hg * Rp * lanes(T + 1) * 4
+    p_aug = hg * Rp * lanes(T + R) * 4
+    accumulators = hg * Rp * (lanes(hd + 1) + 128) * 4
+    q_f32 = hg * Rp * lanes(hd) * 4
+    io_blocks = 2 * hg * Rp * (2 * lanes(hd) * itemsize + 128 * 4)
+    return (kv_buffers + kv_f32 + v_aug + scores + p_aug + accumulators
+            + q_f32 + io_blocks)
+
+
+def tile_plan(*, Hkv: int, bs: int, hd: int, R: int, itemsize: int,
+              max_blocks: int) -> tuple[int, int]:
+    """``(P, hg)``: pages per compute block and KV heads per grid step.
+
+    ``P`` covers :data:`BLOCK_TOKENS` tokens (never more pages than the
+    table has); ``hg`` is the largest divisor of ``Hkv`` whose step fits
+    :data:`VMEM_BUDGET_BYTES`, all heads where they fit. Only where one
+    head does not fit is ``P`` halved. The kernel and its streaming
+    oracle both take their tile shapes from here."""
+    P = max(1, min(max_blocks, BLOCK_TOKENS // bs))
+    while True:
+        for hg in range(Hkv, 0, -1):
+            if Hkv % hg == 0 and _step_vmem_bytes(
+                    hg, P * bs, R, hd, itemsize) <= VMEM_BUDGET_BYTES:
+                return P, hg
+        if P == 1:
+            return 1, 1
+        P //= 2
+
+
+def held_pages(base, S: int, bs: int, max_blocks: int):
+    """Pages of a row's table its window reads: positions below
+    ``base + S``, clipped to the table."""
+    return jnp.clip((base + S + bs - 1) // bs, 0, max_blocks)
+
 
 def _rescale_accumulate(p, alpha, v, acc, *, deterministic: bool):
-    """One flash-attention accumulate step as a SINGLE contraction.
+    """One flash-attention accumulate step as a SINGLE contraction per
+    head, batched over the leading head axis.
 
-    acc (R, hd+1) carries the output accumulator in [:, :hd] and the
-    softmax denominator in [:, hd]. The classic update
+    acc (H, R, hd+1) carries the output accumulator in [..., :hd] and
+    the softmax denominator in [..., hd]. The classic update
     ``alpha * acc + [p @ v, sum(p)]`` leaves XLA free to seed the dot's
     reduction with the rescaled addend (FMA / accumulator-init fusion),
     which rounds differently per compilation context — the one freedom
@@ -73,7 +144,7 @@ def _rescale_accumulate(p, alpha, v, acc, *, deterministic: bool):
 
         [p | diag(alpha)] @ [[v | 1], [acc]]
 
-    is ONE (R, bs+R) x (bs+R, hd+1) contraction — every product
+    is ONE (R, T+R) x (T+R, hd+1) contraction — every product
     (including ``alpha_r * acc_r``) enters the same reduction, and the
     denominator column rides along for free.
 
@@ -84,16 +155,16 @@ def _rescale_accumulate(p, alpha, v, acc, *, deterministic: bool):
     path keeps the plain ``dot_general`` (MXU) — bit-parity across
     hardware is meaningless anyway.
     """
-    R = p.shape[0]
-    p_aug = jnp.concatenate(
-        [p, jnp.where(jnp.eye(R, dtype=bool), alpha, 0.0)], axis=1)
+    H, R = p.shape[0], p.shape[1]
+    eye = jnp.eye(R, dtype=bool)[None]
+    p_aug = jnp.concatenate([p, jnp.where(eye, alpha, 0.0)], axis=2)
     v_aug = jnp.concatenate(
-        [jnp.concatenate([v, jnp.ones((v.shape[0], 1), jnp.float32)],
-                         axis=1), acc], axis=0)
+        [jnp.concatenate([v, jnp.ones((H, v.shape[1], 1), jnp.float32)],
+                         axis=2), acc], axis=1)
     if not deterministic:
-        return jax.lax.dot_general(p_aug, v_aug, (((1,), (0,)), ((), ())),
+        return jax.lax.dot_general(p_aug, v_aug, (((2,), (1,)), ((0,), (0,))),
                                    preferred_element_type=jnp.float32)
-    return _exact_sum(p_aug[:, :, None] * v_aug[None, :, :], 1)
+    return _exact_sum(p_aug[:, :, :, None] * v_aug[:, None, :, :], 2)
 
 
 def _exact_sum(x, axis: int):
@@ -112,39 +183,42 @@ def _exact_sum(x, axis: int):
 
 def _p_and_alpha(s, mask, m_prev, m_safe):
     """Softmax weights p = exp(s - m_safe) and rescale alpha =
-    exp(m_prev - m_safe) out of ONE (R, bs+1) exp op. Besides saving a
+    exp(m_prev - m_safe) out of ONE (..., T+1) exp op. Besides saving a
     transcendental launch, this narrows a determinism gap: a lone
     (R, 1)-shaped exp was observed to compile differently depending on
     unrelated ops elsewhere in the module (vector-vs-scalar codegen of
     the polynomial), while the wide exp is far more stable — one shared
     op means p and alpha can't round apart from each other."""
-    z = jnp.concatenate([s, m_prev], axis=1) - m_safe        # (R, bs+1)
+    z = jnp.concatenate([s, m_prev], axis=-1) - m_safe       # (..., T+1)
     e = jnp.exp(z)
-    p = jnp.where(mask, e[:, :-1], 0.0)
-    alpha = jnp.where(jnp.isfinite(m_prev), e[:, -1:], 0.0)
+    p = jnp.where(mask, e[..., :-1], 0.0)
+    alpha = jnp.where(jnp.isfinite(m_prev), e[..., -1:], 0.0)
     return p, alpha
 
 
 def _qk_scores(q, k, scale: float, *, deterministic: bool):
-    """Masked-score contraction q (R, hd) x k (bs, hd) -> (R, bs).
-    Same determinism split as ``_rescale_accumulate``: ``dot_general``
-    for the compiled TPU path; a broadcast multiply feeding an
-    ``_exact_sum`` add chain for the interpret/oracle mode."""
+    """Score contraction q (H, R, hd) x k (H, T, hd) -> (H, R, T),
+    batched over heads. Same determinism split as
+    ``_rescale_accumulate``: ``dot_general`` for the compiled TPU path;
+    a broadcast multiply feeding an ``_exact_sum`` add chain for the
+    interpret/oracle mode."""
     if not deterministic:
-        return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        return jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                    preferred_element_type=jnp.float32) * scale
-    return _exact_sum(q[:, None, :] * k[None, :, :], 2) * scale
+    return _exact_sum(q[:, :, None, :] * k[:, None, :, :], 3) * scale
 
 
-def _window_mask(s_shape, j: int, base, *, bs: int, G: int, window: int):
-    """Causal-in-window validity for the (R, bs) score tile of KV block
-    ``j``: query row r is window position ``w = r // G`` of its batch
-    row, valid through cache position ``base + w`` (its own scatter
-    included), so ``n_valid = base + w + 1`` — per query row, not per
-    batch row. A sliding window then clips the low side at
-    ``n_valid - window``. Integer-only, exact under any codegen."""
-    kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s_shape, 1)
-    w_off = jax.lax.broadcasted_iota(jnp.int32, s_shape, 0) // G
+def _window_mask(s_shape, j, base, *, T: int, G: int, window: int):
+    """Causal-in-window validity for the (..., R, T) score tile of
+    compute block ``j`` (cache positions ``j*T + [0, T)``): query row r
+    is window position ``w = r // G`` of its batch row, valid through
+    cache position ``base + w`` (its own scatter included), so
+    ``n_valid = base + w + 1`` — per query row, not per batch row. A
+    sliding window then clips the low side at ``n_valid - window``.
+    Integer-only, exact under any codegen."""
+    nd = len(s_shape)
+    kpos = j * T + jax.lax.broadcasted_iota(jnp.int32, s_shape, nd - 1)
+    w_off = jax.lax.broadcasted_iota(jnp.int32, s_shape, nd - 2) // G
     n_valid = base + w_off + 1
     mask = kpos < n_valid
     if window:
@@ -152,40 +226,115 @@ def _window_mask(s_shape, j: int, base, *, bs: int, G: int, window: int):
     return mask
 
 
-def _paged_window_kernel(table_ref, base_ref, q_ref, k_ref, v_ref, o_ref,
-                         lse_ref, acc_ref, m_ref, *, scale: float,
-                         bs: int, G: int, window: int, n_blocks: int,
-                         deterministic: bool):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-
-    base = base_ref[b]
-    q = q_ref[0, 0].astype(jnp.float32)                  # (S*G, hd)
-    k = k_ref[0, 0].astype(jnp.float32)                  # (bs, hd)
-    v = v_ref[0, 0].astype(jnp.float32)
-
-    s = _qk_scores(q, k, scale, deterministic=deterministic)
-    mask = _window_mask(s.shape, j, base, bs=bs, G=G, window=window)
+def _block_update(q, k, v, acc, m_prev, j, base, *, S: int, G: int,
+                  scale: float, window: int, deterministic: bool):
+    """One compute block of the flash recurrence for the (hg, R) query
+    tile: k/v (hg, T, hd) float32 of cache positions ``j*T + [0, T)``.
+    Positions at or past ``base + S`` are past every query row: their
+    scores are masked and their V rows zeroed, whatever the tile holds
+    there. Returns the new (acc, m)."""
+    T = k.shape[1]
+    s = _qk_scores(q, k, scale, deterministic=deterministic)  # (hg, R, T)
+    mask = _window_mask(s.shape, j, base, T=T, G=G, window=window)
     s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    vpos = j * T + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+    v = jnp.where(vpos < base + S, v, 0.0)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
     p, alpha = _p_and_alpha(s, mask, m_prev, m_safe)
-    acc_ref[...] = _rescale_accumulate(p, alpha, v, acc_ref[...],
-                                       deterministic=deterministic)
-    m_ref[...] = m_new
+    acc = _rescale_accumulate(p, alpha, v, acc, deterministic=deterministic)
+    return acc, m_new
 
-    @pl.when(j == n_blocks - 1)
-    def _write():
-        l = jnp.maximum(acc_ref[:, -1:], 1e-30)
-        o_ref[0, 0] = (acc_ref[:, :-1] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_safe + jnp.log(l)              # (S*G, 1)
+
+def _finish(acc, m, dtype):
+    """(out, lse) from the accumulators: a row that saw nothing ends
+    with acc 0 and m -inf, i.e. out 0 and lse log(1e-30)."""
+    m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
+    l = jnp.maximum(acc[..., -1:], 1e-30)
+    return (acc[..., :-1] / l).astype(dtype), m_safe + jnp.log(l)
+
+
+def _paged_window_kernel(table_ref, base_ref, q_ref, pool_k, pool_v, o_ref,
+                         lse_ref, kbuf, vbuf, sems, acc_ref, m_ref, slot_ref,
+                         *, scale: float, S: int, G: int, bs: int, P: int,
+                         hg: int, n_groups: int, n_rows: int,
+                         max_blocks: int, window: int, deterministic: bool):
+    b, g = pl.program_id(0), pl.program_id(1)
+    T = P * bs
+    hd = kbuf.shape[-1]
+    t = b * n_groups + g                     # linear grid step
+    held = lambda row: held_pages(base_ref[row], S, bs,  # noqa: E731
+                                  max_blocks)
+
+    def block_copies(row, grp, i, slot, p):
+        """Page ``p`` of compute block ``i`` of (row, head group): one
+        K and one V copy of a contiguous (hg, bs, hd) pool slab."""
+        phys = table_ref[row * max_blocks + i * P + p]
+        heads = pl.ds(grp * hg, hg)
+        return [pltpu.make_async_copy(pool.at[phys, heads],
+                                      buf.at[slot, :, p], sems.at[kv, slot])
+                for kv, (pool, buf) in enumerate(((pool_k, kbuf),
+                                                  (pool_v, vbuf)))]
+
+    def for_held_pages(row, grp, i, slot, pages, method):
+        def page(p, carry):
+            for c in block_copies(row, grp, i, slot, p):
+                getattr(c, method)()
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(P, pages - i * P), page, 0)
+
+    @pl.when(t == 0)
+    def _():
+        slot_ref[0] = 0
+
+    pages = held(b)
+    n_blk = (pages + P - 1) // P
+    slot0 = slot_ref[0]
+    # the previous step started this step's first block as its last one
+    # ran, if it had any block to run
+    prefetched = (t > 0) & (held(jnp.maximum(t - 1, 0) // n_groups) > 0)
+
+    @pl.when((pages > 0) & jnp.logical_not(prefetched))
+    def _():
+        for_held_pages(b, g, 0, slot0, pages, "start")
+
+    t_next = t + 1
+    b_next = jnp.minimum(t_next // n_groups, n_rows - 1)
+    pages_next = held(b_next)
+    start_next = (t_next < n_rows * n_groups) & (pages_next > 0)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    q = q_ref[0].astype(jnp.float32)                         # (hg, R, hd)
+    base = base_ref[b]
+
+    def body(i, carry):
+        slot = (slot0 + i) % 2
+
+        @pl.when(i + 1 < n_blk)
+        def _():
+            for_held_pages(b, g, i + 1, 1 - slot, pages, "start")
+
+        @pl.when((i + 1 == n_blk) & start_next)
+        def _():
+            for_held_pages(b_next, t_next % n_groups, 0, 1 - slot,
+                           pages_next, "start")
+
+        for_held_pages(b, g, i, slot, pages, "wait")
+        k = kbuf[slot].astype(jnp.float32).reshape(hg, T, hd)
+        v = vbuf[slot].astype(jnp.float32).reshape(hg, T, hd)
+        acc, m = _block_update(q, k, v, acc_ref[...], m_ref[...], i, base,
+                               S=S, G=G, scale=scale, window=window,
+                               deterministic=deterministic)
+        acc_ref[...] = acc
+        m_ref[...] = m
+        return carry
+
+    jax.lax.fori_loop(0, n_blk, body, 0)
+    slot_ref[0] = (slot0 + n_blk) % 2
+    out, lse = _finish(acc_ref[...], m_ref[...], o_ref.dtype)
+    o_ref[0] = out
+    lse_ref[0] = lse
 
 
 @functools.partial(jax.jit, static_argnames=("sliding_window", "interpret"))
@@ -206,41 +355,39 @@ def paged_window_attention(q, pool_k, pool_v, block_table, base_lens, *,
     G = Hq // Hkv
     R = S * G
     max_blocks = block_table.shape[1]
+    P, hg = tile_plan(Hkv=Hkv, bs=bs, hd=hd, R=R,
+                      itemsize=pool_k.dtype.itemsize, max_blocks=max_blocks)
+    n_groups = Hkv // hg
     # (B,S,Hkv,G,hd) -> (B,Hkv,S,G,hd) -> (B,Hkv,S*G,hd): all of one KV
     # head's window queries ride one MXU tile; row r is window position
     # r // G, query head r % G.
     qg = jnp.transpose(q.reshape(B, S, Hkv, G, hd),
                        (0, 2, 1, 3, 4)).reshape(B, Hkv, R, hd)
 
-    kernel = functools.partial(_paged_window_kernel,
-                               scale=1.0 / (hd ** 0.5), bs=bs, G=G,
-                               window=sliding_window, n_blocks=max_blocks,
-                               deterministic=interpret)
-
-    # The index maps receive the scalar-prefetch refs after the grid
-    # indices: K/V tiles are addressed *through the block table*, so the
-    # pool is read in place — physical block table[b, j] is the (b, ., j)
-    # step's tile, whatever pool slot it landed in at admission time.
-    flat_table = block_table.reshape(-1).astype(jnp.int32)
-
-    def kv_map(b, h, j, table, lens):
-        return (table[b * max_blocks + j], h, 0, 0)
-
+    kernel = functools.partial(
+        _paged_window_kernel, scale=1.0 / (hd ** 0.5), S=S, G=G, bs=bs,
+        P=P, hg=hg, n_groups=n_groups, n_rows=B, max_blocks=max_blocks,
+        window=sliding_window, deterministic=interpret)
+    group = lambda b, g, *_: (b, g, 0, 0)                    # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, max_blocks),
+        grid=(B, n_groups),
         in_specs=[
-            pl.BlockSpec((1, 1, R, hd), lambda b, h, j, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, hd), kv_map),
-            pl.BlockSpec((1, 1, bs, hd), kv_map),
+            pl.BlockSpec((1, hg, R, hd), group),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, R, hd), lambda b, h, j, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, R, 1), lambda b, h, j, *_: (b, h, 0, 0)),
+            pl.BlockSpec((1, hg, R, hd), group),
+            pl.BlockSpec((1, hg, R, 1), group),
         ],
         scratch_shapes=[
-            pltpu.VMEM((R, hd + 1), jnp.float32),    # acc | denominator
-            pltpu.VMEM((R, 1), jnp.float32),         # running max
+            pltpu.VMEM((2, hg, P, bs, hd), pool_k.dtype),   # K, two slots
+            pltpu.VMEM((2, hg, P, bs, hd), pool_v.dtype),   # V, two slots
+            pltpu.SemaphoreType.DMA((2, 2)),                # (K|V, slot)
+            pltpu.VMEM((hg, R, hd + 1), jnp.float32),       # acc | denominator
+            pltpu.VMEM((hg, R, 1), jnp.float32),            # running max
+            pltpu.SMEM((1,), jnp.int32),                    # slot of next block
         ],
     )
     out, lse = pl.pallas_call(
@@ -250,11 +397,14 @@ def paged_window_attention(q, pool_k, pool_v, block_table, base_lens, *,
             jax.ShapeDtypeStruct((B, Hkv, R, hd), q.dtype),
             jax.ShapeDtypeStruct((B, Hkv, R, 1), jnp.float32),
         ],
+        # a step prefetches the next step's first block, so the grid
+        # runs in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(flat_table, jnp.asarray(base_lens, jnp.int32).reshape(-1), qg,
-      pool_k, pool_v)
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="paged_window_attention",
+    )(block_table.reshape(-1).astype(jnp.int32),
+      jnp.asarray(base_lens, jnp.int32).reshape(-1), qg, pool_k, pool_v)
     out = jnp.transpose(out.reshape(B, Hkv, S, G, hd),
                         (0, 2, 1, 3, 4)).reshape(B, S, Hq, hd)
     lse = jnp.transpose(lse.reshape(B, Hkv, S, G),

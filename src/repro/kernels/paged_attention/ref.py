@@ -6,7 +6,8 @@ Two references at different distances from the kernel:
   recurrence — the same shared helpers per block (exact-sum score
   contraction, fused-exp weights, single-contraction rescale, the
   integer causal-in-window mask), in the same order, at the same
-  ``(S*G, ...)`` tile shapes — as a ``lax.scan`` over the block sweep.
+  ``(hg, S*G, P*bs)`` tile shapes (``kernel.tile_plan``) — as a loop
+  over each row's held compute blocks, one call per grid step.
   In float32 the interpret-mode kernel's **attention output matches it
   bit-for-bit** (every sum and contraction on that path is an
   exactly-rounded, fixed-order add chain — see ``kernel._exact_sum`` /
@@ -33,16 +34,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.paged_attention.kernel import (_p_and_alpha, _qk_scores,
-                                                  _rescale_accumulate,
-                                                  _window_mask)
+from repro.kernels.paged_attention.kernel import (_block_update, _finish,
+                                                  held_pages, tile_plan)
 
 NEG_INF = float("-inf")
 
 
 def paged_window_attention_ref(q, pool_k, pool_v, block_table, base_lens, *,
                                sliding_window: int = 0):
-    """Streaming-softmax oracle over the block sweep, q_len >= 1.
+    """Streaming-softmax oracle over the compute-block sweep, q_len >= 1.
 
     q (B,S,Hq,hd) — S window tokens per row at positions
     ``base_lens[b] + [0, S)`` (K/V already scattered); pool_k/pool_v
@@ -54,54 +54,62 @@ def paged_window_attention_ref(q, pool_k, pool_v, block_table, base_lens, *,
     G = Hq // Hkv
     R = S * G
     max_blocks = block_table.shape[1]
+    P, hg = tile_plan(Hkv=Hkv, bs=bs, hd=hd, R=R,
+                      itemsize=pool_k.dtype.itemsize, max_blocks=max_blocks)
+    T = P * bs
     qg = jnp.transpose(q.reshape(B, S, Hkv, G, hd),
                        (0, 2, 1, 3, 4)).reshape(B, Hkv, R, hd)
-    scale = 1.0 / (hd ** 0.5)
     base_lens = jnp.asarray(base_lens, jnp.int32).reshape(-1)
+    # the last compute block may reach past the table; those pages are
+    # never held, so any index does (the kernel copies nothing there)
+    n_cols = -(-max_blocks // P) * P
+    table = jnp.pad(jnp.asarray(block_table, jnp.int32),
+                    ((0, 0), (0, n_cols - max_blocks)))
 
-    def one_head(qbh, table_b, base, h):
-        qf = qbh.astype(jnp.float32)                        # (R, hd)
+    def tile(pool, phys, h0):
+        """(hg, T, hd) float32 of the compute block's pages, the
+        kernel's ``(hg, P, bs, hd)`` buffer read as one tile."""
+        g = jax.lax.dynamic_slice_in_dim(pool[phys], h0, hg, axis=1)
+        return jnp.transpose(g, (1, 0, 2, 3)).astype(
+            jnp.float32).reshape(hg, T, hd)
 
-        def body(carry, j):
-            acc, m_prev = carry
-            phys = table_b[j]
-            k = pool_k[phys, h].astype(jnp.float32)         # (bs, hd)
-            v = pool_v[phys, h].astype(jnp.float32)
-            s = _qk_scores(qf, k, scale, deterministic=True)
-            mask = _window_mask(s.shape, j, base, bs=bs, G=G,
-                                window=sliding_window)
-            s = jnp.where(mask, s, NEG_INF)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-            p, alpha = _p_and_alpha(s, mask, m_prev, m_safe)
-            acc = _rescale_accumulate(p, alpha, v, acc, deterministic=True)
-            return (acc, m_new), None
+    @jax.jit
+    def one_step(qt, table_b, base, h0, pool_k, pool_v):
+        """One grid step of the kernel: row ``table_b``/``base``, heads
+        ``h0 + [0, hg)``, its held compute blocks in order."""
+        n_blk = (held_pages(base, S, bs, max_blocks) + P - 1) // P
 
-        # acc[:, :hd] is the output accumulator, acc[:, hd] the softmax
-        # denominator — one fused contraction per block, same as the
-        # kernel (see kernel._rescale_accumulate for why)
-        init = (jnp.zeros((R, hd + 1), jnp.float32),
-                jnp.full((R, 1), NEG_INF, jnp.float32))
-        (acc, m), _ = jax.lax.scan(body, init, jnp.arange(max_blocks))
-        m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
-        l = jnp.maximum(acc[:, -1:], 1e-30)
-        return ((acc[:, :-1] / l).astype(q.dtype),
-                (m_safe + jnp.log(l))[:, 0])
+        def body(j, carry):
+            acc, m = carry
+            phys = jax.lax.dynamic_slice_in_dim(table_b, j * P, P)
+            return _block_update(qt.astype(jnp.float32),
+                                 tile(pool_k, phys, h0),
+                                 tile(pool_v, phys, h0), acc, m, j, base,
+                                 S=S, G=G, scale=1.0 / (hd ** 0.5),
+                                 window=sliding_window, deterministic=True)
 
-    # Deliberately a host loop, not a vmap: batching the (R, hd) x (bs, hd)
-    # dots changes their reduction pattern on CPU and the kernel is held
-    # to *bit*-exactness against this oracle — every dot here must run at
-    # exactly the tile shape the interpret-mode grid step runs it at.
-    # B and Hkv are single digits in every decode-step context.
+        # acc[..., :hd] is the output accumulator, acc[..., hd] the
+        # softmax denominator — one fused contraction per block, same as
+        # the kernel (see kernel._rescale_accumulate for why)
+        init = (jnp.zeros((hg, R, hd + 1), jnp.float32),
+                jnp.full((hg, R, 1), NEG_INF, jnp.float32))
+        acc, m = jax.lax.fori_loop(0, n_blk, body, init)
+        out, lse = _finish(acc, m, q.dtype)
+        return out, lse[..., 0]
+
+    # One call per (row, head group), as the kernel's grid runs it: every
+    # contraction here runs at exactly the (hg, R, T) tile shape of the
+    # kernel's compute block, not batched across rows.
     outs, lses = [], []
     for b in range(B):
-        o_h, l_h = [], []
-        for h in range(Hkv):
-            o, l = one_head(qg[b, h], block_table[b], base_lens[b], h)
-            o_h.append(o)
-            l_h.append(l)
-        outs.append(jnp.stack(o_h))
-        lses.append(jnp.stack(l_h))
+        o_g, l_g = [], []
+        for h0 in range(0, Hkv, hg):
+            o, l = one_step(qg[b, h0:h0 + hg], table[b], base_lens[b], h0,
+                            pool_k, pool_v)
+            o_g.append(o)
+            l_g.append(l)
+        outs.append(jnp.concatenate(o_g))
+        lses.append(jnp.concatenate(l_g))
     out, lse = jnp.stack(outs), jnp.stack(lses)          # (B,Hkv,R,*)
     out = jnp.transpose(out.reshape(B, Hkv, S, G, hd),
                         (0, 2, 1, 3, 4)).reshape(B, S, Hq, hd)

@@ -212,7 +212,7 @@ def paged_verify_attention(q, pool_k, pool_v, k_new, v_new, block_table,
     ``use_kernel`` runs the **fused multi-token Pallas kernel**
     (``kernels.paged_attention.paged_window_attention``): ONE launch
     covers the whole (q_len, kv_len) window — every window query of
-    every row rides the same grid step, masked causally *inside* the
+    a row rides the same query tile, masked causally *inside* the
     window (query j of row b sees cache positions <= cache_len[b] + j,
     its per-row base length) — with the pool still read in place
     through the scalar-prefetched block table. The jnp path gathers
@@ -267,12 +267,12 @@ def paged_decode_attention(q, pool_k, pool_v, k_new, v_new, block_table,
       path, so the attention math — and therefore the emitted token
       stream — is unchanged.
     * **True (Pallas kernel)** — ``kernels.paged_attention`` reads K/V
-      through the block table *in place* (scalar-prefetched table drives
-      the BlockSpec index maps); no transient gather. This is the
-      q_len = 1 **degenerate case of the fused window kernel** that
-      also serves speculative verify and chunked prefill (see
-      ``paged_verify_attention``) — one kernel body behind every paged
-      consumer. Compiled on TPU, interpret mode elsewhere; held
+      through the block table *in place* (the scalar-prefetched table
+      addresses one DMA per page the row holds); no transient gather.
+      This is the q_len = 1 **degenerate case of the fused window
+      kernel** that also serves speculative verify and chunked prefill
+      (see ``paged_verify_attention``) — one kernel body behind every
+      paged consumer. Compiled on TPU, interpret mode elsewhere; held
       bit-exact (f32) against its streaming jnp oracle by the
       differential grids in ``tests/test_kernels.py``.
     """

@@ -33,10 +33,10 @@ Paged engines add two behaviours on top of the block tables:
   the first append into a shared tail duplicates it on device first
   (copy-on-write), so no holder ever sees another's tokens.
 * **In-place kernel decode** (``use_kernel=True``): the paged attention
-  read runs the Pallas kernel in ``kernels/paged_attention`` (K/V read
-  through the block table via scalar-prefetched index maps, no
-  transient gather; interpret mode off-TPU) instead of the jnp gather
-  reference.
+  read runs the Pallas kernel in ``kernels/paged_attention`` (K/V
+  copied through the scalar-prefetched block table, only the pages each
+  row holds, no transient gather; interpret mode off-TPU) instead of
+  the jnp gather reference.
 
 With ``speculation=k`` (and a draft model) the engine decodes
 **speculatively**: each step, a :class:`~repro.serve.spec.DraftRunner`
@@ -536,6 +536,10 @@ class ServingEngine:
                         # Prometheus tells fused-window from
                         # single-token launches by these two series
                         "kernel_windows": 0, "kernel_positions": 0,
+                        # per paged step program: table pages the
+                        # kernel reads (each row's positions below its
+                        # length + window) vs the whole table's pages
+                        "kernel_pages_held": 0, "kernel_pages_table": 0,
                         # host seconds per engine phase (telemetry.phase):
                         # launching a step (argument transfer, enqueue),
                         # blocking on its result, and admission, with the
@@ -572,6 +576,17 @@ class ServingEngine:
                 for name, fn in progs.items()}
 
     # ---------------------------------------------------------- telemetry
+    def _count_kernel_pages(self, S: int) -> None:
+        """Count the pages one paged step program's kernel reads, per
+        layer: row b's ``S``-token window starts at ``slot_len[b]`` and
+        reads its table's pages below ``slot_len[b] + S`` (an idle row,
+        at length 0, reads one). From the lengths this step is planned
+        with; no device sync."""
+        held = -(-(self.slot_len.astype(np.int64) + S) // self.block_size)
+        self.metrics["kernel_pages_held"] += int(
+            np.minimum(held, self.blocks_per_slot).sum())
+        self.metrics["kernel_pages_table"] += int(self.block_table.size)
+
     def _phase(self, name: str, key: str) -> phase:
         """An engine phase: counted in ``metrics[key]``, spanned on the
         tracer's serve-engine track, a ``serve.<name>`` profiler event."""
@@ -1563,6 +1578,7 @@ class ServingEngine:
             self.metrics["kernel_windows"] += 1
             self.metrics["kernel_positions"] += int(
                 sum(n_write[i] for i in active))
+            self._count_kernel_pages(k + 1)
         with self._launch():
             if self.paged:
                 a, out_toks, lps, self.caches = self._verify(
@@ -1670,6 +1686,7 @@ class ServingEngine:
         if self.paged and self.use_kernel:
             self.metrics["kernel_windows"] += 1
             self.metrics["kernel_positions"] += sum(n_fed.values())
+            self._count_kernel_pages(W)
         with self._launch():
             if self.paged:
                 nxt, logp, self.caches = self._chunk_fn(
@@ -1811,6 +1828,7 @@ class ServingEngine:
         samp = self._sampling_slots()
         if self.paged and self.use_kernel:
             self.metrics["kernel_positions"] += len(active)
+            self._count_kernel_pages(1)
         with self._launch():
             if self.paged:
                 nxt, logp, self.caches = self._decode(

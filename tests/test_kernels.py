@@ -132,14 +132,17 @@ def test_wkv_state_carry_equals_two_halves():
 # --------------------------------------------------- paged decode attention
 from repro.kernels.paged_attention.ops import (  # noqa: E402
     paged_decode_attention as paged_decode)
+from repro.kernels.paged_attention.kernel import tile_plan  # noqa: E402
 from repro.kernels.paged_attention.ref import (  # noqa: E402
     gathered_decode_ref, paged_decode_attention_ref)
 
 
-def _paged_case(B, Hq, Hkv, hd, bs, max_blocks, dt, *, seed=0, full=False):
+def _paged_case(B, Hq, Hkv, hd, bs, max_blocks, dt, *, seed=0, full=False,
+                lens=None):
     """A pool + per-row disjoint block tables at ragged lengths, the
     shapes the serving engine hands the kernel: zeroed table tails point
-    at the scratch block, row lengths land anywhere in [1, capacity]."""
+    at the scratch block, row lengths land anywhere in [1, capacity]
+    (or are the given ``lens``; a row of length 0 owns no block)."""
     nb = B * max_blocks + 2
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(ks[0], (B, Hq, hd), dt)
@@ -147,11 +150,15 @@ def _paged_case(B, Hq, Hkv, hd, bs, max_blocks, dt, *, seed=0, full=False):
     pool_v = jax.random.normal(ks[2], (nb, Hkv, bs, hd), dt)
     rng = np.random.default_rng(seed + B * 1000 + hd)
     free = list(rng.permutation(np.arange(1, nb)))
+    given = lens
     lens = np.zeros(B, np.int32)
     table = np.zeros((B, max_blocks), np.int32)
     for b in range(B):
-        lens[b] = max_blocks * bs if full \
-            else int(rng.integers(1, max_blocks * bs + 1))
+        if given is not None:
+            lens[b] = given[b]
+        else:
+            lens[b] = max_blocks * bs if full \
+                else int(rng.integers(1, max_blocks * bs + 1))
         for i in range(-(-int(lens[b]) // bs)):
             table[b, i] = free.pop()
     return q, pool_k, pool_v, jnp.asarray(table), jnp.asarray(lens)
@@ -213,6 +220,56 @@ def test_paged_decode_kernel_differential(B, Hq, Hkv, hd, bs, mb, win, dt):
                                atol=tol(dt), rtol=tol(dt))
 
 
+def _block_tokens(Hq, Hkv, hd, bs, mb, dt, S):
+    """Tokens per compute block the kernel plans for this shape."""
+    P, _ = tile_plan(Hkv=Hkv, bs=bs, hd=hd, R=S * Hq // Hkv,
+                     itemsize=jnp.dtype(dt).itemsize, max_blocks=mb)
+    return P * bs
+
+
+# query heads x KV heads x head_dim x block_size x table pages x dtype;
+# every table spans several compute blocks
+PAGED_SPAN_GRID = [
+    (8, 2, 32, 16, 40, jnp.float32),     # GQA: blocks of 16, 16, 8 pages
+    (4, 4, 32, 8, 40, jnp.float32),      # MHA: blocks of 32, 8 pages
+    (8, 2, 32, 16, 40, jnp.bfloat16),
+    (4, 4, 32, 8, 40, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("Hq,Hkv,hd,bs,mb,dt", PAGED_SPAN_GRID)
+def test_paged_decode_kernel_spans_compute_blocks(Hq, Hkv, hd, bs, mb, dt):
+    """Row lengths at the compute-block edges of a table several blocks
+    long: one token, exactly one block, one token past it, the full
+    table, and a row that holds nothing. The kernel loops over each
+    row's held blocks only; it must match the streaming oracle (f32:
+    out <= 4 ulp / lse <= 32 ulp) and the gather oracle, and the empty
+    row must end as it always has: out 0, lse log(1e-30)."""
+    T = _block_tokens(Hq, Hkv, hd, bs, mb, dt, 1)
+    assert mb * bs > T
+    lens = [1, T, T + 1, mb * bs, 0]
+    q, pk, pv, table, lens = _paged_case(len(lens), Hq, Hkv, hd, bs, mb,
+                                         dt, lens=lens)
+    out, lse = paged_decode(q, pk, pv, table, lens)
+    ro, rl = paged_decode_attention_ref(q, pk, pv, table, lens)
+    go, gl = gathered_decode_ref(q, pk, pv, table, lens)
+    if dt == jnp.float32:
+        _assert_ulp(out, ro, 4)
+        _assert_ulp(lse, rl, 32)
+    else:
+        np.testing.assert_allclose(np.float32(out), np.float32(ro),
+                                   atol=tol(dt), rtol=tol(dt))
+        np.testing.assert_allclose(np.float32(lse), np.float32(rl),
+                                   atol=tol(dt), rtol=tol(dt))
+    np.testing.assert_allclose(np.float32(out[:-1]), np.float32(go[:-1]),
+                               atol=tol(dt), rtol=tol(dt))
+    np.testing.assert_allclose(np.float32(lse[:-1]), np.float32(gl[:-1]),
+                               atol=tol(dt), rtol=tol(dt))
+    np.testing.assert_array_equal(np.float32(out[-1]), 0.0)
+    np.testing.assert_array_equal(np.asarray(lse[-1]),
+                                  np.log(np.float32(1e-30)))
+
+
 def test_paged_decode_kernel_full_and_single_token_rows():
     """Length edges: a row at exactly full capacity and (via seed reroll)
     rows at 1 token keep the mask honest at both extremes."""
@@ -270,11 +327,13 @@ from repro.kernels.paged_attention.ref import (  # noqa: E402
     gathered_window_ref, paged_window_attention_ref)
 
 
-def _window_case(B, S, Hq, Hkv, hd, bs, max_blocks, dt, *, seed=0):
+def _window_case(B, S, Hq, Hkv, hd, bs, max_blocks, dt, *, seed=0,
+                 base=None):
     """Window variant of ``_paged_case``: each row holds a ragged base
-    length (including 0 — a chunked-prefill first chunk) and owns
-    blocks covering ``base + S`` tokens, i.e. the window's K/V is
-    already scattered into the pool; table tails stay at scratch."""
+    length (including 0 — a chunked-prefill first chunk; or the given
+    ``base``) and owns blocks covering ``base + S`` tokens, i.e. the
+    window's K/V is already scattered into the pool; table tails stay
+    at scratch."""
     nb = B * max_blocks + 2
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(ks[0], (B, S, Hq, hd), dt)
@@ -282,10 +341,12 @@ def _window_case(B, S, Hq, Hkv, hd, bs, max_blocks, dt, *, seed=0):
     pool_v = jax.random.normal(ks[2], (nb, Hkv, bs, hd), dt)
     rng = np.random.default_rng(seed + B * 1000 + S * 100 + hd)
     free = list(rng.permutation(np.arange(1, nb)))
+    given = base
     base = np.zeros(B, np.int32)
     table = np.zeros((B, max_blocks), np.int32)
     for b in range(B):
-        base[b] = int(rng.integers(0, max_blocks * bs - S + 1))
+        base[b] = int(rng.integers(0, max_blocks * bs - S + 1)) \
+            if given is None else given[b]
         for i in range(-(-int(base[b] + S) // bs)):
             table[b, i] = free.pop()
     return q, pool_k, pool_v, jnp.asarray(table), jnp.asarray(base)
@@ -333,6 +394,82 @@ def test_paged_window_kernel_differential(S, B, Hq, Hkv, hd, bs, mb, win,
                                atol=tol(dt), rtol=tol(dt))
     np.testing.assert_allclose(np.float32(lse), np.float32(gl),
                                atol=tol(dt), rtol=tol(dt))
+
+
+# q_len x query heads x KV heads x head_dim x block_size x table pages
+# x dtype; every table spans several compute blocks
+WINDOW_SPAN_GRID = [
+    (64, 16, 4, 32, 16, 40, jnp.float32),    # GQA, R 256: head groups
+    (64, 4, 4, 32, 16, 40, jnp.bfloat16),    # MHA
+]
+
+
+@pytest.mark.parametrize("S,Hq,Hkv,hd,bs,mb,dt", WINDOW_SPAN_GRID)
+def test_paged_window_kernel_spans_compute_blocks(S, Hq, Hkv, hd, bs, mb,
+                                                  dt):
+    """Chunk windows whose rows end at the compute-block edges of a
+    table several blocks long: a first chunk (base 0), a window ending
+    exactly on a block, one ending one token past it, and one ending at
+    the table's end, against the streaming and gather oracles."""
+    T = _block_tokens(Hq, Hkv, hd, bs, mb, dt, S)
+    assert mb * bs > T
+    base = [0, T - S, T - S + 1, mb * bs - S]
+    q, pk, pv, table, base = _window_case(len(base), S, Hq, Hkv, hd, bs,
+                                          mb, dt, base=base)
+    out, lse = paged_window(q, pk, pv, table, base)
+    ro, rl = paged_window_attention_ref(q, pk, pv, table, base)
+    go, gl = gathered_window_ref(q, pk, pv, table, base)
+    if dt == jnp.float32:
+        _assert_ulp(out, ro, 4)
+        _assert_ulp(lse, rl, 32)
+    else:
+        np.testing.assert_allclose(np.float32(out), np.float32(ro),
+                                   atol=tol(dt), rtol=tol(dt))
+        np.testing.assert_allclose(np.float32(lse), np.float32(rl),
+                                   atol=tol(dt), rtol=tol(dt))
+    np.testing.assert_allclose(np.float32(out), np.float32(go),
+                               atol=tol(dt), rtol=tol(dt))
+    np.testing.assert_allclose(np.float32(lse), np.float32(gl),
+                               atol=tol(dt), rtol=tol(dt))
+
+
+def test_paged_window_tile_plan_splits_heads_only_for_vmem():
+    """All KV heads ride one grid step unless the step's VMEM would pass
+    the budget: a 256-row query tile (S 64 x G 4) splits into head
+    groups, a decode tile of the same heads does not, and every plan
+    fits."""
+    from repro.kernels.paged_attention.kernel import (VMEM_BUDGET_BYTES,
+                                                      _step_vmem_bytes)
+    wide = tile_plan(Hkv=8, bs=16, hd=128, R=256, itemsize=2, max_blocks=64)
+    narrow = tile_plan(Hkv=8, bs=16, hd=128, R=4, itemsize=2, max_blocks=64)
+    assert narrow == (16, 8)
+    assert wide[0] == 16 and 8 % wide[1] == 0 and wide[1] < 8
+    for P, hg in (wide, narrow):
+        R = 256 if (P, hg) == wide else 4
+        assert _step_vmem_bytes(hg, P * 16, R, 128, 2) <= VMEM_BUDGET_BYTES
+    assert tile_plan(Hkv=2, bs=16, hd=64, R=2, itemsize=4,
+                     max_blocks=3) == (3, 2)
+
+
+@pytest.mark.parametrize("S", [1, 64])
+def test_paged_kernel_never_reads_past_held_pages(S):
+    """Pages past a row's held length are never read: with the scratch
+    block, where every table tail points, full of NaN, every output and
+    lse is finite and equal to the zeroed-scratch case. A kernel that
+    copied the tails and only masked their scores would turn them into
+    NaN (a masked weight of 0 times NaN)."""
+    Hq, Hkv, hd, bs, mb, dt = 8, 2, 32, 16, 40, jnp.float32
+    T = _block_tokens(Hq, Hkv, hd, bs, mb, dt, S)
+    base = [0, T - S, T - S + 1, 5]
+    q, pk, pv, table, base = _window_case(len(base), S, Hq, Hkv, hd, bs,
+                                          mb, dt, base=base)
+    runs = [paged_window(q, pk.at[0].set(fill), pv.at[0].set(fill),
+                         table, base) for fill in (jnp.nan, 0.0)]
+    (out, lse), (out0, lse0) = runs
+    assert np.isfinite(np.asarray(out)).all()
+    assert np.isfinite(np.asarray(lse)).all()
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out0))
+    np.testing.assert_array_equal(np.asarray(lse), np.asarray(lse0))
 
 
 def test_paged_window_kernel_decode_degenerate():

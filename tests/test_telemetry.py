@@ -614,6 +614,35 @@ def test_kernel_dispatch_counters_reach_prometheus(stack):
     assert gather.metrics["kernel_positions"] == 0
 
 
+def test_kernel_page_counters_match_hand_count(stack):
+    """`kernel_pages_held` adds, per paged step program, the table pages
+    each row's window reads (positions below its length + window; an
+    idle row at length 0 reads one), `kernel_pages_table` the whole
+    table (2 slots x 8 pages of 8 tokens). Prompts 20 and 9, chunk 8:
+    admission writes 8 tokens of each, then
+
+    * chunk window 8 at lengths [8, 8]: 2 + 2 = 4 pages
+    * chunk window 8 (a 4-token chunk rides the smallest width) at
+      [16, 9]: ceil(24/8) + ceil(17/8) = 6
+    * decode at [20, 10]: ceil(21/8) + ceil(11/8) = 5
+    * decode at [0, 11], row 0 done after 2 tokens: 1 + 2 = 3
+
+    18 held of 4 x 16. The gather path counts nothing."""
+    cfg, model, params = stack
+    prompts = _prompts(cfg, [20, 9], seed=21)
+    counts = []
+    for use_kernel in (True, False):
+        eng = ServingEngine(model, params, batch_size=2, max_seq=MAX_SEQ,
+                            block_size=8, use_kernel=use_kernel,
+                            prefill_chunk=8)
+        eng.run([Request(rid=i, prompt=list(p), max_new_tokens=n)
+                 for i, (p, n) in enumerate(zip(prompts, (2, 4)))])
+        assert eng.metrics["decode_steps"] == 4
+        counts.append((eng.metrics["kernel_pages_held"],
+                       eng.metrics["kernel_pages_table"]))
+    assert counts == [(18, 64), (0, 0)]
+
+
 # ------------------------------------------------- service-level scrape
 def test_service_and_supervisor_prometheus_exposition(stack):
     from repro.core.supervisor import Supervisor

@@ -68,21 +68,40 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _compile_paged_kernel(sharding, arch: str, batch: int, max_seq: int,
+                          S: int) -> str:
+    """HLO text of the paged kernel compiled for the described chip:
+    ``batch`` rows of ``S`` window tokens against a head-major bf16 pool
+    of ``max_seq / BLOCK`` blocks per row at ``arch``'s widths."""
+    cfg = get_config(arch)
+    Hq, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    mb = max_seq // BLOCK
+    nb = batch * mb + 1
+    pool = _sds((nb, Hkv, BLOCK, hd), jnp.bfloat16, sharding)
+    fn = jax.jit(lambda q, k, v, t, b: paged_window_attention(
+        q, k, v, t, b, interpret=False))
+    return fn.lower(_sds((batch, S, Hq, hd), jnp.bfloat16, sharding),
+                    pool, pool, _sds((batch, mb), jnp.int32, sharding),
+                    _sds((batch,), jnp.int32, sharding)).compile().as_text()
+
+
 @pytest.mark.parametrize("S", [1, 4, 64])
 def test_paged_kernel_compiles_at_qwen3_4b_widths(one_chip, S):
     """Decode (S=1), speculative verify (S=4) and a chunk window (S=64)
     against a head-major bf16 pool of 128 blocks per row."""
-    cfg = get_config("qwen3-4b")
-    Hq, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    mb = MAX_SEQ // BLOCK
-    nb = BATCH * mb + 1
-    pool = _sds((nb, Hkv, BLOCK, hd), jnp.bfloat16, one_chip)
-    fn = jax.jit(lambda q, k, v, t, b: paged_window_attention(
-        q, k, v, t, b, interpret=False))
-    compiled = fn.lower(_sds((BATCH, S, Hq, hd), jnp.bfloat16, one_chip),
-                        pool, pool, _sds((BATCH, mb), jnp.int32, one_chip),
-                        _sds((BATCH,), jnp.int32, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = _compile_paged_kernel(one_chip, "qwen3-4b", BATCH, MAX_SEQ, S)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("arch", ["qwen3-4b", "deepseek-7b"])
+@pytest.mark.parametrize("S", [1, 64])
+def test_paged_kernel_compiles_at_cell_shapes(one_chip, arch, S):
+    """The benchmark cells' engine shape (batch 16 x 1024 tokens, 16-token
+    pages): decode and a 64-token chunk window at Qwen3-4B's widths (GQA,
+    8 KV heads) and DeepSeek-LLM-7B's (MHA, 32 KV heads), so that a
+    kernel tile plan past the chip's VMEM fails here, not on the chip."""
+    text = _compile_paged_kernel(one_chip, arch, 16, 1024, S)
+    assert "tpu_custom_call" in text
 
 
 @pytest.fixture(scope="module")
