@@ -61,6 +61,22 @@ class ArchConfig:
     # --- attention variant ---
     sliding_window: int = 0      # 0 = full causal attention
     attention_free: bool = False
+    # --- per-layer pattern (hybrid stacks) ---
+    # one kind per layer, "mamba" | "attention"; empty = every layer is
+    # the family's one kind (transformer.block_kind)
+    layer_types: tuple = ()
+    # --- Mamba-2 mixer [arXiv:2405.21060] ---
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_state: int = 0
+    mamba_groups: int = 1
+    mamba_conv: int = 4
+    mamba_chunk: int = 256
+    # --- Granite multipliers (the defaults leave a model unscaled) ---
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0  # softmax scale; 0 -> 1/sqrt(hd)
+    logits_scaling: float = 1.0
     # --- misc ---
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
@@ -76,6 +92,25 @@ class ArchConfig:
     @property
     def dinner(self) -> int:
         return self.d_inner or (2 * self.d_model)
+
+    @property
+    def period(self) -> tuple:
+        """The shortest run of ``layer_types`` that repeats over the
+        whole stack (the whole pattern when nothing shorter does)."""
+        t = tuple(self.layer_types)
+        for n in range(1, len(t) + 1):
+            if len(t) % n == 0 and t == t[:n] * (len(t) // n):
+                return t[:n]
+        return t
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels through the causal conv: x, then B and C."""
+        return self.mamba_inner + 2 * self.mamba_groups * self.mamba_state
 
     @property
     def uses_attention(self) -> bool:
@@ -101,6 +136,14 @@ class ArchConfig:
         if self.family in ("ssm", "hybrid"):
             di = self.dinner
             ssm = d * di * 2 + di * d + 2 * di * max(self.ssm_state, 1)
+        if self.layer_types:
+            di, cd, nh = self.mamba_inner, self.mamba_conv_dim, \
+                self.mamba_heads
+            mamba = d * (di + cd + nh) + cd * (self.mamba_conv + 1) \
+                + 3 * nh + di + di * d
+            n_m = sum(t == "mamba" for t in self.layer_types)
+            mixers = n_m * mamba + (self.n_layers - n_m) * attn
+            return emb + mixers + self.n_layers * (ffn + 2 * d)
         per_layer = (attn if self.uses_attention else 0) + ffn + ssm + 2 * d
         enc = self.encoder_layers * (attn + nmat * d * self.d_ff + 2 * d)
         return emb + self.n_layers * per_layer + enc

@@ -337,16 +337,19 @@ def _paged_window_kernel(table_ref, base_ref, q_ref, pool_k, pool_v, o_ref,
     lse_ref[0] = lse
 
 
-@functools.partial(jax.jit, static_argnames=("sliding_window", "interpret"))
+@functools.partial(jax.jit, static_argnames=("sliding_window", "interpret",
+                                             "scale"))
 def paged_window_attention(q, pool_k, pool_v, block_table, base_lens, *,
-                           sliding_window: int = 0, interpret: bool = True):
+                           sliding_window: int = 0, interpret: bool = True,
+                           scale: float | None = None):
     """The fused multi-token tile. q (B, S, Hq, hd) — S window tokens
     per row at absolute positions ``base_lens[b] + [0, S)``, their K/V
     already scattered into the pool; pool_k/pool_v (num_blocks, Hkv,
     bs, hd); block_table (B, max_blocks) int32; base_lens (B,) int32
     tokens resident per row *before* the window. Window query w of row
     b attends to cache positions ``[0, base_lens[b] + w]`` (causal in
-    the window). Returns (out (B,S,Hq,hd) in q.dtype, lse (B,S,Hq) f32).
+    the window); ``scale`` the softmax scale (None: 1/sqrt(hd)). Returns
+    (out (B,S,Hq,hd) in q.dtype, lse (B,S,Hq) f32).
 
     ``S = 1`` with ``base_lens = lengths - 1`` is exactly the classic
     single-token paged decode — one code path, every consumer."""
@@ -355,6 +358,20 @@ def paged_window_attention(q, pool_k, pool_v, block_table, base_lens, *,
     G = Hq // Hkv
     R = S * G
     max_blocks = block_table.shape[1]
+    if S % 2 == 0 and _step_vmem_bytes(
+            1, min(BLOCK_TOKENS, max_blocks * bs), R, hd,
+            pool_k.dtype.itemsize) > VMEM_BUDGET_BYTES:
+        # the window's query rows alone overflow one step's VMEM (the
+        # [p | diag(alpha)] tile grows as R^2): run it as two half
+        # windows, the second starting where the first ends
+        h = S // 2
+        base = jnp.asarray(base_lens, jnp.int32).reshape(-1)
+        halves = [paged_window_attention(
+            q[:, i * h:(i + 1) * h], pool_k, pool_v, block_table,
+            base + i * h, sliding_window=sliding_window,
+            interpret=interpret, scale=scale) for i in range(2)]
+        return tuple(jnp.concatenate(parts, axis=1)
+                     for parts in zip(*halves))
     P, hg = tile_plan(Hkv=Hkv, bs=bs, hd=hd, R=R,
                       itemsize=pool_k.dtype.itemsize, max_blocks=max_blocks)
     n_groups = Hkv // hg
@@ -365,7 +382,8 @@ def paged_window_attention(q, pool_k, pool_v, block_table, base_lens, *,
                        (0, 2, 1, 3, 4)).reshape(B, Hkv, R, hd)
 
     kernel = functools.partial(
-        _paged_window_kernel, scale=1.0 / (hd ** 0.5), S=S, G=G, bs=bs,
+        _paged_window_kernel,
+        scale=1.0 / (hd ** 0.5) if scale is None else scale, S=S, G=G, bs=bs,
         P=P, hg=hg, n_groups=n_groups, n_rows=B, max_blocks=max_blocks,
         window=sliding_window, deterministic=interpret)
     group = lambda b, g, *_: (b, g, 0, 0)                    # noqa: E731
@@ -412,9 +430,11 @@ def paged_window_attention(q, pool_k, pool_v, block_table, base_lens, *,
     return out, lse
 
 
-@functools.partial(jax.jit, static_argnames=("sliding_window", "interpret"))
+@functools.partial(jax.jit, static_argnames=("sliding_window", "interpret",
+                                             "scale"))
 def paged_decode_attention(q, pool_k, pool_v, block_table, lengths, *,
-                           sliding_window: int = 0, interpret: bool = True):
+                           sliding_window: int = 0, interpret: bool = True,
+                           scale: float | None = None):
     """Single-token decode — the fused window kernel at its S = 1
     degenerate case. q (B,Hq,hd); pool_k/pool_v (num_blocks, Hkv, bs,
     hd); block_table (B, max_blocks) int32; lengths (B,) int32 valid
@@ -424,5 +444,5 @@ def paged_decode_attention(q, pool_k, pool_v, block_table, lengths, *,
     out, lse = paged_window_attention(q[:, None], pool_k, pool_v,
                                       block_table, base,
                                       sliding_window=sliding_window,
-                                      interpret=interpret)
+                                      interpret=interpret, scale=scale)
     return out[:, 0], lse[:, 0]

@@ -28,21 +28,26 @@ __all__ = ["paged_decode_attention", "paged_decode_attention_ref",
 
 
 def paged_decode_attention(q, pool_k, pool_v, block_table, lengths, *,
-                           sliding_window: int = 0, force_ref: bool = False):
+                           sliding_window: int = 0, force_ref: bool = False,
+                           scale=None):
     """q (B,Hq,hd); pool_k/pool_v (num_blocks, Hkv, bs, hd); block_table
     (B, max_blocks) int32; lengths (B,) valid tokens per row (new token
-    already scattered). Returns (out (B,Hq,hd), lse (B,Hq) f32)."""
+    already scattered); ``scale`` the softmax scale (None: 1/sqrt(hd)).
+    Returns (out (B,Hq,hd), lse (B,Hq) f32)."""
     if force_ref:
         return paged_decode_attention_ref(q, pool_k, pool_v, block_table,
                                           lengths,
-                                          sliding_window=sliding_window)
+                                          sliding_window=sliding_window,
+                                          scale=scale)
     on_tpu = jax.default_backend() == "tpu"
     return _decode_kernel(q, pool_k, pool_v, block_table, lengths,
-                          sliding_window=sliding_window, interpret=not on_tpu)
+                          sliding_window=sliding_window, interpret=not on_tpu,
+                          scale=scale)
 
 
 def paged_window_attention(q, pool_k, pool_v, block_table, base_lens, *,
-                           sliding_window: int = 0, force_ref: bool = False):
+                           sliding_window: int = 0, force_ref: bool = False,
+                           scale=None):
     """Fused multi-token window: q (B,S,Hq,hd) at absolute positions
     ``base_lens[b] + [0, S)`` (K/V already scattered — diverted writes
     landed in scratch and are masked by causality for every position
@@ -51,7 +56,9 @@ def paged_window_attention(q, pool_k, pool_v, block_table, base_lens, *,
     if force_ref:
         return paged_window_attention_ref(q, pool_k, pool_v, block_table,
                                           base_lens,
-                                          sliding_window=sliding_window)
+                                          sliding_window=sliding_window,
+                                          scale=scale)
     on_tpu = jax.default_backend() == "tpu"
     return _window_kernel(q, pool_k, pool_v, block_table, base_lens,
-                          sliding_window=sliding_window, interpret=not on_tpu)
+                          sliding_window=sliding_window, interpret=not on_tpu,
+                          scale=scale)

@@ -41,15 +41,17 @@ NEG_INF = float("-inf")
 
 
 def paged_window_attention_ref(q, pool_k, pool_v, block_table, base_lens, *,
-                               sliding_window: int = 0):
+                               sliding_window: int = 0, scale=None):
     """Streaming-softmax oracle over the compute-block sweep, q_len >= 1.
 
     q (B,S,Hq,hd) — S window tokens per row at positions
     ``base_lens[b] + [0, S)`` (K/V already scattered); pool_k/pool_v
     (num_blocks, Hkv, bs, hd); block_table (B, max_blocks) int32;
-    base_lens (B,) tokens resident per row before the window. Returns
-    (out (B,S,Hq,hd) in q.dtype, lse (B,S,Hq) f32)."""
+    base_lens (B,) tokens resident per row before the window; ``scale``
+    the softmax scale (None: 1/sqrt(hd)). Returns (out (B,S,Hq,hd) in
+    q.dtype, lse (B,S,Hq) f32)."""
     B, S, Hq, hd = q.shape
+    scale = 1.0 / (hd ** 0.5) if scale is None else scale
     Hkv, bs = pool_k.shape[1], pool_k.shape[2]
     G = Hq // Hkv
     R = S * G
@@ -85,7 +87,7 @@ def paged_window_attention_ref(q, pool_k, pool_v, block_table, base_lens, *,
             return _block_update(qt.astype(jnp.float32),
                                  tile(pool_k, phys, h0),
                                  tile(pool_v, phys, h0), acc, m, j, base,
-                                 S=S, G=G, scale=1.0 / (hd ** 0.5),
+                                 S=S, G=G, scale=scale,
                                  window=sliding_window, deterministic=True)
 
         # acc[..., :hd] is the output accumulator, acc[..., hd] the
@@ -119,7 +121,7 @@ def paged_window_attention_ref(q, pool_k, pool_v, block_table, base_lens, *,
 
 
 def paged_decode_attention_ref(q, pool_k, pool_v, block_table, lengths, *,
-                               sliding_window: int = 0):
+                               sliding_window: int = 0, scale=None):
     """Single-token streaming oracle — the window ref at S = 1.
 
     q (B,Hq,hd); lengths (B,) valid tokens per row. Returns
@@ -127,7 +129,8 @@ def paged_decode_attention_ref(q, pool_k, pool_v, block_table, lengths, *,
     base = jnp.asarray(lengths, jnp.int32).reshape(-1) - 1
     out, lse = paged_window_attention_ref(q[:, None], pool_k, pool_v,
                                           block_table, base,
-                                          sliding_window=sliding_window)
+                                          sliding_window=sliding_window,
+                                          scale=scale)
     return out[:, 0], lse[:, 0]
 
 

@@ -62,16 +62,19 @@ def _hbm_limit():
 def plan_memory(eng: ServingEngine, compiled: dict) -> dict:
     """Device bytes each serving program needs while it runs: its
     arguments, outputs not aliased to donated arguments, and
-    temporaries, plus the KV pool where the pool is not an argument
-    (admission). ``peak`` is the largest of them."""
-    pool = _nbytes(eng.caches)
-    plan = {"params": _nbytes(eng.params), "pool": pool,
+    temporaries, plus the cache where the cache is not an argument
+    (admission). The cache is the KV pool (``pool``) and, for a model
+    with per-slot recurrent state, the state rows (``state``), which
+    do not shrink with the pool. ``peak`` is the largest of them."""
+    state = _nbytes({k: eng.caches[k] for k in eng.state_keys})
+    pool = _nbytes(eng.caches) - state
+    plan = {"params": _nbytes(eng.params), "pool": pool, "state": state,
             "num_blocks": eng.pool.total}
     for name, c in compiled.items():
         ma = c.memory_analysis()
         live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                 - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
-        plan[name] = live + (pool if name == "admit" else 0)
+        plan[name] = live + (pool + state if name == "admit" else 0)
     plan["peak"] = max(plan[name] for name in compiled)
     return plan
 
@@ -118,7 +121,8 @@ def describe_plan(plan) -> str:
     hbm = (f"{gb(plan['hbm'])} device memory, budget {HBM_BUDGET:.0%}"
            if plan["hbm"] else "device memory not reported")
     return (f"memory: params {gb(plan['params'])}, KV pool "
-            f"{plan['num_blocks']} blocks {gb(plan['pool'])}; per program "
+            f"{plan['num_blocks']} blocks {gb(plan['pool'])}, state rows "
+            f"{gb(plan['state'])}; per program "
             f"(args + unaliased outputs + temps, + pool at admit): decode "
             f"{gb(plan['decode'])}, chunk {gb(plan['chunk'])}, admit "
             f"{gb(plan['admit'])}; peak {gb(plan['peak'])} of {hbm}")

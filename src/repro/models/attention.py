@@ -67,12 +67,15 @@ def _expand_kv(kv, G: int):
         .reshape(B, T, Hkv * G, hd)
 
 
-def _gqa_scores(q, k):
-    """q (B,S,Hq,hd), k (B,T,Hkv,hd) -> scores (B,Hq,S,T) in f32."""
+def _gqa_scores(q, k, scale=None):
+    """q (B,S,Hq,hd), k (B,T,Hkv,hd) -> scores (B,Hq,S,T) in f32, scaled
+    by ``scale`` (None: 1/sqrt(hd))."""
     B, S, Hq, hd = q.shape
     kx = _expand_kv(k, Hq // k.shape[2])
     s = jnp.einsum("bshd,bthd->bhst", q, kx,
                    preferred_element_type=jnp.float32)
+    if scale is not None:
+        return s * scale
     return s / jnp.sqrt(jnp.asarray(hd, jnp.float32))
 
 
@@ -88,10 +91,11 @@ def _combine(scores, v, Hq: int):
 Q_CHUNK = 1024  # query-block size for the chunked jnp path
 
 
-def _masked_attention(q, k, v, q_offset, *, sliding_window=0, causal=True):
+def _masked_attention(q, k, v, q_offset, *, sliding_window=0, causal=True,
+                      scale=None):
     """q (B,S,Hq,hd) at absolute positions q_offset + [0,S)."""
     S, T = q.shape[1], k.shape[1]
-    scores = _gqa_scores(q, k)
+    scores = _gqa_scores(q, k, scale)
     i = jnp.arange(S)[:, None] + q_offset     # absolute q positions
     j = jnp.arange(T)[None, :]
     mask = jnp.ones((S, T), bool)
@@ -103,7 +107,8 @@ def _masked_attention(q, k, v, q_offset, *, sliding_window=0, causal=True):
     return _combine(scores, v, q.shape[2])
 
 
-def causal_attention(q, k, v, *, sliding_window: int = 0, causal: bool = True):
+def causal_attention(q, k, v, *, sliding_window: int = 0, causal: bool = True,
+                     scale=None):
     """Full or sliding-window (causal) attention; q/k/v aligned in time.
 
     Long sequences are processed in query chunks (``lax.scan``) so the
@@ -114,14 +119,15 @@ def causal_attention(q, k, v, *, sliding_window: int = 0, causal: bool = True):
     T = k.shape[1]
     if S <= Q_CHUNK or S % Q_CHUNK:
         return _masked_attention(q, k, v, T - S, sliding_window=sliding_window,
-                                 causal=causal)
+                                 causal=causal, scale=scale)
     nc = S // Q_CHUNK
     qc = jnp.moveaxis(q.reshape(B, nc, Q_CHUNK, Hq, hd), 1, 0)
 
     def body(_, inp):
         i, qi = inp
         o = _masked_attention(qi, k, v, T - S + i * Q_CHUNK,
-                              sliding_window=sliding_window, causal=causal)
+                              sliding_window=sliding_window, causal=causal,
+                              scale=scale)
         return None, o
 
     # flash-attention memory behaviour: recompute each chunk's scores in
@@ -132,7 +138,8 @@ def causal_attention(q, k, v, *, sliding_window: int = 0, causal: bool = True):
     return jnp.moveaxis(out, 0, 1).reshape(B, S, Hq * hd)
 
 
-def decode_attention(q, k_cache, v_cache, n_valid, *, sliding_window: int = 0):
+def decode_attention(q, k_cache, v_cache, n_valid, *, sliding_window: int = 0,
+                     scale=None):
     """One new token per sequence attending to the cache.
 
     q: (B, 1, Hq, hd); k/v_cache: (B, T, Hkv, hd); n_valid: scalar or (B,)
@@ -142,7 +149,7 @@ def decode_attention(q, k_cache, v_cache, n_valid, *, sliding_window: int = 0):
     PV contraction lower to partial-max/partial-sum + all-reduce — i.e.
     flash-decoding style LSE combination, inserted by the partitioner.
     """
-    scores = _gqa_scores(q, k_cache)                       # (B,Hq,1,T)
+    scores = _gqa_scores(q, k_cache, scale)                # (B,Hq,1,T)
     T = k_cache.shape[1]
     j = jnp.arange(T)
     n_valid = jnp.asarray(n_valid)
@@ -154,7 +161,8 @@ def decode_attention(q, k_cache, v_cache, n_valid, *, sliding_window: int = 0):
     return _combine(scores, v_cache, q.shape[2])
 
 
-def verify_decode_attention(q, k_cache, v_cache, base, *, sliding_window=0):
+def verify_decode_attention(q, k_cache, v_cache, base, *, sliding_window=0,
+                            scale=None):
     """Multi-token (speculative verify) decode against a stripe cache.
 
     q: (B, S, Hq, hd) — S = k+1 tokens per row at absolute positions
@@ -165,7 +173,7 @@ def verify_decode_attention(q, k_cache, v_cache, base, *, sliding_window=0):
     committed context plus proposals d_1..d_j — exactly what j+1
     sequential ``decode_attention`` calls would each see.
     """
-    scores = _gqa_scores(q, k_cache)                       # (B,Hq,S,T)
+    scores = _gqa_scores(q, k_cache, scale)                # (B,Hq,S,T)
     S, T = q.shape[1], k_cache.shape[1]
     base = jnp.asarray(base).reshape(-1, 1, 1)             # (B,1,1)
     i = base + jnp.arange(S)[None, :, None]                # abs q position
@@ -176,6 +184,39 @@ def verify_decode_attention(q, k_cache, v_cache, base, *, sliding_window=0):
     scores = jnp.where(valid[:, None, :, :], scores,
                        jnp.finfo(jnp.float32).min)
     return _combine(scores, v_cache, q.shape[2])
+
+
+LANES = 128
+
+
+def lane_pack(hd: int, n_kv: int) -> int:
+    """How many KV heads share one row of the paged pool. A TPU DMA of a
+    ``(block_size, hd)`` page must span whole 128-lane rows, so heads
+    narrower than 128 are stored side by side: ``LANES // hd`` adjacent
+    heads per row (1 when ``hd`` fills the lanes or the heads do not
+    divide evenly)."""
+    f = LANES // hd if hd < LANES and LANES % hd == 0 else 1
+    return f if n_kv % f == 0 else 1
+
+
+def _lane_packed(fn, q, pool_k, pool_v, k_new, v_new, *args, scale, **kw):
+    """Run the paged attention ``fn`` against a lane-packed pool
+    (``lane_pack``): K/V rows hold ``f`` adjacent heads, and each query
+    is widened to the row with zeros outside its own head's lanes, so
+    q.k and p.v see only that head; the output keeps its own lanes.
+    Returns what ``fn`` returns, the output unpacked."""
+    B, S, Hq, hd = q.shape
+    Hkv = k_new.shape[2]
+    f = pool_k.shape[-1] // hd
+    own = jax.nn.one_hot((jnp.arange(Hq) // (Hq // Hkv)) % f, f,
+                         dtype=q.dtype)                      # (Hq, f)
+    wide = (q[:, :, :, None, :] * own[:, :, None]).reshape(B, S, Hq, f * hd)
+    pack = lambda t: t.reshape(B, S, Hkv // f, f * hd)       # noqa: E731
+    out, pool_k, pool_v = fn(wide, pool_k, pool_v, pack(k_new), pack(v_new),
+                             *args, scale=hd ** -0.5 if scale is None
+                             else scale, **kw)
+    out = jnp.sum(out.reshape(B, S, Hq, f, hd) * own[..., None], axis=3)
+    return out.reshape(B, S, Hq * hd), pool_k, pool_v
 
 
 def _gather_pool(pool, block_table):
@@ -189,7 +230,7 @@ def _gather_pool(pool, block_table):
 
 def paged_verify_attention(q, pool_k, pool_v, k_new, v_new, block_table,
                            cache_len, n_write, *, sliding_window: int = 0,
-                           use_kernel: bool = False):
+                           use_kernel: bool = False, scale=None):
     """Multi-token window against the KV block pool: the speculative
     **verify** step and the **chunked-prefill** step share this path (a
     prompt chunk is a window of known tokens scattered against the
@@ -219,6 +260,11 @@ def paged_verify_attention(q, pool_k, pool_v, k_new, v_new, block_table,
     once and applies the same causal-in-window mask.
     """
     from repro.serve.blocks import SCRATCH_BLOCK
+    if pool_k.shape[-1] != q.shape[-1]:
+        return _lane_packed(paged_verify_attention, q, pool_k, pool_v,
+                            k_new, v_new, block_table, cache_len, n_write,
+                            sliding_window=sliding_window,
+                            use_kernel=use_kernel, scale=scale)
     bs = pool_k.shape[2]
     B, S = q.shape[:2]
     base = jnp.asarray(cache_len, jnp.int32).reshape(-1)    # (B,)
@@ -236,17 +282,17 @@ def paged_verify_attention(q, pool_k, pool_v, k_new, v_new, block_table,
         from repro.kernels.paged_attention.ops import (
             paged_window_attention as _window_kernel)
         out, _ = _window_kernel(q, pool_k, pool_v, block_table, base,
-                                sliding_window=sliding_window)
+                                sliding_window=sliding_window, scale=scale)
         return out.reshape(B, S, -1), pool_k, pool_v
     out = verify_decode_attention(q, _gather_pool(pool_k, block_table),
                                   _gather_pool(pool_v, block_table), base,
-                                  sliding_window=sliding_window)
+                                  sliding_window=sliding_window, scale=scale)
     return out, pool_k, pool_v
 
 
 def paged_decode_attention(q, pool_k, pool_v, k_new, v_new, block_table,
                            cache_len, *, sliding_window: int = 0,
-                           use_kernel: bool = False):
+                           use_kernel: bool = False, scale=None):
     """Decode one token per sequence against a shared KV **block pool**.
 
     q/k_new/v_new: (B, 1, H*, hd); pool_k/pool_v: (num_blocks, Hkv, bs,
@@ -276,6 +322,11 @@ def paged_decode_attention(q, pool_k, pool_v, k_new, v_new, block_table,
       bit-exact (f32) against its streaming jnp oracle by the
       differential grids in ``tests/test_kernels.py``.
     """
+    if pool_k.shape[-1] != q.shape[-1]:
+        return _lane_packed(paged_decode_attention, q, pool_k, pool_v,
+                            k_new, v_new, block_table, cache_len,
+                            sliding_window=sliding_window,
+                            use_kernel=use_kernel, scale=scale)
     bs = pool_k.shape[2]
     idx = jnp.asarray(cache_len, jnp.int32).reshape(-1)     # (B,)
     rows = jnp.arange(idx.shape[0])
@@ -289,11 +340,11 @@ def paged_decode_attention(q, pool_k, pool_v, k_new, v_new, block_table,
         from repro.kernels.paged_attention.ops import (
             paged_decode_attention as _paged_kernel)
         out, _ = _paged_kernel(q[:, 0], pool_k, pool_v, block_table, idx + 1,
-                               sliding_window=sliding_window)
+                               sliding_window=sliding_window, scale=scale)
         return out.reshape(B, 1, -1), pool_k, pool_v
     out = decode_attention(q, _gather_pool(pool_k, block_table),
                            _gather_pool(pool_v, block_table), idx + 1,
-                           sliding_window=sliding_window)
+                           sliding_window=sliding_window, scale=scale)
     return out, pool_k, pool_v
 
 
@@ -313,6 +364,9 @@ def attention_block(x, p, cfg, *, mode: str, cache=None, cache_len=None,
     already-resident cache.
     """
     win = cfg.sliding_window if sliding_window is None else sliding_window
+    # an explicit softmax scale (Granite's attention_multiplier); None
+    # keeps 1/sqrt(hd)
+    scale = cfg.attention_multiplier or None
     if mode == "decode" and x.shape[1] > 1:
         # ---- multi-token window (speculative verify / chunked prefill) ----
         B, S, _ = x.shape
@@ -325,7 +379,7 @@ def attention_block(x, p, cfg, *, mode: str, cache=None, cache_len=None,
                 else jnp.asarray(n_write, jnp.int32)
             o, k_cache, v_cache = paged_verify_attention(
                 q, cache["k"], cache["v"], k, v, block_table, idx, nw,
-                sliding_window=win, use_kernel=paged_kernel)
+                sliding_window=win, use_kernel=paged_kernel, scale=scale)
         else:
             rows = jnp.arange(B)[:, None]
             k_cache = cache["k"].at[rows, pos].set(
@@ -333,7 +387,7 @@ def attention_block(x, p, cfg, *, mode: str, cache=None, cache_len=None,
             v_cache = cache["v"].at[rows, pos].set(
                 v.astype(cache["v"].dtype))
             o = verify_decode_attention(q, k_cache, v_cache, idx,
-                                        sliding_window=win)
+                                        sliding_window=win, scale=scale)
         return o @ p["w_o"], {"k": k_cache, "v": v_cache}
     if mode == "decode":
         # cache_len = number of tokens already cached; the new token goes
@@ -359,7 +413,7 @@ def attention_block(x, p, cfg, *, mode: str, cache=None, cache_len=None,
             # paged KV: cache leaves are the shared block pool
             o, k_cache, v_cache = paged_decode_attention(
                 q, cache["k"], cache["v"], k, v, block_table, cache_len,
-                sliding_window=win, use_kernel=paged_kernel)
+                sliding_window=win, use_kernel=paged_kernel, scale=scale)
         else:
             idx = jnp.asarray(cache_len, jnp.int32)
             if idx.ndim == 0:
@@ -375,7 +429,7 @@ def attention_block(x, p, cfg, *, mode: str, cache=None, cache_len=None,
                 v_cache = cache["v"].at[rows, idx].set(
                     v[:, 0].astype(cache["v"].dtype))
             o = decode_attention(q, k_cache, v_cache, cache_len + 1,
-                                 sliding_window=win)
+                                 sliding_window=win, scale=scale)
         if plan is not None and plan.mesh is not None:
             # pin the joined attention output replicated as well — the
             # row-sharded w_o otherwise drags head-sharding back through
@@ -388,7 +442,8 @@ def attention_block(x, p, cfg, *, mode: str, cache=None, cache_len=None,
     else:
         q, k, v = qkv(x, p, cfg, positions=positions,
                       mrope_positions=mrope_positions)
-        o = causal_attention(q, k, v, sliding_window=win, causal=causal)
+        o = causal_attention(q, k, v, sliding_window=win, causal=causal,
+                             scale=scale)
         new_cache = {"k": k, "v": v} if mode == "prefill" else None
     return o @ p["w_o"], new_cache
 

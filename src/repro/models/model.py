@@ -21,7 +21,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.models import attention, layers, transformer
+from repro.models import attention, layers, mamba2, transformer
 
 GRID = 16  # stub vision patch grid side (n_patches = GRID*GRID when 256)
 
@@ -55,7 +55,10 @@ def init_params(rng, cfg):
 
 # ================================================================ embedding
 def _embed_tokens(p, cfg, tokens):
-    return p["embed"][tokens]
+    x = p["embed"][tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
 
 
 def _mrope_positions(B, n_patches, s_text):
@@ -127,12 +130,18 @@ def _logits(p, cfg, x):
     if vp != cfg.vocab_size:          # mask padded ids, keep sharding
         pad_mask = jnp.arange(vp) >= cfg.vocab_size
         out = jnp.where(pad_mask, jnp.asarray(-1e9, out.dtype), out)
+    if cfg.logits_scaling != 1.0:
+        out = out / jnp.asarray(cfg.logits_scaling, out.dtype)
     return out
 
 
 # ============================================================ stack wrapper
 def _run_stack(p, cfg, x, *, mode, cache, extras, plan):
     kind = transformer.block_kind(cfg)
+    if kind == "pattern":
+        return transformer.apply_pattern_stack(
+            x, p["blocks"], cfg, mode=mode, cache=cache, extras=extras,
+            plan=plan)
     if kind == "decoder_x":
         # cross K/V is a per-layer scanned input
         enc_kv_stack = (extras or {}).pop("enc_kv_stack", None)
@@ -210,8 +219,10 @@ class Model:
         bit-for-bit. ``block_table``/``paged_kernel``/``n_write`` follow
         :meth:`verify_step` (paged rows divert writes past their granted
         count to the scratch block). Returns (logits (B, S, V), cache).
-        Recurrent families (rwkv / hybrid SSM) cannot chunk — their
-        state steps token-at-a-time — and raise, like verify. With
+        A ``cfg.layer_types`` stack's Mamba-2 layers run the window from
+        the rows' carried state, each row over its first ``n_write``
+        tokens. Other recurrent families (rwkv / hymba's SSM) cannot
+        chunk — their state steps token-at-a-time — and raise. With
         ``last_idx`` set in chunked mode, only each row's last-real-
         position logits are computed (returned as (B, 1, V)) — a chunk
         caller samples at most one token per row, so projecting the
@@ -227,6 +238,9 @@ class Model:
         cfg = self.cfg
         x, extras, prefix = _build_inputs(params, cfg, batch,
                                           drop_last_token=False)
+        if last_idx is not None:
+            # right-padded rows: recurrent state ends at each row's length
+            extras["n_valid"] = jnp.asarray(last_idx, jnp.int32) + 1
         if plan is not None:
             x = plan.constrain_act(x)
         x, cache, _ = _run_stack(params, cfg, x, mode="prefill", cache=None,
@@ -242,7 +256,8 @@ class Model:
 
     # ---------------- decode ----------------
     def decode_step(self, params, token, cache, cache_len, plan=None,
-                    block_table=None, paged_kernel: bool = False):
+                    block_table=None, paged_kernel: bool = False,
+                    advance=None):
         """token (B,1) int32; cache_len = existing token count — a scalar
         (all rows at one length) or a (B,) vector (per-slot lengths for
         mixed-length continuous batching); the new token is written at
@@ -254,11 +269,15 @@ class Model:
         (block_table[b, j // block_size], :, j % block_size). Requires a
         (B,) cache_len vector. ``paged_kernel`` switches the paged read
         from the transient jnp gather to the in-place Pallas kernel
-        (``kernels.paged_attention``; interpret mode off-TPU)."""
+        (``kernels.paged_attention``; interpret mode off-TPU).
+        ``advance`` (B,) bool: rows whose recurrent state this token moves
+        (None: every row); a row that is not advanced keeps its state."""
         cfg = self.cfg
         B = token.shape[0]
         x = _embed_tokens(params, cfg, token)
         extras = {"cache_len": cache_len}
+        if advance is not None:
+            extras["advance"] = advance
         if block_table is not None:
             extras["block_table"] = jnp.asarray(block_table, jnp.int32)
             extras["paged_kernel"] = bool(paged_kernel)
@@ -352,6 +371,11 @@ class Model:
         L, B = cfg.n_layers, batch_size
         H, Hkv, hd, d = cfg.n_heads, cfg.n_kv_heads, cfg.hd, cfg.d_model
         kind = transformer.block_kind(cfg)
+        if kind == "pattern":
+            n_a = cfg.layer_types.count("attention")
+            kv = (n_a, B, capacity, Hkv, hd)
+            return {"k": jnp.zeros(kv, cfg.dtype),
+                    "v": jnp.zeros(kv, cfg.dtype), **self._state(B)}
         if kind == "rwkv":
             return {
                 "state": jnp.zeros((L, B, H, hd, hd), jnp.float32),
@@ -370,24 +394,50 @@ class Model:
             cache["xv"] = jnp.zeros((L, B, cfg.n_frames, Hkv, hd), cfg.dtype)
         return cache
 
-    def init_paged_cache(self, num_blocks: int, block_size: int):
+    def _state(self, rows: int) -> dict:
+        """Zeroed recurrent state of every Mamba-2 layer for ``rows``
+        rows: leaves ``(n_mamba, rows, ...)``; empty without one."""
+        n_m = self.cfg.layer_types.count("mamba")
+        if not n_m:
+            return {}
+        one = mamba2.init_state(self.cfg, rows)
+        return {k: jnp.zeros((n_m,) + v.shape, v.dtype)
+                for k, v in one.items()}
+
+    @property
+    def can_page(self) -> bool:
+        """Whether :meth:`init_paged_cache` can hold this model's cache:
+        pure attention, or a layer pattern whose attention layers page
+        and whose Mamba-2 layers keep per-slot state rows."""
+        return transformer.block_kind(self.cfg) in ("dense", "moe",
+                                                    "pattern")
+
+    def init_paged_cache(self, num_blocks: int, block_size: int,
+                         slots: int = 1):
         """Zeroed block-pool KV: ``(L, num_blocks, Hkv, block_size, hd)``
         per leaf, shared by every slot through a per-slot block table
         (see ``repro.serve.blocks``). Head-major, so one KV head of one
         block is a contiguous ``(block_size, hd)`` tile: the paged
         kernel's K/V block then meets the TPU's (8, 128) tiling rule
-        (``kernels/paged_attention/kernel.py``). Only pure-attention
-        families page; recurrent state is O(1) in sequence length and
-        keeps the per-slot fixed cache."""
+        (``kernels/paged_attention/kernel.py``); heads narrower than the
+        128 lanes share a row (``attention.lane_pack``). A layer-pattern
+        model pages its attention layers' K/V (``L`` = its attention
+        layers) and adds its Mamba-2 layers' recurrent state, O(1) in
+        sequence length, as one row per slot (``slots`` rows). Other
+        recurrent and cross-attention families do not page
+        (:attr:`can_page`)."""
         cfg = self.cfg
         kind = transformer.block_kind(cfg)
-        if kind not in ("dense", "moe"):
-            raise ValueError(f"paged KV unsupported for family {kind!r} "
-                             "(recurrent/cross-attn leaves are not paged)")
-        L, Hkv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
-        shape = (L, num_blocks, Hkv, block_size, hd)
+        if not self.can_page:
+            raise ValueError(f"paged KV unsupported for family {kind!r}: "
+                             "only pure-attention {k, v} caches and layer-"
+                             "pattern state rows page")
+        L = cfg.layer_types.count("attention") if kind == "pattern" \
+            else cfg.n_layers
+        f = attention.lane_pack(cfg.hd, cfg.n_kv_heads)
+        shape = (L, num_blocks, cfg.n_kv_heads // f, block_size, cfg.hd * f)
         return {"k": jnp.zeros(shape, cfg.dtype),
-                "v": jnp.zeros(shape, cfg.dtype)}
+                "v": jnp.zeros(shape, cfg.dtype), **self._state(slots)}
 
     # ---------------- shape stand-ins ----------------
     def input_specs(self, shape) -> dict:

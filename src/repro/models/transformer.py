@@ -9,15 +9,23 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models import attention, layers, moe, rwkv6, ssm
+from repro.models import attention, layers, mamba2, moe, rwkv6, ssm
 
 
 # ----------------------------------------------------------------- init
 def init_block(rng, cfg, *, kind: str):
-    """kind: dense | moe | hybrid | rwkv | encoder | decoder_x (cross-attn)."""
+    """kind: dense | moe | hybrid | rwkv | encoder | decoder_x (cross-attn)
+    | mamba | attention (the layer kinds of a ``cfg.layer_types``
+    pattern)."""
     d = cfg.d_model
     dtype = cfg.dtype
     ks = jax.random.split(rng, 8)
+    if kind in LAYER_KINDS:
+        mixer = ("mamba", mamba2.init_mamba2(ks[0], cfg)) if kind == "mamba" \
+            else ("attn", attention.init_attention(ks[0], cfg))
+        return {"ln1": jnp.ones((d,), dtype), mixer[0]: mixer[1],
+                "ln2": jnp.ones((d,), dtype),
+                "ffn": layers.init_mlp(ks[1], d, cfg.d_ff, cfg.act, dtype)}
     if kind == "rwkv":
         return {
             "ln1": jnp.ones((d,), dtype),
@@ -44,7 +52,15 @@ def init_block(rng, cfg, *, kind: str):
     return p
 
 
+# kinds a ``cfg.layer_types`` pattern may name, and the cache leaves
+# each keeps: paged K/V, or per-row recurrent state
+LAYER_KINDS = ("mamba", "attention")
+CACHE_KEYS = {"mamba": ("ssm", "conv"), "attention": ("k", "v")}
+
+
 def block_kind(cfg) -> str:
+    if cfg.layer_types:
+        return "pattern"
     if cfg.family == "ssm":
         return "rwkv"
     if cfg.family == "moe":
@@ -126,10 +142,128 @@ def apply_block(x, p, cfg, *, kind, mode, cache=None, extras=None, plan=None):
     return x, new_cache, aux
 
 
+def apply_layer(x, p, cfg, *, kind, mode, cache=None, extras=None,
+                plan=None):
+    """One layer of a ``cfg.layer_types`` pattern: RMSNorm, the mixer
+    (Mamba-2 or attention), RMSNorm, the MLP, each branch scaled by
+    ``cfg.residual_multiplier`` into the residual. Returns (x, new_cache);
+    the cache is the layer's ``CACHE_KEYS[kind]`` leaves."""
+    extras = extras or {}
+    r = cfg.residual_multiplier
+    h = layers.rmsnorm(x, p["ln1"], cfg.norm_eps)
+    if kind == "mamba":
+        if mode == "decode" and h.shape[1] == 1:
+            out, new_cache = mamba2.mamba2_step(h, p["mamba"], cfg, cache,
+                                                extras.get("advance"))
+        else:
+            # a window: prefill / admission from zeros, or a chunk window
+            # from the rows' carried state; n_valid right-pads each row
+            out, new_cache = mamba2.mamba2_window(
+                h, p["mamba"], cfg, state=cache,
+                n_valid=extras.get("n_valid", extras.get("n_write")))
+    else:
+        out, new_cache = attention.attention_block(
+            h, p["attn"], cfg, mode=mode, cache=cache,
+            cache_len=extras.get("cache_len"), plan=plan,
+            block_table=extras.get("block_table"),
+            paged_kernel=extras.get("paged_kernel", False),
+            n_write=extras.get("n_write"))
+    x = x + r * out
+    h = layers.rmsnorm(x, p["ln2"], cfg.norm_eps)
+    return x + r * layers.mlp(h, p["ffn"], cfg.act), new_cache
+
+
 # ----------------------------------------------------------------- stack
 def init_stack(rng, cfg, n_layers: int, kind: str):
+    if kind == "pattern":
+        ks = jax.random.split(rng, len(LAYER_KINDS))
+        return {k: layers.stacked(r, cfg.layer_types.count(k),
+                                  lambda kk, k=k: init_block(kk, cfg, kind=k))
+                for k, r in zip(LAYER_KINDS, ks) if k in cfg.layer_types}
     return layers.stacked(rng, n_layers,
                           lambda k: init_block(k, cfg, kind=kind))
+
+
+def _runs(period: tuple) -> list:
+    """``(kind, first index of the kind in the period, length)`` of each
+    run of consecutive same-kind layers of the period."""
+    runs, seen = [], {}
+    for kind in period:
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, seen.get(kind, 0), 1])
+        seen[kind] = seen.get(kind, 0) + 1
+    return [tuple(r) for r in runs]
+
+
+def apply_pattern_stack(x, blocks, cfg, *, mode, cache=None, extras=None,
+                        plan=None):
+    """A stack of mixed layer kinds (``cfg.layer_types``): a scan over
+    the periods of the pattern, each period's layers in order inside it,
+    every run of same-kind layers an inner scan (so the program holds
+    one body per run, not per layer). ``blocks`` holds each kind's
+    layers stacked in layer order (``{"mamba": (n_m, ...), "attention":
+    (n_a, ...)}``), the cache each kind's ``CACHE_KEYS`` leaves the same
+    way. The cache rides the scans as carry and each layer updates its
+    own slice in place; a prefill (no cache given) fills zeroed leaves
+    with each layer's K/V and final state. Returns (x, cache, 0)."""
+    period = cfg.period
+    count = {k: period.count(k) for k in set(period)}
+    runs = _runs(period)
+    fresh = cache is None
+    if mode != "train" and fresh:
+        shapes = jax.eval_shape(lambda h: _layer_outputs(h, blocks, cfg, mode,
+                                                         extras, plan), x)
+        cache = {n: jnp.zeros(a.shape, a.dtype) for n, a in shapes.items()}
+
+    def layer(kind, l, h, c):
+        p = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a, l, keepdims=False), blocks[kind])
+        ci = None if fresh else {n: jax.lax.dynamic_index_in_dim(
+            c[n], l, keepdims=False) for n in CACHE_KEYS[kind]}
+        h, nc = apply_layer(h, p, cfg, kind=kind, mode=mode, cache=ci,
+                            extras=extras, plan=plan)
+        if c is not None:
+            c = dict(c, **{n: jax.lax.dynamic_update_index_in_dim(
+                c[n], nc[n].astype(c[n].dtype), l, 0) for n in nc})
+        return h, c
+
+    def body(carry, per):
+        h, c = carry
+        if plan is not None and mode == "train":
+            h = plan.constrain_residual(h)
+        for kind, first, n in runs:
+            base = per * count[kind] + first
+            if n == 1:
+                h, c = layer(kind, base, h, c)
+            else:
+                (h, c), _ = jax.lax.scan(
+                    lambda hc, j, kind=kind, base=base:
+                    (layer(kind, base + j, *hc), None),
+                    (h, c), jnp.arange(n))
+        return (h, c), None
+
+    fn = jax.checkpoint(body) if (cfg.remat and mode == "train") else body
+    n_per = cfg.n_layers // len(period)
+    (x, cache), _ = jax.lax.scan(fn, (x, None if mode == "train" else cache),
+                                 jnp.arange(n_per))
+    return x, cache, jnp.zeros((), jnp.float32)
+
+
+def _layer_outputs(h, blocks, cfg, mode, extras, plan) -> dict:
+    """The cache leaves a fresh prefill of ``h`` leaves: one layer of
+    each kind run for its output shapes, stacked over the kind's layers
+    (for ``jax.eval_shape`` only)."""
+    out = {}
+    for kind in sorted(set(cfg.layer_types)):
+        p = jax.tree.map(lambda a: a[0], blocks[kind])
+        _, nc = apply_layer(h, p, cfg, kind=kind, mode=mode, extras=extras,
+                            plan=plan)
+        n = cfg.layer_types.count(kind)
+        out.update({k: jnp.zeros((n,) + v.shape, v.dtype)
+                    for k, v in nc.items()})
+    return out
 
 
 def apply_stack(x, blocks, cfg, *, kind, mode, cache=None, extras=None,

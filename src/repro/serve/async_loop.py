@@ -14,6 +14,14 @@ replica loop around JAX async dispatch so both costs disappear:
   ``tick.commit()`` only when the result is actually needed. Host
   planning time hides behind the device step instead of adding to it.
 
+- **Chained decode steps.** Where the engine knows the next step from
+  its host state already (every slot decoding, none ending on this
+  step by its length), ``engine.dispatch_next(tick)`` launches it
+  before this step commits, fed by this step's sampled tokens on the
+  device. The device then runs decode steps back to back while the
+  host commits, streams and plans the one before; slots hold still
+  (no cancel, no admission) until the launched step commits.
+
 - **Per-token streaming.** Every request may carry an ``on_token``
   callback; after each commit the loop emits the tokens that appeared
   since the last tick, in order. Token values are **bit-identical** to
@@ -118,6 +126,7 @@ class AsyncServeLoop:
             "cancel_s": 0.0,            # applying cancels
             "fill_s": 0.0,              # intake, admission, sheds
             "dispatch_s": 0.0,          # planning + launching the step
+            "dispatch_next_s": 0.0,     # launching the step after it
             "plan_time_s": 0.0,         # host time inside the overlap window
             "commit_wait_s": 0.0,       # device wait + commit bookkeeping
             "account_s": 0.0,           # scheduler stats of the finished
@@ -125,6 +134,7 @@ class AsyncServeLoop:
             "outside_s": 0.0,           # the caller's time between calls
         }
         self._left = None               # clock when run_once last returned
+        self._ahead = None              # a launched step not yet committed
 
     # ------------------------------------------------------------ intake
     def submit(self, req: Request, on_token=None) -> StreamHandle:
@@ -233,9 +243,11 @@ class AsyncServeLoop:
             handle._finish()
 
     def run_once(self) -> bool:
-        """One pipelined tick: admit/cancel → fill → dispatch →
-        (plan-ahead window) → commit → account → emit → resolve.
-        Returns False when there was nothing to do.
+        """One pipelined tick: admit/cancel → fill → dispatch → launch
+        the next step where the engine can chain it → (plan-ahead
+        window) → commit → account → emit → resolve. A tick that picks
+        up a chained step skips cancels and admission: its step is
+        already in flight. Returns False when there was nothing to do.
 
         Every phase runs under the telemetry phase timer: it adds to its
         counter in ``self.metrics``, lands on the engine tracer's
@@ -249,21 +261,35 @@ class AsyncServeLoop:
         eng, m, clock, tr = self.engine, self.metrics, self.clock, \
             self.engine.tracer
         with self._lock:
+            # a step the last call launched before its own step committed:
+            # slots hold still until it commits (no cancel, no admission)
+            ahead, self._ahead = self._ahead, None
             with phase("apply-cancels", clock, m, "cancel_s", tr) as p:
-                self._apply_cancels()
+                if ahead is None:
+                    self._apply_cancels()
             if self._left is not None:
                 m["outside_s"] += p.start - self._left
             with phase("fill", clock, m, "fill_s", tr) as p:
                 self._admit()
-                self.scheduler.fill()
-                self._collect_shed()
+                if ahead is None:
+                    self.scheduler.fill()
+                    self._collect_shed()
             self._left = p.end
-            if not (eng.active or eng.waiting or eng._finished_at_admit):
+            if ahead is None and not (eng.active or eng.waiting
+                                      or eng._finished_at_admit):
                 eng.end_launch_chain()
                 return False
             with phase("dispatch", clock, m, "dispatch_s", tr) as p:
-                tick = eng.dispatch_step()
+                tick = eng.dispatch_step() if ahead is None else ahead
                 p.args = {"active": eng.active}
+            # the step after this one, launched before this one commits
+            # where the engine knows it already; a waiting cancel keeps
+            # the next call at the loop boundary
+            with phase("dispatch-next", clock, m, "dispatch_next_s",
+                       tr) as p:
+                if not self._cancels:
+                    self._ahead = eng.dispatch_next(tick)
+                p.args = {"launched": self._ahead is not None}
             # ---- overlap window: the device step is in flight --------
             with phase("plan-window", clock, m, "plan_time_s", tr) as p:
                 self._admit()           # late arrivals reach this plan
@@ -308,6 +334,9 @@ class AsyncServeLoop:
         scheduler + engine state is torn down so a restart starts clean.
         Returns the number of handles failed."""
         with self._lock:
+            if self._ahead is not None:
+                ahead, self._ahead = self._ahead, None
+                ahead.commit()
             err = error if error is not None else ServiceError(
                 f"{self.name}: replica aborted mid-stream")
             handles = list(self._live.values()) + list(self._intake) \

@@ -16,15 +16,21 @@ sequence). KV memory comes in two layouts:
   until another request frees blocks — and if every active slot is
   parked, the newest admission is **preempted** (blocks freed, request
   re-queued for recompute re-admission) so the oldest can advance.
-* **Fixed-stripe (recurrent rwkv / hybrid-SSM / cross-attn caches)** —
+  A layer-pattern model (Mamba-2 layers beside attention layers,
+  ``cfg.layer_types``) pages its attention layers' ``{k, v}`` the same
+  way and keeps every other cache leaf as **per-slot state rows**
+  (``(layers, B, ...)``, indexed by slot): admission writes each row's
+  state at its first chunk's end, chunk windows carry it on, decode
+  advances it (parked rows keep theirs).
+* **Fixed-stripe (recurrent rwkv / hymba-SSM / cross-attn caches)** —
   one ``max_seq`` stripe per slot at ``model.init_cache(B, max_seq)``.
-  Recurrent state is O(1) in sequence length, so paging buys nothing
-  there; the stripe path is also the reference the paged path must
-  match token-for-token.
+  The stripe path is also the reference the paged path must match
+  token-for-token.
 
 Paged engines add two behaviours on top of the block tables:
 
-* **Prefix sharing + copy-on-write** (``prefix_sharing=True``, non-MoE):
+* **Prefix sharing + copy-on-write** (``prefix_sharing=True``, non-MoE,
+  no state rows: a shared prefix would need the state at its end):
   admission walks the prompt through the pool's prefix index and
   *acquires* blocks already holding that content instead of recomputing
   and re-storing them — the request prefills only its un-shared suffix
@@ -92,9 +98,10 @@ prompt blocks register in the prefix index exactly as prefilled ones
 do, so half-prefilled prompts share forward too. Chunked streams are
 bit-identical to monolithic prefill (``tests/test_chunked.py`` holds
 the whole engine grid to it). ``prefill_chunk=0`` restores monolithic
-admission; recurrent and MoE families always prefill monolithically
-(multi-token windows need the ``{k, v}`` scatter and bit-exact
-co-batching).
+admission; stripe-recurrent and MoE families always prefill
+monolithically (their state steps token-at-a-time, or co-batching is not
+bit-exact). Models with state rows chunk: a window starts from the rows'
+carried state, and pad positions leave it as it was.
 """
 from __future__ import annotations
 
@@ -109,7 +116,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serve import sampling
-from repro.serve.blocks import BlockPool
+from repro.serve.blocks import SCRATCH_BLOCK, BlockPool
 from repro.serve.sampling import GREEDY, SamplingParams
 from repro.serve.spec import DraftRunner
 from repro.serve.telemetry import (NOOP, PID_ENGINE, PID_LOOP, PID_POOL,
@@ -121,6 +128,11 @@ _MIN_BUCKET = 8
 # than one chunk's compute, large enough that short prompts (the common
 # case) still admit in one piece exactly as before
 DEFAULT_PREFILL_CHUNK = 64
+# most bytes of fresh recurrent state one admission program may return:
+# each admitted row's state is an output of it, held beside the rows'
+# resident state until it is written in, so a model with state rows
+# admits at most this many bytes' worth of rows per call
+ADMIT_STATE_BYTES = 1 << 30
 
 
 @dataclass
@@ -169,12 +181,16 @@ class _Tick:
     ``dispatch_step()`` and ``commit()`` the engine's host state must be
     treated as read-only — that window is exactly where the async serve
     loop overlaps next-tick planning (admission cost walks, intake
-    validation) with the device step. Commit is one-shot."""
+    validation) with the device step. Commit is one-shot. A plain
+    decode tick of a paged engine also carries ``chain`` (its rows,
+    their requests, its sampled tokens on the device), from which
+    :meth:`ServingEngine.dispatch_next` launches the step after it."""
 
-    __slots__ = ("_commit",)
+    __slots__ = ("_commit", "chain")
 
-    def __init__(self, commit_fn):
+    def __init__(self, commit_fn, chain=None):
         self._commit = commit_fn
+        self.chain = chain
 
     def commit(self) -> list:
         """Synchronize on the device results, run the per-slot
@@ -186,19 +202,21 @@ class _Tick:
 
 
 # ------------------------------------------------------ paged programs
+KV_KEYS = ("k", "v")
+
+
 def paged_admit_step(model, plan, block_size, p, tokens, last_idx, temps,
                      top_ks, seeds, ctrs):
     """Batched prefill for the pool path: returns the first token per
     row (+ logprob) and the prefill KV padded (with zeros, never
     attended) to a block_size multiple so every logical block slices
-    full."""
+    full; state leaves hold each row's state at its ``last_idx``."""
     logits, pref = model.prefill(p, {"tokens": tokens}, plan,
                                  last_idx=last_idx)
     pad = (-tokens.shape[1]) % block_size
     if pad:
-        pref = {key: jnp.pad(pref[key],
-                             ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-                for key in pref}
+        pref = {key: jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+                if key in KV_KEYS else v for key, v in pref.items()}
     nxt, logp = sampling.sample(logits[:, -1, :], temps, top_ks, seeds,
                                 ctrs)
     return nxt, logp, pref
@@ -206,9 +224,20 @@ def paged_admit_step(model, plan, block_size, p, tokens, last_idx, temps,
 
 def paged_decode_step(model, plan, kernel, p, tok, caches, lengths, table,
                       temps, top_ks, seeds, ctrs):
+    """One token per slot (``tok`` (B,), the shape of the sampled
+    tokens, so a step's output can feed the next step as it is). A row
+    whose write site maps to the scratch block (a parked row, an empty
+    slot) writes its K/V there and, with state rows in the cache, keeps
+    its state: the token is fed again when the row resumes."""
+    tok = tok[:, None]
+    advance = None
+    if set(caches) - set(KV_KEYS):
+        bs = caches["k"].shape[3]
+        rows = jnp.arange(table.shape[0])
+        advance = table[rows, lengths // bs] != SCRATCH_BLOCK
     logits, caches = model.decode_step(p, tok, caches, lengths, plan,
                                        block_table=table,
-                                       paged_kernel=kernel)
+                                       paged_kernel=kernel, advance=advance)
     nxt, logp = sampling.sample(logits[:, -1, :], temps, top_ks, seeds,
                                 ctrs)
     return nxt, logp, caches
@@ -250,20 +279,24 @@ def paged_programs(model, *, block_size: int, use_kernel: bool,
 
 
 def paged_program_args(params, caches, *, batch: int, blocks_per_slot: int,
-                       width: int, sharding=None) -> dict:
+                       width: int, sharding=None,
+                       admit_rows: int | None = None) -> dict:
     """Arguments for :func:`paged_programs` at one serving shape:
     ``batch`` slots, ``width``-token chunk windows, and an admission of
-    ``batch`` first chunks ``width`` wide. ``params`` and ``caches`` pass
-    through (arrays or shape stand-ins); the rest are
-    ``ShapeDtypeStruct`` stand-ins on ``sharding``."""
+    ``admit_rows`` (default ``batch``) first chunks ``width`` wide.
+    ``params`` and ``caches`` pass through (arrays or shape stand-ins);
+    the rest are ``ShapeDtypeStruct`` stand-ins on ``sharding``."""
     def sds(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
     rows = sds((batch,))
     samp = (sds((batch,), jnp.float32), rows, rows, rows)
     table = sds((batch, blocks_per_slot))
+    n = admit_rows or batch
+    adm = sds((n,))
     return {
-        "admit": (params, sds((batch, width)), rows, *samp),
-        "decode": (params, sds((batch, 1)), caches, rows, table, *samp),
+        "admit": (params, sds((n, width)), adm, sds((n,), jnp.float32), adm,
+                  adm, adm),
+        "decode": (params, rows, caches, rows, table, *samp),
         "chunk": (params, sds((batch, width)), caches, rows, table, rows,
                   rows, *samp),
     }
@@ -294,32 +327,35 @@ class ServingEngine:
         # on the same timeline as the latency stamps.
         self.tracer = NOOP if tracer is None else tracer
         cache_spec = jax.eval_shape(lambda: model.init_cache(1, _MIN_BUCKET))
-        pure_attn = set(cache_spec) <= {"k", "v"}
+        pure_attn = set(cache_spec) <= set(KV_KEYS)
         # MoE routing flattens the whole (rows x tokens) block into one
         # shared per-expert capacity, so pad tokens / co-batched rows can
         # displace real tokens from dispatch — prefill those one row at a
         # time, exact length, to keep admission bit-exact with solo serving.
         is_moe = bool(getattr(model.cfg, "n_experts", 0))
+        # the model says what it can page: pure attention, or a layer
+        # pattern whose non-{k, v} leaves are per-slot state rows. Other
+        # recurrent / cross-attn caches keep the stripe layout.
+        self.paged = model.can_page if paged is None else paged
+        # per-slot state rows beside the paged K/V (layer-pattern models)
+        self.state_keys = tuple(sorted(set(cache_spec) - set(KV_KEYS))) \
+            if self.paged else ()
         # pure-attention caches tolerate right-padded prompts (pad KV is
-        # masked, then overwritten); recurrent state does not.
-        self._paddable = pure_attn and not is_moe
+        # masked, then overwritten), and so do state rows (pad positions
+        # do not move the state); stripe recurrent state does not.
+        self._paddable = (pure_attn or bool(self.state_keys)) and not is_moe
         self._solo_prefill = is_moe
-        # recurrent / cross-attn state is O(1) in sequence length: paging
-        # buys nothing, keep the stripe layout there.
-        self.paged = pure_attn if paged is None else paged
-        if self.paged and not pure_attn:
-            raise ValueError("paged KV requires a pure-attention {k, v} "
-                             f"cache; got leaves {sorted(cache_spec)}")
         # prefix sharing rides on the block tables; the catch-up tokens of
         # a shared admission decode co-batched, which is bit-exact for
         # dense/GQA but not for MoE (the shared expert-capacity caveat
-        # again) — so MoE engines never share.
+        # again) — so MoE engines never share. A shared prefix carries no
+        # recurrent state, so models with state rows never share either.
         self.prefix_sharing = bool(prefix_sharing) and self.paged \
-            and not is_moe
+            and not is_moe and not self.state_keys
         self.use_kernel = bool(use_kernel)
         # chunked prefill: prompts longer than the chunk admit with their
         # first chunk and feed the rest through decode-interleaved chunk
-        # windows. Needs the multi-token {k, v} window (recurrent state
+        # windows. Needs the multi-token window (stripe recurrent state
         # steps token-at-a-time) and bit-exact co-batching (the MoE
         # shared-capacity caveat), so only paddable families chunk;
         # 0 = monolithic admission (the legacy comparison mode).
@@ -335,15 +371,16 @@ class ServingEngine:
         else:
             if prefill_chunk:
                 raise ValueError("chunked prefill requires a paddable "
-                                 "pure-attention non-MoE cache")
+                                 "non-MoE cache")
             self.prefill_chunk = 0
         # per-step cap on pending prompt tokens fed across slots (the
         # scheduler charges the same budget before admitting new work)
         self.prefill_budget = prefill_budget
         # speculative draft-and-verify: a small draft model proposes k
         # tokens per slot, the target verifies them in one multi-token
-        # step. Pure-attention targets only (the verify window needs the
-        # {k, v} scatter; recurrent state steps token-at-a-time) and
+        # step. Pure-attention targets only (rolling back a rejected
+        # proposal needs the {k, v} truncation; recurrent state has no
+        # snapshot to roll back to) and
         # never MoE (the window co-batches k+1 tokens through shared
         # expert capacity — the standard bit-exactness caveat).
         self.spec_k = int(speculation)
@@ -390,7 +427,8 @@ class ServingEngine:
                                   tracer=self.tracer)
             self.reserve_blocks = min(reserve_blocks, max(self.pool.total - 1,
                                                           0))
-            self.caches = model.init_paged_cache(num_blocks, block_size)
+            self.caches = model.init_paged_cache(num_blocks, block_size,
+                                                 slots=batch_size)
             self.block_table = np.zeros((batch_size, self.blocks_per_slot),
                                         np.int32)
             self.slot_blocks: list = [[] for _ in range(batch_size)]
@@ -426,17 +464,29 @@ class ServingEngine:
             block ``phys`` — a device-side dynamic_update_slice on the
             donated pool, same no-host-copy property as the stripe path.
             The prefill KV is token-major (L, rows, S, Hkv, hd); the
-            pool is head-major (L, num_blocks, Hkv, bs, hd)."""
-            for key in caches:
+            pool is head-major (L, num_blocks, Hkv / f, bs, hd * f),
+            ``f`` heads to a row (``attention.lane_pack``)."""
+            for key in KV_KEYS:
                 L = pref[key].shape[0]
                 chunk = jax.lax.dynamic_slice(
                     pref[key], (jnp.int32(0), row, start, jnp.int32(0),
                                 jnp.int32(0)),
                     (L, 1, block_size) + pref[key].shape[3:])
-                chunk = jnp.swapaxes(chunk, 2, 3)
+                # lane-packed pools hold adjacent heads side by side
+                chunk = jnp.swapaxes(chunk.reshape(
+                    (L, 1, block_size) + caches[key].shape[2::2]), 2, 3)
                 caches[key] = jax.lax.dynamic_update_slice(
                     caches[key], chunk.astype(caches[key].dtype),
                     (jnp.int32(0), phys) + (jnp.int32(0),) * 3)
+            return caches
+
+        def write_state(caches, pref, slots):
+            """Admission's state write: row j of the prefill's state
+            leaves becomes slot ``slots[j]``'s state, replacing whatever
+            a reused slot held (state donated)."""
+            for key in self.state_keys:
+                caches[key] = caches[key].at[:, slots].set(
+                    pref[key].astype(caches[key].dtype), mode="drop")
             return caches
 
         def decode(p, tok, caches, lengths, temps, top_ks, seeds, ctrs):
@@ -490,7 +540,7 @@ class ServingEngine:
             """Copy-on-write: duplicate physical block ``src`` into the
             freshly-allocated ``dst`` on device (all layers, one jitted
             dynamic_update_slice per leaf, pool donated)."""
-            for key in caches:
+            for key in KV_KEYS:
                 nd = caches[key].ndim
                 sizes = (caches[key].shape[0], 1) + caches[key].shape[2:]
                 blk = jax.lax.dynamic_slice(
@@ -503,6 +553,7 @@ class ServingEngine:
 
         self._admit = jax.jit(admit, donate_argnums=(1,))
         self._write_block = jax.jit(write_block, donate_argnums=(0,))
+        self._write_state = jax.jit(write_state, donate_argnums=(0,))
         self._copy_block = jax.jit(copy_block, donate_argnums=(0,))
         self._verify = jax.jit(verify_paged if self.paged else verify,
                                donate_argnums=(2,))
@@ -515,6 +566,14 @@ class ServingEngine:
         else:
             self._decode = jax.jit(decode, donate_argnums=(2,))
             self._chunk_fn = jax.jit(chunk, donate_argnums=(2,))
+        state_bytes = sum(self.caches[k].size * self.caches[k].dtype.itemsize
+                          for k in self.state_keys)
+        # rows per admission program (see ADMIT_STATE_BYTES), a power of
+        # two; every row for a model without state rows
+        self.admit_rows = batch_size
+        if state_bytes:
+            fit = max(ADMIT_STATE_BYTES * batch_size // state_bytes, 1)
+            self.admit_rows = min(batch_size, 1 << (fit.bit_length() - 1))
         self.metrics = {"prefills": 0, "prefill_batches": 0,
                         "decode_steps": 0, "completed": 0,
                         "stop_token_exits": 0, "slot_reuses": 0,
@@ -551,8 +610,17 @@ class ServingEngine:
                         # step's launch returning, over consecutive steps
                         # only: the time no serving step is queued
                         "launch_gap_s": 0.0, "launch_gaps": 0,
+                        # decode steps launched before the step before
+                        # them committed (dispatch_next): no host round
+                        # trip between the two on the device
+                        "chained_steps": 0,
                         # admission to first generated token
-                        "first_tokens": 0, "admit_to_first_s": 0.0}
+                        "first_tokens": 0, "admit_to_first_s": 0.0,
+                        # per-slot recurrent state (layer-pattern models):
+                        # rows a step program advanced (decode and chunk),
+                        # slots an admission wrote afresh, bytes held
+                        "state_rows_stepped": 0, "state_resets": 0,
+                        "state_bytes": state_bytes}
         # when the last step's result reached the host; None once the
         # chain of consecutive steps is broken
         self._result_at = None
@@ -569,20 +637,21 @@ class ServingEngine:
         width = _bucket(self.prefill_chunk or self.max_seq, self.max_seq)
         args = paged_program_args(self.params, self.caches, batch=self.B,
                                   blocks_per_slot=self.blocks_per_slot,
-                                  width=width)
+                                  width=width, admit_rows=self.admit_rows)
         progs = {"admit": self._prefill_paged, "decode": self._decode,
                  "chunk": self._chunk_fn}
         return {name: fn.lower(*args[name]).compile()
                 for name, fn in progs.items()}
 
     # ---------------------------------------------------------- telemetry
-    def _count_kernel_pages(self, S: int) -> None:
+    def _count_kernel_pages(self, S: int, lengths=None) -> None:
         """Count the pages one paged step program's kernel reads, per
         layer: row b's ``S``-token window starts at ``slot_len[b]`` and
         reads its table's pages below ``slot_len[b] + S`` (an idle row,
         at length 0, reads one). From the lengths this step is planned
         with; no device sync."""
-        held = -(-(self.slot_len.astype(np.int64) + S) // self.block_size)
+        lengths = self.slot_len if lengths is None else lengths
+        held = -(-(lengths.astype(np.int64) + S) // self.block_size)
         self.metrics["kernel_pages_held"] += int(
             np.minimum(held, self.blocks_per_slot).sum())
         self.metrics["kernel_pages_table"] += int(self.block_table.size)
@@ -606,10 +675,11 @@ class ServingEngine:
             self._result_at = None
 
     def _result(self, *arrays) -> list:
-        """Block on a serving step's outputs and bring them to the host;
-        the next launch's gap runs from here."""
+        """Block on a serving step's outputs and bring them to the host,
+        all transfers started at once; the next launch's gap runs from
+        here."""
         with self._phase("device-wait", "device_wait_s") as p:
-            out = [np.asarray(a) for a in arrays]
+            out = [np.asarray(a) for a in jax.device_get(list(arrays))]
         self._result_at = p.end
         return out
 
@@ -869,12 +939,13 @@ class ServingEngine:
 
     # --------------------------------------------------------- sampling
     @staticmethod
-    def _sampling_rows(reqs: list):
-        """Per-row sampling params for a prefill group. The counter is
-        the request's emission index (``len(out_tokens)``) — a pure
-        function of the request, so a sampled stream reproduces across
-        engine configurations and preempted re-admissions."""
-        n = len(reqs)
+    def _sampling_rows(reqs: list, rows: int | None = None):
+        """Per-row sampling params for a prefill group (``rows`` long,
+        greedy past ``reqs``). The counter is the request's emission
+        index (``len(out_tokens)``) — a pure function of the request,
+        so a sampled stream reproduces across engine configurations and
+        preempted re-admissions."""
+        n = len(reqs) if rows is None else rows
         temps = np.zeros(n, np.float32)
         top_ks = np.zeros(n, np.int32)
         seeds = np.zeros(n, np.int32)
@@ -888,9 +959,11 @@ class ServingEngine:
         return (jnp.asarray(temps), jnp.asarray(top_ks),
                 jnp.asarray(seeds), jnp.asarray(ctrs))
 
-    def _sampling_slots(self):
+    def _sampling_slots(self, ahead: int = 0):
         """Per-slot sampling params for a decode/verify step (greedy
-        defaults for empty slots — their draws are discarded)."""
+        defaults for empty slots — their draws are discarded); ``ahead``
+        counts emissions not yet committed (a step launched before the
+        one before it commits)."""
         B = self.B
         temps = np.zeros(B, np.float32)
         top_ks = np.zeros(B, np.int32)
@@ -903,7 +976,7 @@ class ServingEngine:
             temps[i] = sp.temperature
             top_ks[i] = sp.top_k
             seeds[i] = sp.seed
-            ctrs[i] = len(r.out_tokens)
+            ctrs[i] = len(r.out_tokens) + ahead
         return (jnp.asarray(temps), jnp.asarray(top_ks),
                 jnp.asarray(seeds), jnp.asarray(ctrs))
 
@@ -1076,16 +1149,26 @@ class ServingEngine:
             else:
                 key = n0                         # exact-length co-batching
             groups.setdefault(key, []).append((req, slot, n0))
-        for key, members in groups.items():
+        batches = [(key, members[i:i + self.admit_rows])
+                   for key, members in groups.items()
+                   for i in range(0, len(members), self.admit_rows)]
+        for key, members in batches:
             width = key if isinstance(key, int) else members[0][2]
-            toks = np.zeros((len(members), width), np.int32)
-            last = np.zeros(len(members), np.int32)
-            slots = np.zeros(len(members), np.int32)
+            # a model with state rows admits in power-of-two row counts:
+            # its admission then compiles once per (rows, width) bucket,
+            # not once per group size. Pad rows write nowhere (slot B is
+            # out of range, dropped).
+            rows = len(members)
+            if self.state_keys:
+                rows = 1 << (rows - 1).bit_length()
+            toks = np.zeros((rows, width), np.int32)
+            last = np.zeros(rows, np.int32)
+            slots = np.full(rows, self.B, np.int32)
             for j, (req, slot, n0) in enumerate(members):
                 toks[j, :n0] = self._eff_prompt(req)[:n0]
                 last[j] = n0 - 1
                 slots[j] = slot
-            samp = self._sampling_rows([req for req, _, _ in members])
+            samp = self._sampling_rows([req for req, _, _ in members], rows)
             if self.paged:
                 nxt, logp, pref = self._prefill_paged(
                     self.params, jnp.asarray(toks), jnp.asarray(last),
@@ -1094,6 +1177,10 @@ class ServingEngine:
                     eff = self._eff_prompt(req)
                     self._insert_paged(pref, j, slot, eff[:n0],
                                        more=n0 < len(eff))
+                if self.state_keys:
+                    self.caches = self._write_state(self.caches, pref,
+                                                    jnp.asarray(slots))
+                    self.metrics["state_resets"] += len(members)
             else:
                 nxt, logp, self.caches = self._admit(
                     self.params, self.caches, jnp.asarray(toks),
@@ -1419,8 +1506,10 @@ class ServingEngine:
                                 args={"slot": slot,
                                       "generated": len(req.out_tokens)})
 
-    def _ensure_writable(self, i: int, width: int) -> int:
-        """Make positions ``[len, len + width)`` of slot ``i`` safe to
+    def _ensure_writable(self, i: int, width: int,
+                         start: int | None = None) -> int:
+        """Make positions ``[len, len + width)`` of slot ``i`` (from
+        ``start`` in place of ``len`` when given) safe to
         scatter into: **copy-on-write** a shared tail before any write
         would land in it, drop stale prefix-index entries for in-place
         writes, and allocate blocks through the window's last position
@@ -1429,7 +1518,7 @@ class ServingEngine:
         actually secured: the full width, a degraded count when the pool
         ran out mid-window (the engine speculates less), or 0 — the slot
         cannot even take its next single token and must park."""
-        L = int(self.slot_len[i])
+        L = int(self.slot_len[i]) if start is None else start
         bs = self.block_size
         first_bi = L // bs
         if first_bi < len(self.slot_blocks[i]):
@@ -1701,6 +1790,8 @@ class ServingEngine:
                     top_ks, seeds, ctrs)
         self.metrics["decode_steps"] += 1
         self.metrics["chunk_steps"] += 1
+        if self.state_keys:
+            self.metrics["state_rows_stepped"] += len(active)
         return _Tick(lambda: self._commit_chunk(active, n_fed, finished,
                                                 nxt, logp))
 
@@ -1817,14 +1908,14 @@ class ServingEngine:
             return self._spec_step(active, n_spec, finished)
         if chunking:
             return self._chunk_step(active, chunk_want, finished)
-        tok = np.zeros((self.B, 1), np.int32)
+        tok = np.zeros(self.B, np.int32)
         for i, r in enumerate(self.slot_req):
             if r is None:
                 continue            # parked rows too: their scatter lands
             if self.slot_pending[i]:            # in the scratch block
-                tok[i, 0] = self.slot_pending[i][0]   # catch-up prompt token
+                tok[i] = self.slot_pending[i][0]    # catch-up prompt token
             else:
-                tok[i, 0] = r.out_tokens[-1]
+                tok[i] = r.out_tokens[-1]
         samp = self._sampling_slots()
         if self.paged and self.use_kernel:
             self.metrics["kernel_positions"] += len(active)
@@ -1837,16 +1928,71 @@ class ServingEngine:
                     jnp.asarray(self.block_table), *samp)
             else:
                 nxt, logp, self.caches = self._decode(
-                    self.params, jnp.asarray(tok), self.caches,
+                    self.params, jnp.asarray(tok[:, None]), self.caches,
                     jnp.asarray(self.slot_len), *samp)
         self.metrics["decode_steps"] += 1
-        return _Tick(lambda: self._commit_decode(active, finished, nxt,
-                                                 logp))
+        if self.state_keys:
+            # parked rows left ``active`` in _grow_or_park: their state
+            # stays (their write site is the scratch block)
+            self.metrics["state_rows_stepped"] += len(active)
+        reqs = [self.slot_req[i] for i in active]
+        return _Tick(lambda: self._commit_decode(active, reqs, finished,
+                                                 nxt, logp),
+                     chain=(active, reqs, nxt) if self.paged else None)
 
-    def _commit_decode(self, active, finished, nxt, logp) -> list:
-        nxt, logp = self._result(nxt, logp)
+    def dispatch_next(self, tick: _Tick) -> _Tick | None:
+        """Launch the decode step that follows ``tick`` before ``tick``
+        commits, fed by its sampled tokens on the device: the device
+        then runs the two steps back to back, with no host round trip
+        between them. Only where that step is known from the host state
+        already: ``tick`` is a plain paged decode over every slot (none
+        free, so no admission can come between the two), no row ends
+        on ``tick`` by its length or the capacity, no prompt token is
+        pending, nothing speculates, and the pool grants every row the
+        next write site. Returns None, launching nothing, otherwise. A
+        row that ends on ``tick`` by a stop token rides the step it
+        launched, and its token there is dropped at commit. Commit
+        ``tick`` before the tick this returns."""
+        if tick.chain is None or self.spec_k or self._waiting \
+                or self._finished_at_admit:
+            return None
+        active, reqs, prev = tick.chain
+        if len(active) != self.B:
+            return None
+        for i, r in zip(active, reqs):
+            if (self.slot_req[i] is not r or self.slot_pending[i]
+                    or len(r.out_tokens) + 2 > r.max_new_tokens
+                    or self.slot_len[i] + 2 > self.max_seq):
+                return None
+        lengths = self.slot_len + 1
         for i in active:
-            r = self.slot_req[i]
+            if not self._ensure_writable(i, 1, start=int(lengths[i])):
+                return None
+        samp = self._sampling_slots(ahead=1)
+        if self.use_kernel:
+            self.metrics["kernel_positions"] += len(active)
+            self._count_kernel_pages(1, lengths)
+        # the host arrays change when ``tick`` commits: pass copies
+        with self._phase("launch", "launch_s"):
+            nxt, logp, self.caches = self._decode(
+                self.params, prev, self.caches, jnp.asarray(lengths),
+                jnp.asarray(self.block_table.copy()), *samp)
+        # queued behind a step still running: no gap on the device
+        self.metrics["launch_gaps"] += 1
+        self.metrics["chained_steps"] += 1
+        self._result_at = None
+        self.metrics["decode_steps"] += 1
+        if self.state_keys:
+            self.metrics["state_rows_stepped"] += len(active)
+        return _Tick(lambda: self._commit_decode(active, reqs, [], nxt,
+                                                 logp),
+                     chain=(active, reqs, nxt))
+
+    def _commit_decode(self, active, reqs, finished, nxt, logp) -> list:
+        nxt, logp = self._result(nxt, logp)
+        for i, r in zip(active, reqs):
+            if self.slot_req[i] is not r:
+                continue        # ended (stop token, cancel) on the step
             self.slot_len[i] += 1
             if self.slot_pending[i]:
                 # a shared admission catching up on its un-shared prompt

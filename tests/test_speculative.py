@@ -132,8 +132,12 @@ def _paged_setup(model, params, toks, bs, num_blocks):
             table[b, i] = nxt
             lo, hi = i * bs, min((i + 1) * bs, P)
             for key in cache:
-                cache[key] = cache[key].at[:, nxt, : hi - lo].set(
-                    pref[key][:, b, lo:hi].astype(cache[key].dtype))
+                # token-major prefill rows into the head-major pool
+                # block (L, Hkv / f, bs, hd * f), f heads to a row
+                blk = pref[key][:, b, lo:hi]
+                blk = blk.reshape(blk.shape[:2] + cache[key].shape[2::2])
+                cache[key] = cache[key].at[:, nxt, :, : hi - lo].set(
+                    jnp.swapaxes(blk, 1, 2).astype(cache[key].dtype))
             nxt += 1
     return cache, jnp.asarray(table), jnp.full((B,), P, jnp.int32)
 

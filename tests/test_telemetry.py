@@ -323,9 +323,11 @@ class _SlowFetch:
 
 def _slow(vc, fn, dt, fetch=0.0):
     """``fn`` taking ``dt`` of the clock; with ``fetch``, its first two
-    outputs take that long each to reach the host."""
+    outputs take that long each to reach the host (an output fed to the
+    next step on the device reaches no host)."""
     def run(*a, **k):
         vc.advance(dt)
+        a = [x.x if isinstance(x, _SlowFetch) else x for x in a]
         out = fn(*a, **k)
         if fetch:
             out = (_SlowFetch(out[0], vc, fetch),
@@ -384,14 +386,21 @@ def test_launch_gap_is_the_host_phases_between_steps(hooked):
         ticks.append(({k: lm[k] - prev[0][k] for k in lm},
                       {k: em[k] - prev[1][k] for k in em}))
         prev = (lm, em)
-    assert ticks[0][1]["launch_gaps"] == 0      # no step before the first
+    # a step launched before the step before it committed (chained) is
+    # queued behind it on the device: a gap of 0
+    first = ticks[0][1]
+    assert first["launch_gaps"] == first["chained_steps"]   # none before
     for (pl, pe), (lm, em) in zip(ticks, ticks[1:]):
-        assert em["launch_gaps"] == 1
-        expect = (pl["commit_wait_s"] - pe["device_wait_s"]
-                  + pl["account_s"] + pl["emit_s"] + lm["outside_s"]
-                  + lm["cancel_s"] + lm["fill_s"] + lm["dispatch_s"])
+        launched = em["decode_steps"] - em["chained_steps"]
+        assert launched in (0, 1)       # 0: the last tick launched it
+        assert em["launch_gaps"] == launched + em["chained_steps"]
+        expect = launched * (
+            pl["commit_wait_s"] - pe["device_wait_s"]
+            + pl["account_s"] + pl["emit_s"] + lm["outside_s"]
+            + lm["cancel_s"] + lm["fill_s"] + lm["dispatch_s"])
         assert em["launch_gap_s"] == pytest.approx(expect, abs=1e-9)
     last_l, last_e = snaps[-1]
+    assert last_e["chained_steps"] > 0
     n = len(snaps)
     assert last_e["launch_gaps"] == n - 1
     assert last_e["launch_s"] == pytest.approx(n * SLOW["launch"])
@@ -408,6 +417,7 @@ PHASE_COUNTERS = [
     ("apply-cancels", PID_LOOP, "loop", "cancel_s"),
     ("fill", PID_LOOP, "loop", "fill_s"),
     ("dispatch", PID_LOOP, "loop", "dispatch_s"),
+    ("dispatch-next", PID_LOOP, "loop", "dispatch_next_s"),
     ("plan-window", PID_LOOP, "loop", "plan_time_s"),
     ("commit-wait", PID_LOOP, "loop", "commit_wait_s"),
     ("account", PID_LOOP, "loop", "account_s"),

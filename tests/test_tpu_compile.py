@@ -135,3 +135,52 @@ def test_full_width_serving_step_compiles_and_fits(serving_args, tpu_branch,
     ma = compiled.memory_analysis()
     used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, (program, used)
+
+
+def test_paged_kernel_compiles_at_granite_widths(one_chip, tpu_branch):
+    """Granite-4.0-H-Micro's attention: heads of 64 (32 query on 8 KV,
+    G 4) at scale 1/64, decode and a 256-token chunk window. Heads
+    narrower than the 128 lanes are lane-packed two to a pool row, so
+    the op compiles the kernel at rows of 128 over 4 packed heads."""
+    from repro.models.attention import lane_pack, paged_decode_attention
+    cfg = get_config("granite-4.0-h-micro")
+    Hq, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    assert (Hq, Hkv, hd, cfg.attention_multiplier) == (32, 8, 64, 1 / 64)
+    f = lane_pack(hd, Hkv)
+    batch, mb = 32, 3072 // BLOCK
+    pool = _sds((batch * mb + 1, Hkv // f, BLOCK, hd * f), jnp.bfloat16,
+                one_chip)
+    fn = jax.jit(lambda q, k, v, kn, vn, t, n: paged_decode_attention(
+        q, k, v, kn, vn, t, n, use_kernel=True,
+        scale=cfg.attention_multiplier))
+    one = _sds((batch, 1, Hkv, hd), jnp.bfloat16, one_chip)
+    text = fn.lower(_sds((batch, 1, Hq, hd), jnp.bfloat16, one_chip), pool,
+                    pool, one, one, _sds((batch, mb), jnp.int32, one_chip),
+                    _sds((batch,), jnp.int32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_granite_serving_step_compiles_and_fits(one_chip, tpu_branch,
+                                                program):
+    """Granite-4.0-H-Micro at published width (40 layers: 36 Mamba-2,
+    4 attention) at its cell's engine shape, batch 32 x 3072 tokens and
+    256-token chunk windows: the paged kernel is in the program, and
+    arguments (weights, pool, 32 state rows) plus temporaries fit one
+    chip."""
+    model = build_model(get_config("granite-4.0-h-micro"))
+    batch, max_seq, width = 32, 3072, 256
+    put = lambda tree: jax.tree.map(                       # noqa: E731
+        lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+    params = put(jax.eval_shape(model.init, jax.random.key(0)))
+    caches = put(jax.eval_shape(lambda: model.init_paged_cache(
+        batch * (max_seq // BLOCK) + 1, BLOCK, slots=batch)))
+    args = paged_program_args(params, caches, batch=batch,
+                              blocks_per_slot=max_seq // BLOCK, width=width,
+                              sharding=one_chip)
+    progs = paged_programs(model, block_size=BLOCK, use_kernel=True)
+    compiled = progs[program].lower(*args[program]).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert used < V5E_HBM_BYTES, (program, used)
