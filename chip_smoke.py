@@ -16,7 +16,8 @@ paged-attention kernel, the pool sized from the compiled programs'
 
 1. ``engine``: the decode program must contain the compiled kernel
    (``tpu_custom_call``);
-2. ``kernel``: the kernel at the served widths must match the
+2. ``kernel``: the kernel at the served widths must write each
+   window's K/V into the pool where a plain scatter does, and match the
    gather-then-softmax oracle ``gathered_window_ref`` for windows of 1, 4
    and 64 tokens, within ``TOLERANCE``;
 3. ``serve``: eight requests with prompts of 256-448 tokens (chunked
@@ -104,16 +105,20 @@ def engine_phase(args, on_chip: bool):
 
 
 def _window_case(cfg, eng, S: int, seed: int):
-    """Random q and a head-major pool at the engine's widths, disjoint
-    per-row block tables and ragged base lengths with room for S."""
+    """Random q, the window's new K/V and a head-major one-layer pool at
+    the engine's widths, disjoint per-row block tables and ragged base
+    lengths with room for S."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     B, bs, mb = eng.B, eng.block_size, eng.blocks_per_slot
     nb = B * mb + 1
-    ks = jax.random.split(jax.random.key(seed), 3)
+    ks = jax.random.split(jax.random.key(seed), 5)
     q = jax.random.normal(ks[0], (B, S, cfg.n_heads, cfg.hd), cfg.dtype)
-    pool = (nb, cfg.n_kv_heads, bs, cfg.hd)
+    new = (B, S, cfg.n_kv_heads, cfg.hd)
+    kn = jax.random.normal(ks[3], new, cfg.dtype)
+    vn = jax.random.normal(ks[4], new, cfg.dtype)
+    pool = (1, nb, cfg.n_kv_heads, bs, cfg.hd)
     pk = jax.random.normal(ks[1], pool, cfg.dtype)
     pv = jax.random.normal(ks[2], pool, cfg.dtype)
     rng = np.random.default_rng(seed)
@@ -123,7 +128,7 @@ def _window_case(cfg, eng, S: int, seed: int):
     for b in range(B):
         for i in range(-(-(int(base[b]) + S) // bs)):
             table[b, i] = free.pop()
-    return q, pk, pv, jnp.asarray(table), jnp.asarray(base)
+    return q, kn, vn, pk, pv, jnp.asarray(table), jnp.asarray(base)
 
 
 def kernel_phase(cfg, eng, seed: int) -> None:
@@ -134,17 +139,29 @@ def kernel_phase(cfg, eng, seed: int) -> None:
     from repro.kernels.paged_attention.ref import gathered_window_ref
     tol = TOLERANCE[jax.numpy.dtype(cfg.dtype).name]
     for S in WINDOWS:
-        case = _window_case(cfg, eng, S, seed + S)
-        out, lse = paged_window_attention(*case)
+        q, kn, vn, pk, pv, table, base = _window_case(cfg, eng, S, seed + S)
+        B, bs = q.shape[0], pk.shape[3]
+        out, lse, wk, wv = paged_window_attention(
+            q, kn, vn, pk, pv, table, base, 0, np.full(B, S, np.int32))
+        # the same writes by a plain scatter, then the gather oracle
+        pos = np.asarray(base)[:, None] + np.arange(S)[None]
+        phys = np.asarray(table)[np.arange(B)[:, None], pos // bs]
+        pk = pk.at[0, phys, :, pos % bs].set(kn)
+        pv = pv.at[0, phys, :, pos % bs].set(vn)
+        for got, want in ((wk, pk), (wv, pv)):
+            check(np.array_equal(np.asarray(got), np.asarray(want)),
+                  f"kernel S={S}: the pool it wrote differs from the "
+                  "scatter's")
         with jax.default_matmul_precision("highest"):
-            ref_out, ref_lse = gathered_window_ref(*case)
+            ref_out, ref_lse = gathered_window_ref(q, pk[0], pv[0], table,
+                                                   base)
         errs = []
         for a, b in ((out, ref_out), (lse, ref_lse)):
             a, b = np.float32(a), np.float32(b)
             check(np.all(np.isfinite(a)), f"kernel S={S}: non-finite")
             errs.append(float(np.max(np.abs(a - b) / (1 + np.abs(b)))))
-        print(f"  kernel S={S}: q {tuple(case[0].shape)}, pool "
-              f"{tuple(case[1].shape)}; max |kernel - gather| / (1 + "
+        print(f"  kernel S={S}: q {tuple(q.shape)}, pool "
+              f"{tuple(pk.shape)}; max |kernel - gather| / (1 + "
               f"|gather|): out {errs[0]:.2e}, lse {errs[1]:.2e} "
               f"(tolerance {tol:g})")
         check(max(errs) <= tol, f"kernel S={S} differs from the gather "

@@ -13,7 +13,9 @@ rows hold 64-512 tokens, chunk rows start their window anywhere in
 ``layers`` dependent calls (one per layer of the cell's model), median
 of ``--reps`` runs, reported per call. The gather path is the read the
 engine runs without the kernel: gather the pool through the table, then
-masked attention over the whole table.
+masked attention over the whole table. The kernel also writes each
+window's K/V into the pool (here the values the pool already holds, so
+every call sees the same pool).
 
 One JSON line per case: milliseconds per call for each path, the
 largest difference between the paths' outputs, the share of table
@@ -23,6 +25,7 @@ GB/s (TPU v5e). Refuses to run off the TPU.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import statistics
 import time
@@ -55,8 +58,9 @@ def inputs(Hq: int, Hkv: int, hd: int, S: int, base: np.ndarray):
     nb = BATCH * mb + 1
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (BATCH, S, Hq, hd), jnp.bfloat16)
-    pk = jax.random.normal(ks[1], (nb, Hkv, BLOCK, hd), jnp.bfloat16)
-    pv = jax.random.normal(ks[2], (nb, Hkv, BLOCK, hd), jnp.bfloat16)
+    # a one-layer stack, the kernel's operand
+    pk = jax.random.normal(ks[1], (1, nb, Hkv, BLOCK, hd), jnp.bfloat16)
+    pv = jax.random.normal(ks[2], (1, nb, Hkv, BLOCK, hd), jnp.bfloat16)
     # each row owns its own pages, in a shuffled order; tails at scratch 0
     perm = np.random.default_rng(1).permutation(np.arange(1, nb))
     table = np.zeros((BATCH, mb), np.int32)
@@ -66,35 +70,51 @@ def inputs(Hq: int, Hkv: int, hd: int, S: int, base: np.ndarray):
     return q, pk, pv, jnp.asarray(table), jnp.asarray(base)
 
 
+def held(pool, table, base, S):
+    """What layer 0 of the pool holds at each row's window positions."""
+    pos = base[:, None] + jnp.arange(S)[None]
+    phys = table[jnp.arange(BATCH)[:, None], pos // BLOCK]
+    return pool[0, phys, :, pos % BLOCK]
+
+
 def kernel_read(q, pk, pv, table, base):
-    return paged_kernel.paged_window_attention(q, pk, pv, table, base,
-                                               interpret=False)[0]
+    S = q.shape[1]
+    out, _, pk, pv = paged_kernel.paged_window_attention(
+        q, held(pk, table, base, S), held(pv, table, base, S), pk, pv,
+        table, base, 0, jnp.full((BATCH,), S, jnp.int32), interpret=False)
+    return out, pk, pv
 
 
 def gather_read(q, pk, pv, table, base):
-    k, v = _gather_pool(pk, table), _gather_pool(pv, table)
+    k, v = _gather_pool(pk[0], table), _gather_pool(pv[0], table)
     if q.shape[1] == 1:
         out = decode_attention(q, k, v, base + 1)
     else:
         out = verify_decode_attention(q, k, v, base)
-    return out.reshape(q.shape)
+    return out.reshape(q.shape), pk, pv
 
 
 def per_call_ms(read, args, layers: int, reps: int) -> float:
     """Median over ``reps`` of one jitted loop of ``layers`` calls, each
-    fed the last one's output so none can be hoisted or skipped."""
-    @jax.jit
+    fed the last one's output (and pool) so none can be hoisted or
+    skipped."""
+    @functools.partial(jax.jit, donate_argnums=(1, 2))
     def run(q, pk, pv, table, base):
-        def body(_, q):
-            out = read(q, pk, pv, table, base)
-            return q + (out * 1e-3).astype(q.dtype)
-        return jax.lax.fori_loop(0, layers, body, q)
+        def body(_, carry):
+            q, pk, pv = carry
+            out, pk, pv = read(q, pk, pv, table, base)
+            return q + (out * 1e-3).astype(q.dtype), pk, pv
+        return jax.lax.fori_loop(0, layers, body, (q, pk, pv))
 
-    run(*args).block_until_ready()                      # compile, warm
+    q, pk, pv, table, base = args
+    pk, pv = jnp.copy(pk), jnp.copy(pv)          # the loop donates its own
+    q, pk, pv = run(q, pk, pv, table, base)              # compile, warm
+    q.block_until_ready()
     times = []
     for _ in range(reps):
         t0 = time.perf_counter()
-        run(*args).block_until_ready()
+        q, pk, pv = run(q, pk, pv, table, base)
+        q.block_until_ready()
         times.append(time.perf_counter() - t0)
     return 1e3 * statistics.median(times) / layers
 
@@ -122,17 +142,16 @@ def main(argv=None) -> None:
                        / (BATCH * (MAX_SEQ // BLOCK)),
                        "hbm_floor_ms": 1e3 * kv_bytes / HBM_BYTES_PER_S,
                        "device": dev.device_kind}
-                plan = getattr(paged_kernel, "tile_plan", None)
-                if plan is not None:
-                    row["P_hg"] = plan(Hkv=Hkv, bs=BLOCK, hd=hd,
-                                       R=S * Hq // Hkv, itemsize=2,
-                                       max_blocks=MAX_SEQ // BLOCK)
+                row["P_hg"] = paged_kernel.tile_plan(
+                    Hkv=Hkv, bs=BLOCK, hd=hd, S=S, G=Hq // Hkv, itemsize=2,
+                    max_blocks=MAX_SEQ // BLOCK)
                 paths = args.paths.split(",")
                 for path in paths:
                     row[f"{path}_ms"] = per_call_ms(reads[path], a, layers,
                                                     args.reps)
                 if len(paths) > 1:
-                    outs = [np.float32(jax.jit(reads[p])(*a)) for p in paths]
+                    outs = [np.float32(jax.jit(reads[p])(*a)[0])
+                            for p in paths]
                     row["max_abs_diff"] = float(np.abs(outs[0] - outs[1]).max())
                 print(json.dumps(row), flush=True)
                 lines.append(row)

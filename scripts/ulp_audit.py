@@ -97,6 +97,16 @@ def _window_case(jax, jnp, B, S, Hq, Hkv, hd, bs, mb, seed):
     return q, pk, pv, jnp.asarray(table), jnp.asarray(base)
 
 
+def _held(jnp, pool, table, base, S):
+    """What a one-layer pool holds at each row's positions ``base +
+    [0, S)``: the kernel writes these back, so it reads the pool as
+    built."""
+    bs = pool.shape[2]
+    pos = jnp.maximum(jnp.asarray(base)[:, None] + jnp.arange(S)[None], 0)
+    phys = table[jnp.arange(table.shape[0])[:, None], pos // bs]
+    return pool[phys, :, pos % bs]
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", type=Path, default=Path("experiments/ulp-audit"))
@@ -116,8 +126,10 @@ def main() -> int:
         for seed in range(args.seeds):
             q, pk, pv, tb, ln = _decode_case(jax, jnp, B, Hq, Hkv, hd, bs,
                                              mb, seed)
-            out, lse = paged_decode_attention(q, pk, pv, tb, ln,
-                                              sliding_window=win)
+            kn, vn = (_held(jnp, p, tb, ln - 1, 1)[:, 0] for p in (pk, pv))
+            out, lse, _, _ = paged_decode_attention(
+                q, kn, vn, pk[None], pv[None], tb, ln, 0,
+                sliding_window=win)
             ro, rl = paged_decode_attention_ref(q, pk, pv, tb, ln,
                                                 sliding_window=win)
             cases.append({
@@ -128,8 +140,10 @@ def main() -> int:
         for seed in range(args.seeds):
             q, pk, pv, tb, base = _window_case(jax, jnp, B, S, Hq, Hkv, hd,
                                                bs, mb, seed)
-            out, lse = paged_window_attention(q, pk, pv, tb, base,
-                                              sliding_window=win)
+            kn, vn = (_held(jnp, p, tb, base, S) for p in (pk, pv))
+            out, lse, _, _ = paged_window_attention(
+                q, kn, vn, pk[None], pv[None], tb, base, 0,
+                jnp.full((B,), S, jnp.int32), sliding_window=win)
             ro, rl = paged_window_attention_ref(q, pk, pv, tb, base,
                                                 sliding_window=win)
             cases.append({

@@ -56,7 +56,7 @@ def paged_window_attention_ref(q, pool_k, pool_v, block_table, base_lens, *,
     G = Hq // Hkv
     R = S * G
     max_blocks = block_table.shape[1]
-    P, hg = tile_plan(Hkv=Hkv, bs=bs, hd=hd, R=R,
+    P, hg = tile_plan(Hkv=Hkv, bs=bs, hd=hd, S=S, G=G,
                       itemsize=pool_k.dtype.itemsize, max_blocks=max_blocks)
     T = P * bs
     qg = jnp.transpose(q.reshape(B, S, Hkv, G, hd),

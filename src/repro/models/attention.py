@@ -229,45 +229,58 @@ def _gather_pool(pool, block_table):
 
 
 def paged_verify_attention(q, pool_k, pool_v, k_new, v_new, block_table,
-                           cache_len, n_write, *, sliding_window: int = 0,
-                           use_kernel: bool = False, scale=None):
-    """Multi-token window against the KV block pool: the speculative
-    **verify** step and the **chunked-prefill** step share this path (a
-    prompt chunk is a window of known tokens scattered against the
-    partially-resident prompt; the causal-inside-the-window mask is
-    exactly the partial-prompt causal mask).
+                           cache_len, n_write, layer, *,
+                           sliding_window: int = 0, use_kernel: bool = False,
+                           scale=None):
+    """Multi-token window against layer ``layer`` of the stacked KV
+    block pool (``(L, num_blocks, Hkv, bs, hd)`` per leaf): the
+    speculative **verify** step and the **chunked-prefill** step share
+    this path (a prompt chunk is a window of known tokens written
+    against the partially-resident prompt; the causal-inside-the-window
+    mask is exactly the partial-prompt causal mask).
 
     q/k_new/v_new: (B, S, H*, hd) — S window tokens per row at
     positions ``cache_len[b] + [0, S)``; n_write: (B,) tokens of the
     window row b actually owns blocks for (``n_spec + 1`` when
     verifying, the row's chunk token count when chunk-prefilling; 0 for
-    parked riders). Window token j of row b scatters at
+    parked riders). Window token j of row b is written at
     ``(block_table[b, (len+j) // bs], (len+j) % bs)`` when ``j <
-    n_write[b]`` and is **diverted to the scratch block** otherwise —
-    a row must never scatter speculative K/V into a block it has not
-    been granted (it could still be shared with another sequence, or
-    not allocated at all). Reads past a row's n_write are garbage but
-    masked out of every output the caller commits (acceptance is capped
-    at n_spec). Returns (out (B, S, Hq*hd), new_pool_k, new_pool_v).
+    n_write[b]`` and never into the row's table otherwise — a row must
+    never write speculative K/V into a block it has not been granted
+    (it could still be shared with another sequence, or not allocated
+    at all). Reads past a row's n_write are garbage but masked out of
+    every output the caller commits (acceptance is capped at n_spec).
+    Returns (out (B, S, Hq*hd), new_pool_k, new_pool_v).
 
     ``use_kernel`` runs the **fused multi-token Pallas kernel**
     (``kernels.paged_attention.paged_window_attention``): ONE launch
     covers the whole (q_len, kv_len) window — every window query of
     a row rides the same query tile, masked causally *inside* the
     window (query j of row b sees cache positions <= cache_len[b] + j,
-    its per-row base length) — with the pool still read in place
-    through the scalar-prefetched block table. The jnp path gathers
-    once and applies the same causal-in-window mask.
+    its per-row base length) — and writes the granted tokens into the
+    layer's pages in place (the window's tokens past n_write are not
+    written), reading through the scalar-prefetched block table: the
+    stacked pool is neither sliced per layer nor copied. The jnp path
+    (the CPU reference) scatters into the stacked pool, diverting the
+    tokens past n_write to the scratch block, gathers the layer once
+    and applies the same causal-in-window mask.
     """
     from repro.serve.blocks import SCRATCH_BLOCK
     if pool_k.shape[-1] != q.shape[-1]:
         return _lane_packed(paged_verify_attention, q, pool_k, pool_v,
                             k_new, v_new, block_table, cache_len, n_write,
-                            sliding_window=sliding_window,
+                            layer, sliding_window=sliding_window,
                             use_kernel=use_kernel, scale=scale)
-    bs = pool_k.shape[2]
     B, S = q.shape[:2]
     base = jnp.asarray(cache_len, jnp.int32).reshape(-1)    # (B,)
+    if use_kernel:
+        from repro.kernels.paged_attention.ops import (
+            paged_window_attention as _window_kernel)
+        out, _, pool_k, pool_v = _window_kernel(
+            q, k_new, v_new, pool_k, pool_v, block_table, base, layer,
+            n_write, sliding_window=sliding_window, scale=scale)
+        return out.reshape(B, S, -1), pool_k, pool_v
+    bs = pool_k.shape[3]
     pos = base[:, None] + jnp.arange(S)[None, :]            # (B,S)
     rows = jnp.arange(B)
     safe = jnp.arange(S)[None, :] < jnp.asarray(n_write,
@@ -276,74 +289,75 @@ def paged_verify_attention(q, pool_k, pool_v, k_new, v_new, block_table,
                      SCRATCH_BLOCK)                         # (B,S)
     # head-major pool: the (B,S) advanced indices around the head slice
     # address (B, S, Hkv, hd) sites, the layout of k_new/v_new
-    pool_k = pool_k.at[phys, :, pos % bs].set(k_new.astype(pool_k.dtype))
-    pool_v = pool_v.at[phys, :, pos % bs].set(v_new.astype(pool_v.dtype))
-    if use_kernel:
-        from repro.kernels.paged_attention.ops import (
-            paged_window_attention as _window_kernel)
-        out, _ = _window_kernel(q, pool_k, pool_v, block_table, base,
-                                sliding_window=sliding_window, scale=scale)
-        return out.reshape(B, S, -1), pool_k, pool_v
-    out = verify_decode_attention(q, _gather_pool(pool_k, block_table),
-                                  _gather_pool(pool_v, block_table), base,
-                                  sliding_window=sliding_window, scale=scale)
+    pool_k = pool_k.at[layer, phys, :, pos % bs].set(
+        k_new.astype(pool_k.dtype))
+    pool_v = pool_v.at[layer, phys, :, pos % bs].set(
+        v_new.astype(pool_v.dtype))
+    out = verify_decode_attention(q, _gather_pool(pool_k[layer], block_table),
+                                  _gather_pool(pool_v[layer], block_table),
+                                  base, sliding_window=sliding_window,
+                                  scale=scale)
     return out, pool_k, pool_v
 
 
 def paged_decode_attention(q, pool_k, pool_v, k_new, v_new, block_table,
-                           cache_len, *, sliding_window: int = 0,
+                           cache_len, layer, *, sliding_window: int = 0,
                            use_kernel: bool = False, scale=None):
-    """Decode one token per sequence against a shared KV **block pool**.
+    """Decode one token per sequence against layer ``layer`` of a shared
+    KV **block pool** stacked over layers.
 
-    q/k_new/v_new: (B, 1, H*, hd); pool_k/pool_v: (num_blocks, Hkv, bs,
-    hd), head-major so the kernel's per-head block tile is contiguous;
-    block_table: (B, max_blocks) int32; cache_len: (B,) tokens
-    already cached per row. Row b's logical position j lives at
+    q/k_new/v_new: (B, 1, H*, hd); pool_k/pool_v: (L, num_blocks, Hkv,
+    bs, hd), head-major so the kernel's per-head block tile is
+    contiguous; block_table: (B, max_blocks) int32; cache_len: (B,)
+    tokens already cached per row. Row b's logical position j lives at
     ``(block_table[b, j // bs], j % bs)`` — the new token's K/V is
-    scattered there first (owned blocks are disjoint across rows — with
-    prefix sharing the engine copy-on-writes any shared tail before the
-    step — so the scatter never collides; unowned table entries point at
-    the reserved scratch block 0). Returns (out, new_pool_k, new_pool_v).
+    written there (owned blocks are disjoint across rows — with prefix
+    sharing the engine copy-on-writes any shared tail before the step —
+    so the writes never collide; unowned table entries point at the
+    reserved scratch block 0). Returns (out, new_pool_k, new_pool_v).
 
-    Two read paths behind ``use_kernel``:
+    Two paths behind ``use_kernel``:
 
-    * **False (portable jnp reference)** — gather each row's effective
-      cache through its table row into a transient (B, max_blocks*bs)
-      buffer and run the same masked ``decode_attention`` as the stripe
-      path, so the attention math — and therefore the emitted token
-      stream — is unchanged.
-    * **True (Pallas kernel)** — ``kernels.paged_attention`` reads K/V
-      through the block table *in place* (the scalar-prefetched table
-      addresses one DMA per page the row holds); no transient gather.
-      This is the q_len = 1 **degenerate case of the fused window
-      kernel** that also serves speculative verify and chunked prefill
-      (see ``paged_verify_attention``) — one kernel body behind every
-      paged consumer. Compiled on TPU, interpret mode elsewhere; held
+    * **False (portable jnp reference)** — scatter the token into the
+      stacked pool, gather each row's effective cache of the layer
+      through its table row into a transient (B, max_blocks*bs) buffer
+      and run the same masked ``decode_attention`` as the stripe path,
+      so the attention math — and therefore the emitted token stream —
+      is unchanged.
+    * **True (Pallas kernel)** — ``kernels.paged_attention`` writes the
+      token into its page and reads K/V through the block table *in
+      place*, the whole stacked pool aliased in and out (the
+      scalar-prefetched table and layer address one DMA per page the
+      row holds); no transient gather, no per-layer slice. This is the
+      q_len = 1 **degenerate case of the fused window kernel** that
+      also serves speculative verify and chunked prefill (see
+      ``paged_verify_attention``) — one kernel body behind every paged
+      consumer. Compiled on TPU, interpret mode elsewhere; held
       bit-exact (f32) against its streaming jnp oracle by the
       differential grids in ``tests/test_kernels.py``.
     """
     if pool_k.shape[-1] != q.shape[-1]:
         return _lane_packed(paged_decode_attention, q, pool_k, pool_v,
-                            k_new, v_new, block_table, cache_len,
+                            k_new, v_new, block_table, cache_len, layer,
                             sliding_window=sliding_window,
                             use_kernel=use_kernel, scale=scale)
-    bs = pool_k.shape[2]
     idx = jnp.asarray(cache_len, jnp.int32).reshape(-1)     # (B,)
-    rows = jnp.arange(idx.shape[0])
-    phys = block_table[rows, idx // bs]                     # (B,)
-    pool_k = pool_k.at[phys, :, idx % bs].set(
-        k_new[:, 0].astype(pool_k.dtype))
-    pool_v = pool_v.at[phys, :, idx % bs].set(
-        v_new[:, 0].astype(pool_v.dtype))
     B = block_table.shape[0]
     if use_kernel:
         from repro.kernels.paged_attention.ops import (
             paged_decode_attention as _paged_kernel)
-        out, _ = _paged_kernel(q[:, 0], pool_k, pool_v, block_table, idx + 1,
-                               sliding_window=sliding_window, scale=scale)
+        out, _, pool_k, pool_v = _paged_kernel(
+            q[:, 0], k_new[:, 0], v_new[:, 0], pool_k, pool_v, block_table,
+            idx + 1, layer, sliding_window=sliding_window, scale=scale)
         return out.reshape(B, 1, -1), pool_k, pool_v
-    out = decode_attention(q, _gather_pool(pool_k, block_table),
-                           _gather_pool(pool_v, block_table), idx + 1,
+    bs = pool_k.shape[3]
+    phys = block_table[jnp.arange(B), idx // bs]            # (B,)
+    pool_k = pool_k.at[layer, phys, :, idx % bs].set(
+        k_new[:, 0].astype(pool_k.dtype))
+    pool_v = pool_v.at[layer, phys, :, idx % bs].set(
+        v_new[:, 0].astype(pool_v.dtype))
+    out = decode_attention(q, _gather_pool(pool_k[layer], block_table),
+                           _gather_pool(pool_v[layer], block_table), idx + 1,
                            sliding_window=sliding_window, scale=scale)
     return out, pool_k, pool_v
 
@@ -351,11 +365,13 @@ def paged_decode_attention(q, pool_k, pool_v, k_new, v_new, block_table,
 def attention_block(x, p, cfg, *, mode: str, cache=None, cache_len=None,
                     positions=None, mrope_positions=None, causal=True,
                     sliding_window=None, plan=None, block_table=None,
-                    paged_kernel=False, n_write=None):
+                    paged_kernel=False, n_write=None, layer=None):
     """Full attention sub-block incl. output proj. Returns (out, new_cache).
 
     cache: dict(k=(B,T,Hkv,hd), v=(B,T,Hkv,hd)) or None — or, with
-    ``block_table`` set, the paged pool dict(k=(num_blocks,Hkv,bs,hd), ...).
+    ``block_table`` set, the whole paged pool stacked over layers,
+    dict(k=(L,num_blocks,Hkv,bs,hd), ...), of which this block reads
+    and writes layer ``layer`` and returns the whole pool.
     In decode mode, ``x`` with more than one token per row is a
     **multi-token window** — a speculative verify window or a chunked
     prefill window: the S tokens write K/V at positions
@@ -379,7 +395,8 @@ def attention_block(x, p, cfg, *, mode: str, cache=None, cache_len=None,
                 else jnp.asarray(n_write, jnp.int32)
             o, k_cache, v_cache = paged_verify_attention(
                 q, cache["k"], cache["v"], k, v, block_table, idx, nw,
-                sliding_window=win, use_kernel=paged_kernel, scale=scale)
+                layer, sliding_window=win, use_kernel=paged_kernel,
+                scale=scale)
         else:
             rows = jnp.arange(B)[:, None]
             k_cache = cache["k"].at[rows, pos].set(
@@ -413,7 +430,8 @@ def attention_block(x, p, cfg, *, mode: str, cache=None, cache_len=None,
             # paged KV: cache leaves are the shared block pool
             o, k_cache, v_cache = paged_decode_attention(
                 q, cache["k"], cache["v"], k, v, block_table, cache_len,
-                sliding_window=win, use_kernel=paged_kernel, scale=scale)
+                layer, sliding_window=win, use_kernel=paged_kernel,
+                scale=scale)
         else:
             idx = jnp.asarray(cache_len, jnp.int32)
             if idx.ndim == 0:

@@ -217,8 +217,8 @@ class Model:
         sequential :meth:`decode_step` calls would produce), so a prompt
         split into chunk windows reproduces a monolithic prefill
         bit-for-bit. ``block_table``/``paged_kernel``/``n_write`` follow
-        :meth:`verify_step` (paged rows divert writes past their granted
-        count to the scratch block). Returns (logits (B, S, V), cache).
+        :meth:`verify_step` (paged rows write nothing into their blocks
+        past their granted count). Returns (logits (B, S, V), cache).
         A ``cfg.layer_types`` stack's Mamba-2 layers run the window from
         the rows' carried state, each row over its first ``n_write``
         tokens. Other recurrent families (rwkv / hymba's SSM) cannot
@@ -316,8 +316,9 @@ class Model:
         enforces. Returns (logits (B, S, V), new_cache).
 
         Paged mode (``block_table``): ``n_write`` (B,) caps how many
-        window positions row b may scatter into its own blocks; writes
-        past the cap land in the scratch block (a speculating row is
+        window positions row b may write into its own blocks; the rest
+        never reach them (the jnp path diverts them to the scratch block,
+        the kernel writes nothing for them; a speculating row is
         granted blocks up to its watermark *before* the step — see
         ``ServingEngine._ensure_writable`` — and a rider row must not
         touch blocks it does not own). Only pure-attention ``{k, v}``

@@ -108,7 +108,7 @@ def apply_block(x, p, cfg, *, kind, mode, cache=None, extras=None, plan=None):
         mrope_positions=extras.get("mrope_positions"), plan=plan,
         block_table=extras.get("block_table"),
         paged_kernel=extras.get("paged_kernel", False),
-        n_write=extras.get("n_write"))
+        n_write=extras.get("n_write"), layer=extras.get("layer"))
 
     if kind == "hybrid":
         scache = None if cache is None else {"state": cache["ssm_state"]}
@@ -167,7 +167,7 @@ def apply_layer(x, p, cfg, *, kind, mode, cache=None, extras=None,
             cache_len=extras.get("cache_len"), plan=plan,
             block_table=extras.get("block_table"),
             paged_kernel=extras.get("paged_kernel", False),
-            n_write=extras.get("n_write"))
+            n_write=extras.get("n_write"), layer=extras.get("layer"))
     x = x + r * out
     h = layers.rmsnorm(x, p["ln2"], cfg.norm_eps)
     return x + r * layers.mlp(h, p["ffn"], cfg.act), new_cache
@@ -206,12 +206,16 @@ def apply_pattern_stack(x, blocks, cfg, *, mode, cache=None, extras=None,
     layers stacked in layer order (``{"mamba": (n_m, ...), "attention":
     (n_a, ...)}``), the cache each kind's ``CACHE_KEYS`` leaves the same
     way. The cache rides the scans as carry and each layer updates its
-    own slice in place; a prefill (no cache given) fills zeroed leaves
-    with each layer's K/V and final state. Returns (x, cache, 0)."""
+    own slice in place; a paged attention layer (a ``block_table`` in
+    ``extras``) takes the whole stacked pool and its layer index, and
+    the kernel reads and writes that layer's pages in place. A prefill
+    (no cache given) fills zeroed leaves with each layer's K/V and final
+    state. Returns (x, cache, 0)."""
     period = cfg.period
     count = {k: period.count(k) for k in set(period)}
     runs = _runs(period)
     fresh = cache is None
+    paged = (extras or {}).get("block_table") is not None
     if mode != "train" and fresh:
         shapes = jax.eval_shape(lambda h: _layer_outputs(h, blocks, cfg, mode,
                                                          extras, plan), x)
@@ -220,6 +224,11 @@ def apply_pattern_stack(x, blocks, cfg, *, mode, cache=None, extras=None,
     def layer(kind, l, h, c):
         p = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
             a, l, keepdims=False), blocks[kind])
+        if paged and kind == "attention":
+            h, nc = apply_layer(h, p, cfg, kind=kind, mode=mode,
+                                cache={n: c[n] for n in CACHE_KEYS[kind]},
+                                extras=dict(extras, layer=l), plan=plan)
+            return h, dict(c, **nc)
         ci = None if fresh else {n: jax.lax.dynamic_index_in_dim(
             c[n], l, keepdims=False) for n in CACHE_KEYS[kind]}
         h, nc = apply_layer(h, p, cfg, kind=kind, mode=mode, cache=ci,
@@ -277,7 +286,26 @@ def apply_stack(x, blocks, cfg, *, kind, mode, cache=None, extras=None,
     deepseek-7b x decode_32k dry-run.)
 
     cache: pytree stacked over L (or None). Returns (x, new_cache, aux_sum).
+
+    A paged cache (a ``block_table`` in ``extras``) rides the scan as
+    carry, whole, beside the layer index: each layer's kernel reads and
+    writes its own pages of the stacked pool in place, so the step
+    neither slices the pool per layer nor rebuilds it as a scan output.
     """
+    if (extras or {}).get("block_table") is not None:
+        def paged_body(carry, xs):
+            h, c = carry
+            bp, l = xs
+            h, c, aux = apply_block(h, bp, cfg, kind=kind, mode=mode,
+                                    cache=c, extras=dict(extras, layer=l),
+                                    plan=plan)
+            return (h, c), aux
+
+        n_layers = jax.tree.leaves(blocks)[0].shape[0]
+        (x, cache), aux = jax.lax.scan(paged_body, (x, cache),
+                                       (blocks, jnp.arange(n_layers)))
+        return x, cache, jnp.sum(aux)
+
     def body(carry, xs):
         h = carry
         bp, c = xs

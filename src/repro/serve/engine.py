@@ -245,8 +245,9 @@ def paged_decode_step(model, plan, kernel, p, tok, caches, lengths, table,
 
 def paged_chunk_step(model, plan, kernel, p, toks, caches, lengths, table,
                      n_write, last_idx, temps, top_ks, seeds, ctrs):
-    """Paged chunk window: scatter through the block table, diverted to
-    scratch past each row's fed count (pads, parked riders)."""
+    """Paged chunk window: each row writes its fed tokens through the
+    block table, and nothing into its blocks past them (pads, parked
+    riders)."""
     logits, caches = model.prefill(p, {"tokens": toks}, plan,
                                    cache=caches, cache_len=lengths,
                                    block_table=table, paged_kernel=kernel,
@@ -509,8 +510,8 @@ class ServingEngine:
 
         def verify_paged(p, toks, caches, lengths, table, n_write, dprobs,
                          proposed, n_spec, temps, top_ks, seeds, ctrs):
-            """Paged verify: the window scatters through the block table
-            (diverted to scratch past each row's granted watermark)."""
+            """Paged verify: the window writes through the block table
+            (nothing into a row's blocks past its granted watermark)."""
             logits, caches = model.verify_step(p, toks, caches, lengths,
                                                plan, block_table=table,
                                                paged_kernel=kernel_flag,

@@ -131,10 +131,40 @@ def test_wkv_state_carry_equals_two_halves():
 
 # --------------------------------------------------- paged decode attention
 from repro.kernels.paged_attention.ops import (  # noqa: E402
-    paged_decode_attention as paged_decode)
+    paged_decode_attention as decode_op,
+    paged_window_attention as window_op)
 from repro.kernels.paged_attention.kernel import tile_plan  # noqa: E402
 from repro.kernels.paged_attention.ref import (  # noqa: E402
     gathered_decode_ref, paged_decode_attention_ref)
+
+
+def _held(pool, table, base, S):
+    """What a one-layer pool holds at each row's positions ``base +
+    [0, S)`` (B, S, Hkv, hd); a position below 0 reads position 0."""
+    bs = pool.shape[2]
+    pos = jnp.maximum(jnp.asarray(base)[:, None] + jnp.arange(S)[None], 0)
+    phys = table[jnp.arange(table.shape[0])[:, None], pos // bs]
+    return pool[phys, :, pos % bs]
+
+
+def paged_decode(q, pk, pv, table, lens, **kw):
+    """The kernel over a one-layer pool, writing at each row's last
+    position the K/V the pool already holds there, so out and lse read
+    the pool as given."""
+    kn, vn = (_held(p, table, lens - 1, 1)[:, 0] for p in (pk, pv))
+    out, lse, _, _ = decode_op(q, kn, vn, pk[None], pv[None], table, lens,
+                               0, **kw)
+    return out, lse
+
+
+def paged_window(q, pk, pv, table, base, **kw):
+    """The window kernel over a one-layer pool, every row writing the
+    K/V the pool already holds at its window's positions."""
+    S = q.shape[1]
+    kn, vn = (_held(p, table, base, S) for p in (pk, pv))
+    out, lse, _, _ = window_op(q, kn, vn, pk[None], pv[None], table, base,
+                               0, jnp.full(q.shape[:1], S, jnp.int32), **kw)
+    return out, lse
 
 
 def _paged_case(B, Hq, Hkv, hd, bs, max_blocks, dt, *, seed=0, full=False,
@@ -222,7 +252,7 @@ def test_paged_decode_kernel_differential(B, Hq, Hkv, hd, bs, mb, win, dt):
 
 def _block_tokens(Hq, Hkv, hd, bs, mb, dt, S):
     """Tokens per compute block the kernel plans for this shape."""
-    P, _ = tile_plan(Hkv=Hkv, bs=bs, hd=hd, R=S * Hq // Hkv,
+    P, _ = tile_plan(Hkv=Hkv, bs=bs, hd=hd, S=S, G=Hq // Hkv,
                      itemsize=jnp.dtype(dt).itemsize, max_blocks=mb)
     return P * bs
 
@@ -310,10 +340,12 @@ def test_paged_attention_serving_path_kernel_vs_gather():
     v_new = jax.random.normal(ks[1], (B, 1, Hkv, hd))
     # cache_len = lens - 1 so the scatter stays inside owned blocks
     cache_len = lens - 1
-    o_g, pk_g, pv_g = serve_paged(q[:, None], pk, pv, k_new, v_new, table,
-                                  cache_len, use_kernel=False)
-    o_k, pk_k, pv_k = serve_paged(q[:, None], pk, pv, k_new, v_new, table,
-                                  cache_len, use_kernel=True)
+    o_g, pk_g, pv_g = serve_paged(q[:, None], pk[None], pv[None], k_new,
+                                  v_new, table, cache_len, 0,
+                                  use_kernel=False)
+    o_k, pk_k, pv_k = serve_paged(q[:, None], pk[None], pv[None], k_new,
+                                  v_new, table, cache_len, 0,
+                                  use_kernel=True)
     np.testing.assert_array_equal(np.asarray(pk_g), np.asarray(pk_k))
     np.testing.assert_array_equal(np.asarray(pv_g), np.asarray(pv_k))
     np.testing.assert_allclose(np.float32(o_g), np.float32(o_k), atol=3e-5,
@@ -321,8 +353,6 @@ def test_paged_attention_serving_path_kernel_vs_gather():
 
 
 # ------------------------------------------------- fused window attention
-from repro.kernels.paged_attention.ops import (  # noqa: E402
-    paged_window_attention as paged_window)
 from repro.kernels.paged_attention.ref import (  # noqa: E402
     gathered_window_ref, paged_window_attention_ref)
 
@@ -440,14 +470,17 @@ def test_paged_window_tile_plan_splits_heads_only_for_vmem():
     fits."""
     from repro.kernels.paged_attention.kernel import (VMEM_BUDGET_BYTES,
                                                       _step_vmem_bytes)
-    wide = tile_plan(Hkv=8, bs=16, hd=128, R=256, itemsize=2, max_blocks=64)
-    narrow = tile_plan(Hkv=8, bs=16, hd=128, R=4, itemsize=2, max_blocks=64)
+    wide = tile_plan(Hkv=8, bs=16, hd=128, S=64, G=4, itemsize=2,
+                     max_blocks=64)
+    narrow = tile_plan(Hkv=8, bs=16, hd=128, S=1, G=4, itemsize=2,
+                       max_blocks=64)
     assert narrow == (16, 8)
     assert wide[0] == 16 and 8 % wide[1] == 0 and wide[1] < 8
     for P, hg in (wide, narrow):
-        R = 256 if (P, hg) == wide else 4
-        assert _step_vmem_bytes(hg, P * 16, R, 128, 2) <= VMEM_BUDGET_BYTES
-    assert tile_plan(Hkv=2, bs=16, hd=64, R=2, itemsize=4,
+        S = 64 if (P, hg) == wide else 1
+        assert _step_vmem_bytes(hg, P * 16, S, 4, 128,
+                                2) <= VMEM_BUDGET_BYTES
+    assert tile_plan(Hkv=2, bs=16, hd=64, S=1, G=2, itemsize=4,
                      max_blocks=3) == (3, 2)
 
 
@@ -513,18 +546,96 @@ def test_paged_verify_serving_path_kernel_vs_gather():
     v_new = jax.random.normal(ks[1], (B, S, Hkv, hd))
     # full window / partial grant / parked rider (all writes diverted)
     n_write = jnp.asarray([S, 2, 0], jnp.int32)
-    o_g, pk_g, pv_g = sv(q, pk, pv, k_new, v_new, table, base, n_write,
-                         use_kernel=False)
-    o_k, pk_k, pv_k = sv(q, pk, pv, k_new, v_new, table, base, n_write,
-                         use_kernel=True)
-    np.testing.assert_array_equal(np.asarray(pk_g)[1:], np.asarray(pk_k)[1:])
-    np.testing.assert_array_equal(np.asarray(pv_g)[1:], np.asarray(pv_k)[1:])
+    o_g, pk_g, pv_g = sv(q, pk[None], pv[None], k_new, v_new, table, base,
+                         n_write, 0, use_kernel=False)
+    o_k, pk_k, pv_k = sv(q, pk[None], pv[None], k_new, v_new, table, base,
+                         n_write, 0, use_kernel=True)
+    np.testing.assert_array_equal(np.asarray(pk_g)[:, 1:],
+                                  np.asarray(pk_k)[:, 1:])
+    np.testing.assert_array_equal(np.asarray(pv_g)[:, 1:],
+                                  np.asarray(pv_k)[:, 1:])
     og = np.float32(o_g).reshape(B, S, Hq, hd)
     ok = np.float32(o_k).reshape(B, S, Hq, hd)
     for b in range(B):
         c = int(n_write[b])
         np.testing.assert_allclose(ok[b, :c], og[b, :c], atol=3e-5,
                                    rtol=3e-5)
+
+
+# window tokens x query heads x KV heads x head_dim x dtype; heads of 64
+# are lane-packed two to a pool row (attention.lane_pack)
+INPLACE_GRID = [
+    (1, 8, 2, 128, jnp.float32),      # decode
+    (4, 8, 2, 128, jnp.float32),      # speculative verify
+    (64, 8, 2, 128, jnp.float32),     # chunk window
+    (1, 8, 4, 64, jnp.float32),       # lane-packed decode
+    (4, 8, 4, 64, jnp.bfloat16),      # lane-packed verify, bf16
+    (64, 8, 4, 64, jnp.bfloat16),     # lane-packed chunk window, bf16
+]
+
+
+@pytest.mark.parametrize("S,Hq,Hkv,hd,dt", INPLACE_GRID)
+def test_paged_kernel_writes_window_in_place(S, Hq, Hkv, hd, dt):
+    """The kernel's in-place write against a pool stacked over three
+    layers, through the serving entry points (decode for S = 1, the
+    verify/chunk window otherwise), kernel against the jnp path: the
+    target layer's pages equal the jnp scatter's, every other layer is
+    bit-for-bit untouched, and the outputs agree on every position the
+    engine can commit. Rows start on a block edge, mid-block, and one
+    window straddles a compute-block edge; one row writes part of its
+    window and one none of it (a parked rider), and in decode one row's
+    write site is the scratch block. The scratch block itself is left
+    out of the comparison: diverted writes land there in the jnp path
+    and nowhere in the kernel's, and it is garbage by design."""
+    from repro.models.attention import (lane_pack, paged_decode_attention,
+                                        paged_verify_attention)
+    L, layer, bs, mb = 3, 1, 16, 24
+    f = lane_pack(hd, Hkv)
+    T = _block_tokens(Hq, Hkv // f, hd * f, bs, mb, dt, S)
+    base = np.array([0, 37, T - S // 2 - 1, 90], np.int32)
+    B = len(base)
+    n_write = np.array([S, max(S // 2, 1), 0, S], np.int32)
+    nb = B * mb + 2
+    ks = jax.random.split(jax.random.PRNGKey(S * 10 + hd), 5)
+    q = jax.random.normal(ks[0], (B, S, Hq, hd), dt)
+    shape = (L, nb, Hkv // f, bs, hd * f)
+    pk = jax.random.normal(ks[1], shape, dt)
+    pv = jax.random.normal(ks[2], shape, dt)
+    k_new = jax.random.normal(ks[3], (B, S, Hkv, hd), dt)
+    v_new = jax.random.normal(ks[4], (B, S, Hkv, hd), dt)
+    free = list(np.random.default_rng(S).permutation(np.arange(1, nb)))
+    table = np.zeros((B, mb), np.int32)
+    for b in range(B):
+        for i in range(-(-int(base[b] + S) // bs)):
+            table[b, i] = free.pop()
+    if S == 1:
+        # a parked row: its write site is the scratch block
+        table[2, base[2] // bs] = 0
+        run = lambda kernel: paged_decode_attention(       # noqa: E731
+            q, pk, pv, k_new, v_new, jnp.asarray(table), jnp.asarray(base),
+            layer, use_kernel=kernel)
+        n_write = np.ones(B, np.int32)
+    else:
+        run = lambda kernel: paged_verify_attention(       # noqa: E731
+            q, pk, pv, k_new, v_new, jnp.asarray(table), jnp.asarray(base),
+            jnp.asarray(n_write), layer, use_kernel=kernel)
+    o_g, pk_g, pv_g = run(False)
+    o_k, pk_k, pv_k = run(True)
+    for new, ref, old in ((pk_k, pk_g, pk), (pv_k, pv_g, pv)):
+        new, ref, old = (np.asarray(a).view(np.uint8) for a in (new, ref,
+                                                                old))
+        np.testing.assert_array_equal(new[layer, 1:], ref[layer, 1:])
+        for other in set(range(L)) - {layer}:
+            np.testing.assert_array_equal(new[other], old[other])
+    assert not np.array_equal(np.asarray(pk_k[layer]), np.asarray(pk[layer]))
+    og = np.float32(o_g).reshape(B, S, Hq, hd)
+    ok = np.float32(o_k).reshape(B, S, Hq, hd)
+    for b in range(B):
+        if S == 1 and table[b, base[b] // bs] == 0:
+            continue
+        c = int(n_write[b])
+        np.testing.assert_allclose(ok[b, :c], og[b, :c], atol=tol(dt),
+                                   rtol=tol(dt))
 
 
 # ---------------------------------------------------------------- ssm scan

@@ -10,6 +10,7 @@ one process may load the TPU library, and the test workers each import
 every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,18 +72,22 @@ def _sds(shape, dtype, sharding):
 def _compile_paged_kernel(sharding, arch: str, batch: int, max_seq: int,
                           S: int) -> str:
     """HLO text of the paged kernel compiled for the described chip:
-    ``batch`` rows of ``S`` window tokens against a head-major bf16 pool
-    of ``max_seq / BLOCK`` blocks per row at ``arch``'s widths."""
+    ``batch`` rows of ``S`` window tokens, their K/V written in place
+    into one layer of a head-major bf16 pool stacked over ``arch``'s
+    layers, ``max_seq / BLOCK`` blocks per row at ``arch``'s widths."""
     cfg = get_config(arch)
     Hq, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     mb = max_seq // BLOCK
     nb = batch * mb + 1
-    pool = _sds((nb, Hkv, BLOCK, hd), jnp.bfloat16, sharding)
-    fn = jax.jit(lambda q, k, v, t, b: paged_window_attention(
-        q, k, v, t, b, interpret=False))
+    pool = _sds((cfg.n_layers, nb, Hkv, BLOCK, hd), jnp.bfloat16, sharding)
+    new = _sds((batch, S, Hkv, hd), jnp.bfloat16, sharding)
+    rows = _sds((batch,), jnp.int32, sharding)
+    fn = jax.jit(lambda q, kn, vn, k, v, t, b, l, n: paged_window_attention(
+        q, kn, vn, k, v, t, b, l, n, interpret=False), donate_argnums=(3, 4))
     return fn.lower(_sds((batch, S, Hq, hd), jnp.bfloat16, sharding),
-                    pool, pool, _sds((batch, mb), jnp.int32, sharding),
-                    _sds((batch,), jnp.int32, sharding)).compile().as_text()
+                    new, new, pool, pool,
+                    _sds((batch, mb), jnp.int32, sharding), rows,
+                    _sds((), jnp.int32, sharding), rows).compile().as_text()
 
 
 @pytest.mark.parametrize("S", [1, 4, 64])
@@ -104,33 +109,53 @@ def test_paged_kernel_compiles_at_cell_shapes(one_chip, arch, S):
     assert "tpu_custom_call" in text
 
 
+# the served shapes: Qwen3-4B at the engine shape above, Granite at its
+# cell's (batch, tokens per slot, chunk width)
+SERVED = {"qwen3-4b": (BATCH, MAX_SEQ, CHUNK),
+          "granite-4.0-h-micro": (32, 3072, 256)}
+
+
 @pytest.fixture(scope="module")
-def serving_args(one_chip):
-    """Qwen3-4B parameters and the KV pool as sharded shape stand-ins
-    (``jax.eval_shape``: nothing is allocated)."""
-    cfg = get_config("qwen3-4b")
-    model = build_model(cfg)
-    put = lambda tree: jax.tree.map(                       # noqa: E731
-        lambda x: _sds(x.shape, x.dtype, one_chip), tree)
-    params = put(jax.eval_shape(model.init, jax.random.key(0)))
-    num_blocks = BATCH * (MAX_SEQ // BLOCK) + 1
-    caches = put(jax.eval_shape(
-        lambda: model.init_paged_cache(num_blocks, BLOCK)))
-    args = paged_program_args(params, caches, batch=BATCH,
-                              blocks_per_slot=MAX_SEQ // BLOCK,
-                              width=CHUNK, sharding=one_chip)
-    return model, args
+def compiled_step(one_chip):
+    """``get(arch, program, quarter=False)``: the engine's jitted paged
+    ``program`` for ``arch`` at its served shape, compiled once for the
+    described chip, and its K pool leaf (a shape stand-in). Parameters
+    and the pool are sharded shape stand-ins (``jax.eval_shape``:
+    nothing is allocated); ``quarter`` compiles it over a pool of a
+    quarter of the blocks."""
+    done = {}
+
+    def get(arch, program, quarter=False):
+        key = (arch, program, quarter)
+        if key not in done:
+            model = build_model(get_config(arch))
+            batch, max_seq, width = SERVED[arch]
+            put = lambda tree: jax.tree.map(               # noqa: E731
+                lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+            params = put(jax.eval_shape(model.init, jax.random.key(0)))
+            blocks = batch * (max_seq // BLOCK) // (4 if quarter else 1)
+            caches = put(jax.eval_shape(lambda: model.init_paged_cache(
+                blocks + 1, BLOCK, slots=batch)))
+            args = paged_program_args(params, caches, batch=batch,
+                                      blocks_per_slot=max_seq // BLOCK,
+                                      width=width, sharding=one_chip)
+            progs = paged_programs(model, block_size=BLOCK, use_kernel=True)
+            # the kernel ops pick compiled vs interpret from
+            # ``jax.default_backend()``, the CPU here
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(jax, "default_backend", lambda: "tpu")
+                done[key] = (progs[program].lower(*args[program]).compile(),
+                             caches["k"])
+        return done[key]
+    return get
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
-def test_full_width_serving_step_compiles_and_fits(serving_args, tpu_branch,
-                                                   program):
+def test_full_width_serving_step_compiles_and_fits(compiled_step, program):
     """The engine's jitted paged decode step and chunk-window step at 36
     layers x d 2560 in bf16, batch 8 x 2048 tokens: the kernel is in the
     program and arguments plus temporaries fit one chip."""
-    model, args = serving_args
-    progs = paged_programs(model, block_size=BLOCK, use_kernel=True)
-    compiled = progs[program].lower(*args[program]).compile()
+    compiled, _ = compiled_step("qwen3-4b", program)
     assert "tpu_custom_call" in compiled.as_text()
     ma = compiled.memory_analysis()
     used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
@@ -148,39 +173,66 @@ def test_paged_kernel_compiles_at_granite_widths(one_chip, tpu_branch):
     assert (Hq, Hkv, hd, cfg.attention_multiplier) == (32, 8, 64, 1 / 64)
     f = lane_pack(hd, Hkv)
     batch, mb = 32, 3072 // BLOCK
-    pool = _sds((batch * mb + 1, Hkv // f, BLOCK, hd * f), jnp.bfloat16,
-                one_chip)
-    fn = jax.jit(lambda q, k, v, kn, vn, t, n: paged_decode_attention(
-        q, k, v, kn, vn, t, n, use_kernel=True,
-        scale=cfg.attention_multiplier))
+    pool = _sds((cfg.layer_types.count("attention"), batch * mb + 1,
+                 Hkv // f, BLOCK, hd * f), jnp.bfloat16, one_chip)
+    fn = jax.jit(lambda q, k, v, kn, vn, t, n, l: paged_decode_attention(
+        q, k, v, kn, vn, t, n, l, use_kernel=True,
+        scale=cfg.attention_multiplier), donate_argnums=(1, 2))
     one = _sds((batch, 1, Hkv, hd), jnp.bfloat16, one_chip)
     text = fn.lower(_sds((batch, 1, Hq, hd), jnp.bfloat16, one_chip), pool,
                     pool, one, one, _sds((batch, mb), jnp.int32, one_chip),
-                    _sds((batch,), jnp.int32, one_chip)).compile().as_text()
+                    _sds((batch,), jnp.int32, one_chip),
+                    _sds((), jnp.int32, one_chip)).compile().as_text()
     assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
-def test_granite_serving_step_compiles_and_fits(one_chip, tpu_branch,
-                                                program):
+def test_granite_serving_step_compiles_and_fits(compiled_step, program):
     """Granite-4.0-H-Micro at published width (40 layers: 36 Mamba-2,
     4 attention) at its cell's engine shape, batch 32 x 3072 tokens and
     256-token chunk windows: the paged kernel is in the program, and
     arguments (weights, pool, 32 state rows) plus temporaries fit one
     chip."""
-    model = build_model(get_config("granite-4.0-h-micro"))
-    batch, max_seq, width = 32, 3072, 256
-    put = lambda tree: jax.tree.map(                       # noqa: E731
-        lambda x: _sds(x.shape, x.dtype, one_chip), tree)
-    params = put(jax.eval_shape(model.init, jax.random.key(0)))
-    caches = put(jax.eval_shape(lambda: model.init_paged_cache(
-        batch * (max_seq // BLOCK) + 1, BLOCK, slots=batch)))
-    args = paged_program_args(params, caches, batch=batch,
-                              blocks_per_slot=max_seq // BLOCK, width=width,
-                              sharding=one_chip)
-    progs = paged_programs(model, block_size=BLOCK, use_kernel=True)
-    compiled = progs[program].lower(*args[program]).compile()
+    compiled, _ = compiled_step("granite-4.0-h-micro", program)
     assert "tpu_custom_call" in compiled.as_text()
     ma = compiled.memory_analysis()
     used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, (program, used)
+
+
+# an op's result in the compiled program's text: name, dims, opcode
+_RESULT = re.compile(r"^\s*(?:ROOT\s+)?(%\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                     re.M)
+# ops that would copy, slice or rebuild the pool or a layer of it
+POOL_OPS = ("copy", "dynamic-slice", "dynamic-update-slice", "scatter",
+            "fusion")
+
+
+@pytest.mark.parametrize("arch", ["qwen3-4b", "granite-4.0-h-micro"])
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_paged_step_updates_pool_in_place(compiled_step, arch, program):
+    """The paged steps carry the stacked K/V pool through the layer loop
+    and the kernel writes each layer's pages in place, its pool
+    operands aliased to its outputs: no copy, dynamic-slice,
+    dynamic-update-slice, scatter or fusion in the compiled program
+    yields the pool's shape or one layer's slice of it, and the
+    program's temporaries hold no extra pool. For Qwen3-4B the
+    temporaries are under 5% of the pool's bytes. Granite's Mamba-2
+    layers need more than its pool on their own (a re-laid-out copy of
+    the stacked in-projections in decode, the chunked scan's
+    activations in a window), none of it sized by the pool: there the
+    temporaries may grow by under 5% of the pool's bytes from the same
+    program over a pool of a quarter of the blocks."""
+    compiled, pool = compiled_step(arch, program)
+    layer = pool.shape[1:]
+    sized = {tuple(pool.shape), layer, (1, *layer)}
+    hits = [name for name, dims, op in _RESULT.findall(compiled.as_text())
+            if op in POOL_OPS
+            and tuple(int(d) for d in dims.split(",") if d) in sized]
+    assert not hits, hits
+    pool_bytes = 2 * pool.size * pool.dtype.itemsize         # K and V
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if arch == "granite-4.0-h-micro":
+        small, _ = compiled_step(arch, program, quarter=True)
+        temp -= small.memory_analysis().temp_size_in_bytes
+    assert temp < 0.05 * pool_bytes, (temp, pool_bytes)
